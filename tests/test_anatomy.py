@@ -31,7 +31,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from tony_tpu.obs import anatomy, comms
 from tony_tpu.obs import profile as profile_mod
-from tony_tpu.ops.compat import shard_map_compat
 
 
 @pytest.fixture(autouse=True)
@@ -49,7 +48,7 @@ def _psum_program():
     def f(x, w):
         return jax.lax.psum(jnp.dot(x, w), "dp")
 
-    sf = jax.jit(shard_map_compat(
+    sf = jax.jit(jax.shard_map(
         f, mesh=mesh, in_specs=(P("dp"), P(None, None)), out_specs=P(),
     ))
     x = jnp.ones((n * 16, 64), jnp.float32)
@@ -70,7 +69,9 @@ class TestCommsExtraction:
         # result is the reduced f32[1? x 32] block per participant; payload
         # bytes are the result type's size — nonzero and 4-byte aligned
         assert row["bytes"] > 0 and row["bytes"] % 4 == 0
-        assert row["name"].startswith("all-reduce")
+        # the NAME is whatever lowered the op (jax 0.9: `psum_invariant.N`);
+        # the kind comes from the opcode
+        assert row["name"]
         groups = row["replica_groups"]
         # one group over every device (parsed {{...}} form) or the iota
         # string form — both must name all n participants
@@ -351,8 +352,9 @@ class TestCapture:
 
 
 class TestReport:
-    def _capture_app(self, tmp_path, procs=("w0", "w1"), scale=(1, 2)):
+    def _capture_app(self, tmp_path, procs=("w0", "w1"), scale=(1, 8)):
         compiled, x, w, _ = _psum_program()
+        jax.block_until_ready(compiled(x, w))  # warm outside the windows
         app_dir = str(tmp_path)
         # ONE broadcast id shared by every proc — the AM path's shape
         req = profile_mod.write_request(app_dir, steps=2)
@@ -364,7 +366,9 @@ class TestReport:
             ctl.check_request()
             for _ in range(4):
                 ctl.step()
-                for _ in range(mult):  # w1 does 2x work: the straggler
+                # w1 does 8x work — the straggler, by a margin a loaded
+                # test host's millisecond hiccups cannot close
+                for _ in range(mult):
                     jax.block_until_ready(compiled(x, w))
             ctl.finish()
         return app_dir, req.id
@@ -374,7 +378,7 @@ class TestReport:
         rep = anatomy.build_anatomy(app_dir)
         assert set(rep["procs"]) == {"w0", "w1"}
         cp = rep["critical_path"]
-        assert cp["proc"] == "w1"  # 2x work per step dominates every step
+        assert cp["proc"] == "w1"  # 8x work per step dominates every step
         assert cp["dominated_steps"]["w1"] == 2
         assert len(cp["by_step"]) == 2
 

@@ -79,11 +79,16 @@ def test_group_block_invariance(params, x):
     partial tiles)."""
     v8, g8, y8, _ = run(params, x, dispatch="grouped", group_block=8)
     v128, g128, y128, _ = run(params, x, dispatch="grouped", group_block=128)
-    assert abs(float(v8) - float(v128)) < 1e-5
+    # the scalar is a float32 sum in the hundreds: one unit in its last
+    # place is 3e-5, so the bound is relative
+    assert abs(float(v8) - float(v128)) <= 1e-6 * abs(float(v128))
     np.testing.assert_allclose(np.asarray(y8), np.asarray(y128), atol=1e-6)
     for k in g8:
+        # float32 grads of magnitude ~20 accumulated in another tile order:
+        # a few units in the last place (2e-6 each) — relative, not absolute
         np.testing.assert_allclose(
-            np.asarray(g8[k]), np.asarray(g128[k]), atol=1e-5, err_msg=k
+            np.asarray(g8[k]), np.asarray(g128[k]), atol=1e-5, rtol=1e-5,
+            err_msg=k,
         )
 
 
@@ -274,8 +279,7 @@ def test_grouped_is_shard_map_safe():
     so splitting the batch must not change any token's output."""
     from jax.sharding import PartitionSpec as P
 
-    from tony_tpu.ops.compat import shard_map_compat
-
+    
     cfg = dataclasses.replace(BASE, dispatch="grouped")
     params = init_moe_params(jax.random.key(0), BASE, dtype=jnp.float32)
     x = jax.random.normal(jax.random.key(1), (2, 24, 32), jnp.float32)
@@ -286,7 +290,7 @@ def test_grouped_is_shard_map_safe():
     def local(p, xx):
         return moe_block(p, xx, cfg)[0]
 
-    got = shard_map_compat(
+    got = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(), P("dp", None, None)),

@@ -20,7 +20,6 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from tony_tpu.ops.compat import shard_map_compat
 from tony_tpu.ops.moe_overlap import chunk_tokens_from_report, overlap_chunks
 from tony_tpu.parallel.mesh import MeshShape, build_mesh, set_default_mesh
 from tony_tpu.parallel.moe import MoEConfig, init_moe_params, moe_block
@@ -218,7 +217,7 @@ class TestFallbacks:
                 y, _ = moe_block(p, xx, cfg)
                 return y
 
-            got = shard_map_compat(
+            got = jax.shard_map(
                 f, mesh=mesh, in_specs=(P(), P()), out_specs=P()
             )(params, x)
         finally:

@@ -16,7 +16,7 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from tony_tpu.ops.compat import shard_map_compat as _shard_map
+from jax import shard_map as _shard_map
 from tony_tpu.ops.overlap import (
     all_gather_matmul_local,
     bucket_bytes_from_report,

@@ -30,28 +30,6 @@ from tony_tpu.parallel import (
 from tony_tpu.parallel.moe import logical_axes as moe_logical_axes
 
 
-def _xfail_known_jax04_failure(
-    exc: BaseException, signatures: tuple[str, ...], what: str
-):
-    """Pin a pre-existing environment failure to its exact signature (the
-    test_examples.py gloo-offline pattern): on this jax line (<0.5) the
-    shard_map compat shim drops ``axis_names`` and falls back to the FULL
-    manual region, where the expert=tp override / pp x MoE out_specs
-    combinations are known-broken on the CPU mesh. xfail ONLY when the
-    raised chain carries every known signature under jax<0.5; any other
-    failure — or the same test failing on a newer jax — is real and
-    re-raises."""
-    version = tuple(int(p) for p in jax.__version__.split(".")[:2])
-    chain, node = [], exc
-    while node is not None:
-        chain.append(f"{type(node).__name__}: {node}")
-        node = node.__cause__ or node.__context__
-    text = "\n".join(chain)
-    if version < (0, 5) and all(sig in text for sig in signatures):
-        pytest.xfail(f"{what} (known jax {jax.__version__} CPU-mesh failure)")
-    raise exc
-
-
 def ref_causal_attention(q, k, v):
     scale = 1.0 / math.sqrt(q.shape[-1])
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
@@ -234,17 +212,9 @@ class TestMoE:
         params_s = jax.device_put(params, shardings)
         x_s = jax.device_put(x, NamedSharding(mesh, P(("dp", "fsdp"), None, None)))
         got, _ = jax.jit(lambda p, a: moe_block(p, a, cfg))(params_s, x_s)
-        try:
-            np.testing.assert_allclose(
-                np.asarray(got), np.asarray(expect), atol=1e-4
-            )
-        except AssertionError as e:
-            _xfail_known_jax04_failure(
-                e,
-                ("Not equal to tolerance",
-                 "Mismatched elements: 1024 / 1024 (100%)"),
-                "expert=tp resharded moe_block diverges everywhere",
-            )
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(expect), atol=1e-4
+        )
 
 
 def test_multislice_mesh_shape_and_training():
@@ -355,13 +325,7 @@ def test_pp_moe_train_step_matches_sequential():
         return m_pp, m_seq
 
     # coef 0 isolates the CE: must match exactly
-    try:
-        m_pp, m_seq = run(0.0)
-    except Exception as e:
-        _xfail_known_jax04_failure(
-            e, ("_SpecError",),
-            "pp x MoE out_specs rejected under the full-manual fallback",
-        )
+    m_pp, m_seq = run(0.0)
     assert abs(float(m_pp["loss"]) - float(m_seq["loss"])) < 1e-5
     assert abs(float(m_pp["grad_norm"]) - float(m_seq["grad_norm"])) < 1e-4
     # with the aux term on, per-microbatch routing statistics differ from

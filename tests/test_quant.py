@@ -222,12 +222,8 @@ class TestQuantCache:
         assert dt == jnp.int8 and qmax == 127.0
         with pytest.raises(ValueError):
             kv_quant_spec("int4")
-        if not hasattr(jnp, "float8_e4m3fn"):
-            with pytest.raises(ValueError):
-                kv_quant_spec("fp8_e4m3")
-        else:
-            dt8, qmax8 = kv_quant_spec("fp8_e4m3")
-            assert qmax8 == 448.0
+        dt8, qmax8 = kv_quant_spec("fp8_e4m3")
+        assert dt8 == jnp.float8_e4m3fn and qmax8 == 448.0
 
     def test_running_scale_growth_keeps_old_positions_accurate(self):
         """Write small-amplitude rows, then 8x larger rows into the SAME
@@ -391,19 +387,12 @@ class TestQuantEngine:
         assert "quant_pool_resident_bytes" not in bf.stats_snapshot()
         assert bf.stats_snapshot()["kv_bytes_per_token"] > snap["kv_bytes_per_token"]
 
-    # slow: fp8 availability is a property of the jax line, not of this
-    # code — the int8 path above is the tier-1 surface, and the fp8 engine
+    # slow: the int8 path above is the tier-1 surface, and the fp8 engine
     # build costs ~3s of a tier-1 budget that runs close to its ceiling.
-    # kv_quant_spec's fp8 gate itself stays tier-1 in TestQuantCache.
+    # kv_quant_spec's fp8 row itself stays tier-1 in TestQuantCache.
     @pytest.mark.slow
     def test_fp8_gate(self, setup):
         cfg, params = setup
-        if not hasattr(jnp, "float8_e4m3fn"):
-            with pytest.raises(ValueError):
-                Engine(params, cfg, ServeConfig(
-                    slots=1, max_len=16, kv_block=8, quant_kv="fp8_e4m3",
-                ))
-            return
         eng = Engine(params, cfg, ServeConfig(
             slots=1, max_len=16, kv_block=8, quant_kv="fp8_e4m3",
         ))

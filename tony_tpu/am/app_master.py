@@ -611,7 +611,13 @@ class ApplicationMaster(ApplicationRpcServicer):
         # slot. Later step pushes obey the throttle — the unbounded-history
         # guard stays intact for long jobs.
         now = time.monotonic()
-        first_step = "step" in samples and tid not in self._step_metric_seen
+        # ... and so does the sample that names the task's devices
+        # (obs.metrics.device_samples — fit() sends it with step 1, a serve
+        # gang host with its first stats push): what a task came up on
+        # belongs in the history whatever the monitor pushed before it
+        first_step = tid not in self._step_metric_seen and (
+            "step" in samples or any(n.startswith("device/") for n in samples)
+        )
         if first_step:
             self._step_metric_seen.add(tid)
         if first_step or (

@@ -75,11 +75,13 @@ class Keys:
     METRICS_ENABLED = "metrics.enabled"
     PROFILER_ENABLED = "profiler.enabled"
     PROFILER_PORT = "profiler.port"
-    # persistent XLA compilation cache for fit() jobs: resubmits and elastic
-    # restarts skip compile — the dominant submit->first-step cost (the
-    # north-star latency metric; measured in docs/PERF.md)
+    # persistent XLA compilation cache for fit() jobs and serve gang hosts:
+    # resubmits and gang restarts load their executables instead of
+    # recompiling (the compile segment of submit->first-step)
     TRAIN_JAX_CACHE = "train.jax_cache"
-    TRAIN_JAX_CACHE_DIR = "train.jax_cache_dir"  # default ~/.tony-tpu/jax_cache
+    # '' -> <checkout>/.jax_cache; JAX_COMPILATION_CACHE_DIR set from outside
+    # overrides both (utils/compile_cache.py)
+    TRAIN_JAX_CACHE_DIR = "train.jax_cache_dir"
     # cloud-tpu-diagnostics periodic stack traces (wedged-job debugging)
     DIAGNOSTICS_ENABLED = "diagnostics.enabled"
     # distributed trace spine (obs/trace.py; docs/OBS.md): always-on sampled

@@ -99,7 +99,14 @@ def _subtract(a: list[tuple[float, float]],
 # --- device-trace parsing -----------------------------------------------------
 
 
-def _is_collective_event(name: str) -> bool:
+def _is_collective_event(name: str, known: frozenset = frozenset()) -> bool:
+    """A trace event is a collective when the compile ledger says so —
+    ``known`` holds the op NAMES of the compiled program's collective rows
+    (obs/comms.py classifies by opcode; XLA names an op after whatever
+    lowered it, e.g. ``psum_invariant.3`` for an ``all-reduce``) — or, for
+    captures without a ledger, when its name is a collective opcode."""
+    if name in known or name.replace("-done", "-start") in known:
+        return True
     base = name.split(".")[0]
     if base in comms.COLLECTIVE_KINDS:
         return True
@@ -109,7 +116,9 @@ def _is_collective_event(name: str) -> bool:
     return False
 
 
-def load_device_trace(run_dir: str) -> dict[str, Any]:
+def load_device_trace(
+    run_dir: str, collective_names: frozenset = frozenset()
+) -> dict[str, Any]:
     """Parse the ``*.trace.json[.gz]`` files of one profiler run dir into
     step windows + device-op intervals (seconds, trace timebase).
 
@@ -118,7 +127,7 @@ def load_device_trace(run_dir: str) -> dict[str, Any]:
     (``tf_...``) AND its name is an op name — not a python-tracer event
     (``$...``) and not a C++ wrapper (``Class::Method``). The
     ``anatomy.step`` annotation spans (host thread) become the step
-    windows."""
+    windows. ``collective_names``: see :func:`_is_collective_event`."""
     out: dict[str, Any] = {
         "found": False, "step_windows": [], "compute": [], "collective": [],
         "collective_events": [], "files": [],
@@ -170,7 +179,7 @@ def load_device_trace(run_dir: str) -> dict[str, Any]:
             if not (pname.startswith("/device:") or tname.startswith("tf_")):
                 continue
             iv = (ts, ts + dur)
-            if _is_collective_event(ename):
+            if _is_collective_event(ename, collective_names):
                 out["collective"].append(iv)
                 out["collective_events"].append(
                     {"name": ename, "ts": ts, "dur_s": dur}
@@ -320,7 +329,10 @@ def proc_report(manifest: dict[str, Any],
                 ledger_rows: list[dict[str, Any]] | None = None
                 ) -> dict[str, Any]:
     """The full anatomy of ONE process's capture."""
-    trace_data = load_device_trace(manifest.get("artifact", ""))
+    trace_data = load_device_trace(
+        manifest.get("artifact", ""),
+        frozenset(r["name"] for r in ledger_rows or ()),
+    )
     budget = step_budget(manifest, trace_data)
     colls = collective_table(trace_data, ledger_rows)
     return {

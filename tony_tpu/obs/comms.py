@@ -119,8 +119,8 @@ def extract_collectives(compiled: Any) -> list[dict[str, Any]]:
     else:
         try:
             text = compiled.as_text()
-        except Exception:
-            return []
+        except (AttributeError, NotImplementedError, RuntimeError):
+            return []  # not a compiled executable / backend keeps no text
     rows: list[dict[str, Any]] = []
     for line in text.splitlines():
         if "(" not in line or "=" not in line:

@@ -55,7 +55,7 @@ def aot_analysis(compiled) -> dict[str, Any]:
                 alias_bytes=int(ma.alias_size_in_bytes),
                 generated_code_bytes=int(ma.generated_code_size_in_bytes),
             )
-    except Exception:
+    except (NotImplementedError, RuntimeError):  # backend has no such analysis
         pass
     try:
         ca = compiled.cost_analysis()
@@ -66,7 +66,7 @@ def aot_analysis(compiled) -> dict[str, Any]:
                               ("bytes accessed", "bytes_accessed")):
                 if key in ca:
                     out[name] = float(ca[key])
-    except Exception:
+    except (NotImplementedError, RuntimeError):
         pass
     return out
 
@@ -128,14 +128,11 @@ class CompileLedger:
             "dur_s": round(float(dur_s), 4),
             **aot_analysis(compiled),
         }
-        try:
-            from tony_tpu.obs.comms import extract_collectives
+        from tony_tpu.obs.comms import extract_collectives
 
-            colls = extract_collectives(compiled)
-            if colls:
-                entry["collectives"] = colls
-        except Exception:
-            pass
+        colls = extract_collectives(compiled)  # [] when there is no HLO text
+        if colls:
+            entry["collectives"] = colls
         with self._lock:
             self._entries.append(entry)
         return entry

@@ -1,9 +1,7 @@
 """``tony perf diff``: cross-run performance regression verdicts.
 
-The repo accumulates one BENCH json per merged PR (BENCH_r01..r05 at the
-root) — and until now no tool read them: a PR that tanked tok/s/chip or
-TTFT would sail through review unless a human eyeballed two json blobs.
-This module compares two bench reports (or two live-series rollups) key
+A PR that tanks tok/s/chip or TTFT should not need a human to eyeball two
+json blobs. This module compares two bench reports (or two live-series rollups) key
 by key under per-section tolerance rules and emits a machine-checkable
 verdict; ``tests/test_perf_diff.py`` wires it as a tier-1 gate against
 committed fixtures, so the gate itself cannot rot.

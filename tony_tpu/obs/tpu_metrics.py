@@ -6,8 +6,8 @@ device-side daemon to query on TPU; the equivalents live in the runtime the
 training process already holds:
 
 - ``device.memory_stats()`` — HBM bytes in use / peak / limit (PJRT exposes
-  this on real TPU backends; interpreters and some relay platforms return
-  None, in which case the source simply yields nothing).
+  this on real TPU backends; the CPU backend returns None, in which case
+  the source simply yields nothing).
 - device duty cycle is not exposed through JAX's public API; the meaningful
   utilisation number on TPU is MFU, which the trainer computes from step
   timing (obs.metrics.StepTimer) and pushes through the same channel.
@@ -27,19 +27,16 @@ from tony_tpu.obs.monitor import Sample
 
 def tpu_memory_samples() -> list[Sample]:
     """HBM usage samples for every local device; [] when unavailable."""
-    try:
-        import jax
+    import jax
 
+    try:
         devices = jax.local_devices()
-    except Exception:
+    except RuntimeError:  # no backend could be created in this process
         return []
     now = time.time()
     out: list[Sample] = []
     for d in devices:
-        try:
-            stats = d.memory_stats()
-        except Exception:
-            stats = None
+        stats = d.memory_stats()  # None on the CPU backend
         if not stats:
             continue
         suffix = f"_dev{d.id}" if len(devices) > 1 else ""

@@ -30,8 +30,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from tony_tpu.ops.compat import (
-    pallas_compiler_params as _CompilerParams,
-    shard_map_compat as _shard_map,
+    struct_with_vma as _struct,
     use_interpret as _use_interpret,
 )
 
@@ -89,16 +88,6 @@ def _kv_index(b: int, heads: int, kv_heads: int) -> int:
     return (b // heads) * kv_heads + (b % heads) // rep
 
 
-def _out_struct(shape, dtype, *inputs) -> jax.ShapeDtypeStruct:
-    """Pallas out_shape carrying the inputs' varying-mesh-axes type: inside a
-    shard_map region (e.g. a pp pipeline stage) outputs must declare the vma
-    set or shard_map's type checker rejects the call. One shared copy in
-    ops.compat (degrades gracefully on jax builds without ``jax.typeof``)."""
-    from tony_tpu.ops.compat import struct_with_vma
-
-    return struct_with_vma(shape, dtype, *inputs)
-
-
 def _flash_fwd(q, k, v, *, scale, blk_q, blk_k, causal, heads, kv_heads):
     """q: [B*heads, S, D], k/v: [B*kv_heads, S, D] ->
     (out [B*heads, S, D], lse [B*heads, 1, S] fp32)."""
@@ -115,11 +104,11 @@ def _flash_fwd(q, k, v, *, scale, blk_q, blk_k, causal, heads, kv_heads):
         in_specs=[qspec, kspec, kspec],
         out_specs=[qspec, rowspec],
         out_shape=[
-            _out_struct((BH, S, D), q.dtype, q, k, v),
-            _out_struct((BH, 1, S), jnp.float32, q, k, v),
+            _struct((BH, S, D), q.dtype, q, k, v),
+            _struct((BH, 1, S), jnp.float32, q, k, v),
         ],
         # out/lse blocks revisit the same index across the k-step dim
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         scratch_shapes=[
@@ -239,9 +228,9 @@ def flash_dq_pass(q, k, v, do, lse, delta, *, scale, blk_q, blk_k, causal,
         grid=(BH, nq, nk),
         in_specs=[qspec, kspec, kspec, qspec, rowspec, rowspec],
         out_specs=[qspec],
-        out_shape=[_out_struct((BH, S, D), q.dtype, q, k, v, do)],
+        out_shape=[_struct((BH, S, D), q.dtype, q, k, v, do)],
         scratch_shapes=[pltpu.VMEM((blk_q, D), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=_use_interpret(),
@@ -275,14 +264,14 @@ def flash_dkv_pass(q, k, v, do, lse, delta, *, scale, blk_q, blk_k, causal,
         in_specs=[qspec_t, kspec_t, kspec_t, qspec_t, rowspec_t, rowspec_t],
         out_specs=[kspec_t, kspec_t],
         out_shape=[
-            _out_struct((BKV, S, D), k.dtype, q, k, v, do),
-            _out_struct((BKV, S, D), v.dtype, q, k, v, do),
+            _struct((BKV, S, D), k.dtype, q, k, v, do),
+            _struct((BKV, S, D), v.dtype, q, k, v, do),
         ],
         scratch_shapes=[
             pltpu.VMEM((blk_k, D), jnp.float32),
             pltpu.VMEM((blk_k, D), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=_use_interpret(),
@@ -408,7 +397,7 @@ def sharded_flash_attention(q, k, v, cfg=None, **kwargs) -> jax.Array:
         k = jnp.repeat(k, rep, axis=2)
         v = jnp.repeat(v, rep, axis=2)
     spec = attn_spec(mesh)  # seq_axis=None: sequence stays device-local
-    return _shard_map(
+    return jax.shard_map(
         lambda a, b, c: flash_attention(a, b, c, cfg, **kwargs),
         mesh=mesh,
         in_specs=(spec, spec, spec),

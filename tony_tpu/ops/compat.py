@@ -1,102 +1,32 @@
-"""jax-version compatibility shims shared by the Pallas kernel modules.
+"""What the Pallas kernel modules share: interpret-mode selection off-TPU
+and the vma-carrying ``out_shape`` struct for kernels under ``shard_map``.
 
-One copy of the glue that differs across the jax lines this repo runs on
-(the CI image's 0.4.x vs newer): the TPU compiler-params spelling, the
-vma-carrying ShapeDtypeStruct for kernels under shard_map, interpret-mode
-selection off-TPU, and the shard_map entry itself. Kernel modules
-(fused_ce, grouped_mm) and their callers import from here so a version fix
-lands once.
+Targets the installed jax line only (``pyproject.toml``): callers use
+``jax.shard_map``, ``pltpu.CompilerParams``, ``lax.axis_size``, ``lax.pcast``
+and ``jax.typeof`` directly.
 """
 
 from __future__ import annotations
 
 import jax
-from jax.experimental.pallas import tpu as pltpu
-
-# jax < 0.5 spells these differently; resolve once so the kernels (and the
-# CPU interpreter tests) run on either line
-pallas_compiler_params = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams"
-)
 
 
 def struct_with_vma(shape, dtype, *inputs) -> jax.ShapeDtypeStruct:
-    """Pallas out_shape carrying the inputs' varying-mesh-axes type (see
-    ops/attention._out_struct); degrades to a plain struct on jax builds
-    without ``jax.typeof``/vma typing."""
-    typeof = getattr(jax, "typeof", None)
-    if typeof is None:
-        return jax.ShapeDtypeStruct(shape, dtype)
-    vma = frozenset()
-    for x in inputs:
-        vma |= getattr(typeof(x), "vma", frozenset()) or frozenset()
+    """Pallas out_shape carrying the union of the inputs' varying-mesh-axes
+    types, so a kernel called under ``shard_map`` type-checks (``check_vma``)
+    without every call site spelling the set out."""
+    vma = frozenset().union(*(jax.typeof(x).vma for x in inputs))
     if vma:
         return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
     return jax.ShapeDtypeStruct(shape, dtype)
 
 
-def shard_map_compat(*args, **kwargs):
-    """``jax.shard_map`` where it exists, the experimental spelling
-    otherwise — translating the new kwargs the old one doesn't know:
-    ``check_vma`` -> ``check_rep`` (default off — the legacy checker has no
-    rule for pallas_call; the new-jax path carries the vma set on the
-    kernel out_shape instead) and partial-manual ``axis_names`` -> its
-    complement ``auto``."""
-    fn = getattr(jax, "shard_map", None)
-    if fn is None:
-        from jax.experimental.shard_map import shard_map as fn
-
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        kwargs.setdefault("check_rep", False)
-        if "axis_names" in kwargs:
-            # partial-manual is not viable on this line: the old tracer
-            # lowers axis_index in a manual-with-auto region through a
-            # PartitionId instruction the SPMD partitioner rejects. Fall
-            # back to FULL manual: unmentioned axes are treated as
-            # replicated (shard_map reshards at entry), which trades the
-            # auto axes' compute sharding inside the region for
-            # correctness — acceptable on the CPU-correctness CI line;
-            # the new-jax path keeps true partial-auto.
-            kwargs.pop("axis_names")
-    return fn(*args, **kwargs)
-
-
 def use_interpret() -> bool:
-    """Pallas interpret mode everywhere but real TPU (CPU tests/CI)."""
+    """Pallas interpret mode everywhere but a real TPU backend (CPU tests).
+    Kernel modules bind this name in their own namespace, which is where
+    tests/test_tpu_compile.py steers it; chip_smoke.py asserts the compiled
+    form (``tpu_custom_call``) so a silent CPU backend cannot pass there."""
     return jax.default_backend() != "tpu"
 
 
-def axis_size(axis_name) -> int:
-    """``lax.axis_size`` where it exists; the psum-of-1 idiom otherwise
-    (old jax constant-folds a literal psum to the axis size)."""
-    fn = getattr(jax.lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
-def pcast_varying(x, axis_names):
-    """``lax.pcast(x, axes, to='varying')`` on jax lines with vma typing;
-    identity where the typing system (and pcast) doesn't exist — old
-    shard_map with check_rep off imposes no varying-axes constraints, so
-    there is nothing to cast."""
-    fn = getattr(jax.lax, "pcast", None)
-    if fn is None:
-        return x
-    return fn(x, tuple(axis_names), to="varying")
-
-
-def vma_of(x) -> frozenset:
-    """The varying-mesh-axes set of ``x``'s type (empty on jax builds
-    without ``jax.typeof``)."""
-    typeof = getattr(jax, "typeof", None)
-    if typeof is None:
-        return frozenset()
-    return getattr(typeof(x), "vma", frozenset()) or frozenset()
-
-
-__all__ = [
-    "axis_size", "pallas_compiler_params", "pcast_varying",
-    "shard_map_compat", "struct_with_vma", "use_interpret", "vma_of",
-]
+__all__ = ["struct_with_vma", "use_interpret"]

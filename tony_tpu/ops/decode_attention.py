@@ -55,10 +55,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from tony_tpu.ops.compat import (
-    pallas_compiler_params as _CompilerParams,
-    use_interpret as _use_interpret,
-)
+from tony_tpu.ops.compat import use_interpret as _use_interpret
 
 _NEG = -0.7 * float(jnp.finfo(jnp.float32).max)
 
@@ -291,7 +288,7 @@ def _decode_pallas(q, k, v, lengths, *, scale, block):
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B * Hkv, R, hd), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=_use_interpret(),
@@ -358,19 +355,20 @@ def _paged_kernel(len_ref, tbl_ref, q_ref, k_ref, v_ref, o_ref, acc, m_sc,
 def _paged_quant_kernel(len_ref, tbl_ref, q_ref, k_ref, v_ref, ksc_ref,
                         vsc_ref, o_ref, acc, m_sc, l_sc, *, scale, block,
                         kv_heads, rep, queries):
-    """Quantized pools: the (1, 1) scale tiles ride BlockSpecs steered by
-    the same table lookup as their K/V tiles, so the per-block-per-head
-    scale arrives alongside the int8/fp8 payload and the dequant happens in
-    registers — the bf16 cache never exists in HBM."""
+    """Quantized pools: the per-block-per-head scales arrive as whole
+    ``[B * Hkv, M]`` float32 tables in SMEM (gathered through the block
+    table by the caller — a (1, 1) VMEM tile per scale breaks the TPU's
+    (8, 128) tiling rule), so tile (i, j)'s scale is one scalar load and
+    the dequant happens in registers — the bf16 cache never exists in HBM."""
     i, j = pl.program_id(0), pl.program_id(1)
     nb = pl.num_programs(1)
     row_len = len_ref[i // kv_heads]
 
     def read_kv():
-        k = (k_ref[0, 0].astype(jnp.float32) * ksc_ref[0, 0]).astype(
+        k = (k_ref[0, 0].astype(jnp.float32) * ksc_ref[i, j]).astype(
             q_ref.dtype
         )
-        v = (v_ref[0, 0].astype(jnp.float32) * vsc_ref[0, 0]).astype(
+        v = (v_ref[0, 0].astype(jnp.float32) * vsc_ref[i, j]).astype(
             q_ref.dtype
         )
         return k, v
@@ -386,8 +384,9 @@ def _paged_pallas(q, k, v, lengths, tables, *, scale, k_scale=None,
     """Grid (B * Hkv, M): the table rides as scalar prefetch and its values
     steer the K/V BlockSpec index map, so each tile's DMA fetches the
     physical block the row's table names (no gather materialised). With
-    ``k_scale``/``v_scale`` ``[P, Hkv]`` the same table-steered index map
-    carries each tile's scale scalar in as a (1, 1) block."""
+    ``k_scale``/``v_scale`` ``[P, Hkv]`` the scales of the blocks the table
+    names are gathered here (``B * Hkv * M`` floats — the size of the
+    attention work, not of the pool) and ride whole in SMEM."""
     B, G, H, hd = q.shape
     Hkv, blk = k.shape[1], k.shape[2]
     rep = H // Hkv
@@ -406,12 +405,9 @@ def _paged_pallas(q, k, v, lengths, tables, *, scale, k_scale=None,
             ),
         )
 
-    def scale_spec():
-        return pl.BlockSpec(
-            (1, 1),
-            lambda i, j, ln, tb, kv_heads=Hkv: (
-                tb[i // kv_heads, j], i % kv_heads
-            ),
+    def row_scales(pool):  # [P, Hkv] -> [B * Hkv, M], row i = (b, kv head)
+        return pool[tables].transpose(0, 2, 1).reshape(B * Hkv, nb).astype(
+            jnp.float32
         )
 
     in_specs = [
@@ -422,8 +418,8 @@ def _paged_pallas(q, k, v, lengths, tables, *, scale, k_scale=None,
     operands = [qf, k, v]
     kernel = _paged_kernel
     if quant:
-        in_specs += [scale_spec(), scale_spec()]
-        operands += [k_scale, v_scale]
+        in_specs += [pl.BlockSpec(memory_space=pltpu.SMEM)] * 2
+        operands += [row_scales(k_scale), row_scales(v_scale)]
         kernel = _paged_quant_kernel
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -443,7 +439,7 @@ def _paged_pallas(q, k, v, lengths, tables, *, scale, k_scale=None,
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B * Hkv, R, hd), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=_use_interpret(),
@@ -494,7 +490,7 @@ def decode_attention(
     only): the pools hold int8 or fp8 payloads quantized per physical
     block per kv-head (serve/cache.py); both impls dequantize each tile
     inline — scan gathers the scale row next to the block gather, pallas
-    threads the scale pools through the same table-steered index map.
+    reads the table-gathered scales as scalars from SMEM.
     """
     squeeze = q.ndim == 3
     if squeeze:

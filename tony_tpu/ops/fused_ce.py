@@ -49,18 +49,44 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from tony_tpu.ops.compat import (
-    pallas_compiler_params as _CompilerParams,
-    shard_map_compat as _shard_map,
     struct_with_vma as _struct,
     use_interpret as _use_interpret,
 )
 
 _NEG = -0.7 * float(jnp.finfo(jnp.float32).max)
 
-# pallas tile defaults (clipped to the actual shapes); 512x512 keeps the
-# fp32 accumulators + one W block + one h block well under VMEM at D=2048
+# pallas tile defaults (clipped to the actual shapes)
 _BLOCK_N = 512
 _BLOCK_V = 512
+
+# Mosaic's default scoped-VMEM budget is 16 MiB, which the 512x512 tiles
+# overrun from D=2048 up (the v5e compiler: 27.4 MiB for the backward at
+# D=2048, 16.8 MiB for the forward at D=4096). The chip has 128 MiB of
+# VMEM; each call asks for what its tiles need, capped below that.
+_VMEM_CAP = 100 * 2**20
+
+
+def _vmem_limit(blk_n: int, blk_v: int, D: int, itemsize: int) -> int:
+    """Scoped-VMEM bytes for the largest of the three kernels (dh/dW) at
+    these tiles. With h = blk_n*D, w = D*blk_v elements:
+
+    - pipelined blocks, double-buffered: 2 * (h + w + max(h, w)) * itemsize
+      (inputs h and W, output dh or dW)
+    - fp32 accumulator scratch: max(h, w) * 4
+    - fp32 temporaries in the body (h and W upcast, the matmul result
+      before it is added): (h + w + max(h, w)) * 4
+    - the [blk_n, blk_v] fp32 logits / p / dlogits / mask tiles: 4 * n*v * 4
+
+    D=2048 -> 32 MiB, D=4096 -> 64 MiB at the default tiles. Past the cap
+    (D=8192 at these tiles) the compiler refuses with its own VMEM error:
+    pass smaller ``ce_block_n``/``ce_block_v``."""
+    h, w = blk_n * D, D * blk_v
+    out = max(h, w)
+    need = (
+        2 * (h + w + out) * itemsize + out * 4 + (h + w + out) * 4
+        + 4 * blk_n * blk_v * 4
+    )
+    return min(need, _VMEM_CAP)
 
 
 # --- scan (XLA) implementation ------------------------------------------------
@@ -302,8 +328,9 @@ def _pallas_fwd(h, w, tgt, blk_n, blk_v):
             pltpu.VMEM((blk_n, 1), jnp.float32),
             pltpu.VMEM((blk_n, 1), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_vmem_limit(blk_n, blk_v, D, h.dtype.itemsize),
         ),
         interpret=_use_interpret(),
     )(h, w, tgt2)
@@ -316,6 +343,10 @@ def _pallas_bwd(h, w, tgt, lse, g, blk_n, blk_v):
     blk_n, blk_v = min(blk_n, N), min(blk_v, V)
     ni, nv = pl.cdiv(N, blk_n), pl.cdiv(V, blk_v)
     tgt2, lse2, g2 = tgt.reshape(1, N), lse.reshape(1, N), g.reshape(1, N)
+    params = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "arbitrary"),
+        vmem_limit_bytes=_vmem_limit(blk_n, blk_v, D, h.dtype.itemsize),
+    )
 
     hspec, wspec, rowspec = _pallas_specs(blk_n, blk_v, D)
     dh = pl.pallas_call(
@@ -325,9 +356,7 @@ def _pallas_bwd(h, w, tgt, lse, g, blk_n, blk_v):
         out_specs=[hspec],
         out_shape=[_struct((N, D), h.dtype, h, w, g)],
         scratch_shapes=[pltpu.VMEM((blk_n, D), jnp.float32)],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")
-        ),
+        compiler_params=params,
         interpret=_use_interpret(),
     )(h, w, tgt2, lse2, g2)[0]
 
@@ -341,9 +370,7 @@ def _pallas_bwd(h, w, tgt, lse, g, blk_n, blk_v):
         out_specs=[wspec_t],
         out_shape=[_struct((D, V), w.dtype, h, w, g)],
         scratch_shapes=[pltpu.VMEM((D, blk_v), jnp.float32)],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")
-        ),
+        compiler_params=params,
         interpret=_use_interpret(),
     )(h, w, tgt2, lse2, g2)[0]
     return dh, dw
@@ -458,7 +485,7 @@ def sharded_fused_ce_tokens(h, w, targets, cfg=None, **kwargs) -> jax.Array:
     batch = tuple(a for a in ("dp", "fsdp", "ep") if a in axes) or None
     seq = "sp" if "sp" in axes else None
     spec = P(batch, seq)
-    return _shard_map(
+    return jax.shard_map(
         lambda a, b, c: fused_ce_tokens(a, b, c, cfg, **kwargs),
         mesh=mesh,
         in_specs=(P(batch, seq, None), P(), spec),

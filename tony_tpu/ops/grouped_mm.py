@@ -42,7 +42,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from tony_tpu.ops.compat import (
-    pallas_compiler_params as _CompilerParams,
     struct_with_vma as _struct,
     use_interpret as _use_interpret,
 )
@@ -131,7 +130,7 @@ def _gmm_pallas_call(x, w, tile_group, block_cols):
         _gmm_kernel,
         grid_spec=grid_spec,
         out_shape=_struct((x.shape[0], n), x.dtype, x, w),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")
         ),
         interpret=_use_interpret(),
@@ -179,7 +178,7 @@ def _gmm_dx_call(dy, w, tile_group, block_cols):
         _gmm_dx_kernel,
         grid_spec=grid_spec,
         out_shape=_struct((dy.shape[0], d), dy.dtype, dy, w),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "parallel", "arbitrary")
         ),
         interpret=_use_interpret(),
@@ -230,7 +229,7 @@ def _gmm_dw_call(x, dy, tile_group, n_groups, block_cols):
         _gmm_dw_kernel,
         grid_spec=grid_spec,
         out_shape=_struct((n_groups, d, n), jnp.float32, x, dy),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=_use_interpret(),

@@ -19,19 +19,19 @@ keep total traffic exactly equal to the single psum (disjoint row blocks)
 and keep every shape static. Each chunk's psum still combines that chunk's
 per-expert-group partials across the ep shards.
 
-``overlapped_combine`` is a ``custom_vjp`` so the backward is the matching
-decomposed collective: the transpose of a per-chunk psum of disjoint row
-slices is a per-chunk psum of the corresponding COTANGENT slices — never
-one refused full-width collective. The boundary contract (probed on this
-jax line, ``check_rep=False``): shard_map delivers an ep-unmentioned
-output's cotangent split 1/ep per shard and itself psums returned
-cotangents over each input's unmentioned axes. So the backward psums each
-incoming cotangent chunk once (restoring the true value, exactly how AD
-transposes the plain path's single psum) and returns everything else
-LOCAL: the ep-sharded expert weights keep their shard's grad, the
-ep-replicated token/weight cotangents are per-shard contributions the
-boundary reduces, and the int route tensor takes ``float0`` zeros (the
-`ops.grouped_mm` idiom).
+``overlapped_combine`` is a ``custom_vjp`` so the backward keeps the
+decomposition: per chunk, never one full-width collective. Under
+``shard_map``'s varying-mesh-axes typing (``check_vma``, the default) a
+custom_vjp must hand back each cotangent typed like its primal. The
+forward psum makes a chunk's output ep-invariant, so its cotangent slice
+arrives whole on every shard and the psum's transpose is a ``pcast`` back
+to ep-varying — no collective. The chunk FFN's own cotangents vary over
+every axis its inputs meet, so each is summed onto its primal's type
+(`_typed_like`): the token/weight rows over ``ep`` (one psum per chunk,
+overlapping the next chunk's re-linearised FFN), the ep-sharded expert
+weights over the data axes (their own shard's grad, accumulated over
+chunks first). This is what AD does on the plain path, decomposed. The int
+route tensor takes ``float0`` zeros (the `ops.grouped_mm` idiom).
 
 The two impls follow the repo pattern: ``'scan'`` drives the chunk FFN's
 grouped matmuls through the pure-XLA lax.scan kernel (CPU/shard_map-safe
@@ -157,32 +157,40 @@ def _overlapped_combine_fwd(ffn_fn, axis_name, n_chunks, w1, w3, w2, flat,
     return y, (w1, w3, w2, flat, sel, weight)
 
 
+def _typed_like(ct: jax.Array, primal: jax.Array) -> jax.Array:
+    """Give a cotangent its primal's varying-mesh-axes type, as shard_map's
+    vma typing requires of a custom_vjp's outputs: axes the cotangent
+    varies over and the primal does not are summed out (the transpose of
+    the implicit invariant->varying cast the forward made at the primal's
+    first use — AD does exactly this on the plain path)."""
+    extra = jax.typeof(ct).vma - jax.typeof(primal).vma
+    return jax.lax.psum(ct, tuple(sorted(extra))) if extra else ct
+
+
 def _overlapped_combine_bwd(ffn_fn, axis_name, n_chunks, res, g):
     w1, w3, w2, flat, sel, weight = res
     dw1 = dw3 = dw2 = None
     dflat, dweight = [], []
     for s in _chunk_slices(flat.shape[0], n_chunks):
-        # the transpose of a chunk's forward psum is a psum of that
-        # chunk's cotangent slice — the boundary splits an ep-unmentioned
-        # output's cotangent 1/ep across shards (probed, check_rep=False),
-        # and this per-chunk collective restores the full value, exactly
-        # how AD transposes the plain path's single psum, decomposed
-        g_c = jax.lax.psum(g[s], axis_name)
+        # the forward psum made each chunk's output ep-invariant, so its
+        # cotangent slice arrives whole on every shard: the psum's
+        # transpose is the cast back to ep-varying, no collective
+        g_c = jax.lax.pcast(g[s], (axis_name,), to="varying")
         chunk = partial(_chunk_primal, ffn_fn, sel[s])
         _, vjp_fn = jax.vjp(chunk, w1, w3, w2, flat[s], weight[s])
         dw1_c, dw3_c, dw2_c, dfl_c, dwg_c = vjp_fn(g_c)
-        # everything below stays LOCAL: the ep-sharded expert weights keep
-        # their own shard's grad (accumulated over chunks), and the ep-
-        # replicated token/weight cotangents are per-shard contributions
-        # the boundary itself psums over ep — adding our own psum here
-        # would double-count it
+        # the ep-sharded expert weights keep their own shard's grad,
+        # accumulated over chunks; the ep-invariant token/weight rows get
+        # every shard's contribution — one per-chunk psum over ep, which
+        # overlaps the next chunk's re-linearised FFN like the forward's
         dw1 = dw1_c if dw1 is None else dw1 + dw1_c
         dw3 = dw3_c if dw3 is None else dw3 + dw3_c
         dw2 = dw2_c if dw2 is None else dw2 + dw2_c
-        dflat.append(dfl_c)
-        dweight.append(dwg_c)
+        dflat.append(_typed_like(dfl_c, flat))
+        dweight.append(_typed_like(dwg_c, weight))
     dsel = np.zeros(sel.shape, jax.dtypes.float0)
-    return (dw1, dw3, dw2, jnp.concatenate(dflat, axis=0), dsel,
+    return (_typed_like(dw1, w1), _typed_like(dw3, w3), _typed_like(dw2, w2),
+            jnp.concatenate(dflat, axis=0), dsel,
             jnp.concatenate(dweight, axis=0))
 
 

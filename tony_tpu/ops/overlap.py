@@ -51,12 +51,10 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
 from tony_tpu.ops.compat import (
-    axis_size as _axis_size,
-    pallas_compiler_params as _CompilerParams,
-    shard_map_compat as _shard_map,
     struct_with_vma as _struct_with_vma,
     use_interpret as _use_interpret,
 )
@@ -95,7 +93,7 @@ def _chunk_mm(a: jax.Array, b: jax.Array, impl: str,
             ],
             out_specs=pl.BlockSpec((M, bn), lambda j: (0, j)),
             out_shape=_struct_with_vma((M, N), jnp.float32, a, b),
-            compiler_params=_CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel",)
             ),
             interpret=_use_interpret(),
@@ -122,7 +120,7 @@ def _ring_contract(x2, w_loc, axis_name, impl):
     flight on the ring: the ppermute and the matmul have no data
     dependency, so XLA schedules them concurrently.
     """
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     Dl, N = w_loc.shape
     perm = [(i, (i + 1) % n) for i in range(n)]
@@ -146,7 +144,7 @@ def _ring_concat(x2, w_loc, axis_name, impl):
     x2 [M, D], w_loc [D, N/n] this device's column shard; returns the full
     [M, N] with each column block written as its shard arrives.
     """
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     Nl = w_loc.shape[1]
     perm = [(i, (i + 1) % n) for i in range(n)]
@@ -174,7 +172,7 @@ def _ring_reduce_scatter(partial_fn, shape, axis_name, *operands):
     final hop lands shard ``my`` home fully reduced). Each hop's send
     overlaps the next partial product's matmul.
     """
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     perm = [(i, (i + 1) % n) for i in range(n)]
     acc0 = partial_fn((my - 1) % n) + (
@@ -266,7 +264,7 @@ def matmul_reduce_scatter_local(x, g, axis_name="fsdp", scatter_dim=0,
     _check_impl(impl)
     x2, g2 = _flat2(x), _flat2(g)
     D, N = x2.shape[1], g2.shape[1]
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     if scatter_dim == 0:
         Dl = D // n
 
@@ -347,7 +345,7 @@ def overlap_matmul(x: jax.Array, w: jax.Array, *, gather_dim: int,
 
     x_spec = P(axis_name, *([None] * (x.ndim - 1)))
     w_spec = P(axis_name, None) if gather_dim == 0 else P(None, axis_name)
-    return _shard_map(
+    return jax.shard_map(
         f, mesh=mesh,
         in_specs=(x_spec, w_spec),
         out_specs=x_spec,

@@ -32,11 +32,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from tony_tpu.ops.compat import (
-    pallas_compiler_params as _CompilerParams,
-    use_interpret as _use_interpret,
-)
+from tony_tpu.ops.compat import use_interpret as _use_interpret
 
 WEIGHT_QMAX = 127.0
 
@@ -104,7 +102,7 @@ def _pallas_impl(x2, wq, scale, bn):
         ],
         out_specs=pl.BlockSpec((Bx, bn), lambda j: (0, j)),
         out_shape=jax.ShapeDtypeStruct((Bx, N), x2.dtype),
-        compiler_params=_CompilerParams(dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=_use_interpret(),
     )(x2, wq, scale[None, :].astype(jnp.float32))
     return out

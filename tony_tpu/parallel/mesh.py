@@ -29,7 +29,7 @@ from dataclasses import dataclass, field
 import jax
 import numpy as np
 from jax.experimental import mesh_utils
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh, get_abstract_mesh
 
 # Canonical axis order: slice-crossing / outermost first.
 MESH_AXES = ("dp", "pp", "fsdp", "ep", "tp", "sp")
@@ -92,22 +92,6 @@ def inside_manual_region() -> bool:
     ``jax.sharding.use_mesh`` context also sets one, with Auto/Explicit axis
     types — only Manual axes mean an enclosing shard_map region that shardy
     forbids re-binding collective axes inside."""
-    try:
-        from jax.sharding import AxisType, get_abstract_mesh
-    except ImportError:
-        # old jax (no abstract-mesh typing): shard_map binds its manual
-        # axes into the tracing axis env, so a non-empty env means we are
-        # tracing inside one (also true under pmap/named vmap — both want
-        # the region-local path here anyway). The accessor lives in
-        # jax._src.core on this line (jax.core only has a deprecation
-        # stub for it).
-        try:
-            from jax._src.core import get_axis_env
-        except ImportError:
-            return False
-        env = get_axis_env()
-        return bool(getattr(env, "axis_sizes", None))
-
     mesh = get_abstract_mesh()
     if mesh is None or not mesh.shape_tuple:
         return False
@@ -152,6 +136,11 @@ def build_mesh(shape: MeshShape | None = None, devices: list | None = None) -> M
     try:
         dev_array = mesh_utils.create_device_mesh(shape.sizes, devices=devices)
     except (ValueError, AssertionError):
+        if devices[0].platform == "tpu":
+            # real chips carry topology metadata: a layout JAX cannot place
+            # on it is a config error, not something to paper over with a
+            # raveled order that puts far chips on the inner axes
+            raise
         # Virtual/CPU device sets lack topology metadata; fall back to raveled order.
         dev_array = np.asarray(devices).reshape(shape.sizes)
     return Mesh(dev_array, MESH_AXES)

@@ -314,7 +314,6 @@ def _moe_grouped_ep(params: dict[str, Any], flat: jax.Array, cfg: MoEConfig,
 
     from jax.sharding import PartitionSpec as P
 
-    from tony_tpu.ops.compat import shard_map_compat
     from tony_tpu.ops.moe_overlap import overlap_chunks, overlapped_combine
 
     ep = int(mesh.shape["ep"])
@@ -350,7 +349,7 @@ def _moe_grouped_ep(params: dict[str, Any], flat: jax.Array, cfg: MoEConfig,
         return jax.lax.psum(y, "ep")
     wspec = P("ep", None, None)
     bspec = P(batch, None)
-    y = shard_map_compat(
+    y = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(wspec, wspec, wspec, bspec, bspec, bspec),

@@ -27,12 +27,10 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from tony_tpu.ops.compat import (
-    axis_size as _axis_size,
-    pcast_varying as _pcast_varying,
-    shard_map_compat as _shard_map,
-    vma_of as _vma_of,
-)
+
+def _pcast_varying(x, axis_names):
+    return lax.pcast(x, tuple(axis_names), to="varying")
+
 
 StageFn = Callable[[Any, jax.Array], jax.Array]
 
@@ -57,7 +55,7 @@ def pipeline_local(
     ``(out, aux)`` where aux matches the sequential trainer's
     sum-over-layers, mean-over-batch scalar.
     """
-    n_stages = _axis_size(axis_name)
+    n_stages = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     M = x.shape[0]
     n_ticks = M + n_stages - 1
@@ -140,7 +138,7 @@ def pipeline_apply(
         params = jax.tree.map(lambda a: a[0], params)  # drop unit stage dim
         return pipeline_local(stage_fn, params, xs, axis_name=axis_name)
 
-    return _shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(param_specs, P()),
@@ -234,7 +232,7 @@ def _run_1f1b(stage_params, head_params, xs, targets,
             axis_name=axis_name, n_stages=P_, M=M,
         )
 
-    return _shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(layer_specs, P(), P(), P()),
@@ -257,7 +255,7 @@ def _1f1b_local(stage_params, head_params, xs, targets, *,
 
     def vary(a):
         # idempotent: zeros_like of pp-sharded params is already varying
-        if axis_name in _vma_of(a):
+        if axis_name in jax.typeof(a).vma:
             return a
         return _pcast_varying(a, (axis_name,))
 

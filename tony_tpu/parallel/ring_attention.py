@@ -25,7 +25,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from tony_tpu.ops.compat import axis_size as _axis_size, shard_map_compat as _shard_map
 
 _NEG = -0.7 * float(jnp.finfo(jnp.float32).max)
 
@@ -57,7 +56,7 @@ def ring_attention_local(
     chunk (chunk index == its position along ``axis_name``). Returns the
     attention output for the local queries, exact (not approximate).
     """
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     B, S, H, D = q.shape
     if scale is None:
@@ -140,7 +139,7 @@ def _check_blocks(S: int, blk_q: int, blk_k: int) -> tuple[int, int]:
 def _ring_flash_fwd_local(q, k, v, axis_name, blk_q, blk_k):
     from tony_tpu.ops.attention import flash_fwd_pass
 
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     B, S, H, D = q.shape
     Hkv = k.shape[2]
@@ -202,7 +201,7 @@ def _ring_flash_bwd_rule(axis_name, blk_q, blk_k, res, g):
     from tony_tpu.ops.attention import flash_dq_pass, flash_dkv_pass
 
     q, k, v, out, lse = res
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     B, S, H, D = q.shape
     Hkv = k.shape[2]
@@ -284,7 +283,7 @@ def make_ring_attention(
                 "(e.g. a pp pipeline stage); use attention_impl='flash' or "
                 "'dot' with pp, or drop pp and shard the sequence with sp"
             )
-        return _shard_map(
+        return jax.shard_map(
             lambda a, b, c: inner(a, b, c),
             mesh=mesh,
             in_specs=(spec, spec, spec),
@@ -339,7 +338,7 @@ def make_ring_flash_attention(mesh: Mesh, *, axis_name: str = "sp"):
         # stays on — same vma discipline as the dense ring path.
         from tony_tpu.ops.attention import _use_interpret
 
-        return _shard_map(
+        return jax.shard_map(
             lambda a, b, c: ring_flash_attention_local(
                 a, b, c, axis_name, blk_q, blk_k
             ),

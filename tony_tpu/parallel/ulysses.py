@@ -20,7 +20,6 @@ from jax import lax
 from jax.sharding import Mesh
 
 from tony_tpu.models.llama import dot_attention as _causal_attention
-from tony_tpu.ops.compat import axis_size as _axis_size, shard_map_compat as _shard_map
 
 
 def ulysses_attention_local(
@@ -37,7 +36,7 @@ def ulysses_attention_local(
     to [B, S, H_local, D] (full sequence, heads split), runs exact attention,
     and re-shards back. ``attn(q, k, v)`` is the local attention function.
     """
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     H = q.shape[2]
     if H % n:
         raise ValueError(f"n_heads={H} not divisible by {axis_name} size {n}")
@@ -68,7 +67,7 @@ def make_ulysses_attention(mesh: Mesh, *, axis_name: str = "sp"):
                 "region (e.g. a pp pipeline stage); use attention_impl="
                 "'flash' or 'dot' with pp, or drop pp"
             )
-        return _shard_map(
+        return jax.shard_map(
             lambda a, b, c: inner(a, b, c),
             mesh=mesh,
             in_specs=(spec, spec, spec),

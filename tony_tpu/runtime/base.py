@@ -82,14 +82,20 @@ class Runtime:
                 "true" if config.get_bool(Keys.RESTART_RESUME_FROM_CHECKPOINT, True)
                 else "false"
             )
-        # Persistent XLA compilation cache: the single biggest submit->
-        # first-step lever (docs/PERF.md latency section) — resubmits and
-        # elastic gang restarts of the same job skip compile entirely.
-        # fit() applies it; default on, per-user shared dir.
+        # Persistent XLA compilation cache: resubmits and gang restarts of
+        # the same job load their executables instead of recompiling.
+        # fit() and the serve gang host apply it (utils/compile_cache.py
+        # holds the directory rule: JAX_COMPILATION_CACHE_DIR from outside
+        # wins, else this key, else <checkout>/.jax_cache). Default on.
         if config.get_bool(Keys.TRAIN_JAX_CACHE, True):
-            env["TONY_JAX_CACHE_DIR"] = config.get_str(
-                Keys.TRAIN_JAX_CACHE_DIR, ""
-            ) or os.path.expanduser(os.path.join("~", ".tony-tpu", "jax_cache"))
+            from tony_tpu.utils.compile_cache import (
+                ENV_JOB_CACHE_DIR, default_cache_dir,
+            )
+
+            env[ENV_JOB_CACHE_DIR] = (
+                config.get_str(Keys.TRAIN_JAX_CACHE_DIR, "")
+                or default_cache_dir()
+            )
         # One flag to get per-host traces (SURVEY.md section 5 "Tracing"):
         # the profiler server must live in the process doing the compute, so
         # the executor exports the intent and fit() starts it.

@@ -74,10 +74,6 @@ def kv_quant_spec(kv_dtype: str) -> tuple[jnp.dtype, float]:
     if kv_dtype == "int8":
         return jnp.dtype(jnp.int8), 127.0
     if kv_dtype == "fp8_e4m3":
-        if not hasattr(jnp, "float8_e4m3fn"):
-            raise ValueError(
-                "kv_dtype 'fp8_e4m3' needs a jax build with float8_e4m3fn"
-            )
         return jnp.dtype(jnp.float8_e4m3fn), 448.0
     raise ValueError(
         f"unknown kv quant dtype {kv_dtype!r} (expected one of "

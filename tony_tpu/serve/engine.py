@@ -324,7 +324,7 @@ class Engine:
             # tokens/s/chip divides by the devices actually backing the
             # model (a sharded-params engine must not overreport per-chip)
             n_chips = max(1, len(jax.tree.leaves(params)[0].sharding.device_set))
-        except Exception:
+        except AttributeError:  # params are not jax arrays
             n_chips = 1
         self.metrics = DecodeMetrics(n_chips=n_chips)
         # paged pool + per-slot block tables (serve/cache.py): the table is
@@ -1623,20 +1623,15 @@ _aot_prefill_cache: dict = {}
 
 def _aot_compile(fn, avals, key, name, ledger, cache=_aot_prefill_cache):
     """Shared AOT-with-ledger path: lower from ``avals`` (live arrays or
-    ShapeDtypeStructs), journal the measured cost/memory plan under
-    ``name``, fall back to lazy jit dispatch on any compile failure."""
+    ShapeDtypeStructs) and journal the measured cost/memory plan under
+    ``name``. A step the compiler refuses raises here."""
     hit = cache.get(key)
     if hit is not None:
         return hit
     t0 = time.perf_counter()
-    try:
-        with ledger.label(name):
-            compiled = fn.lower(*avals).compile()
-        ledger.record_aot(name, compiled, time.perf_counter() - t0)
-    except Exception:
-        log.debug("AOT compile of %s failed; using lazy jit", name,
-                  exc_info=True)
-        compiled = fn
+    with ledger.label(name):
+        compiled = fn.lower(*avals).compile()
+    ledger.record_aot(name, compiled, time.perf_counter() - t0)
     if len(cache) < 512:
         cache[key] = compiled
     return compiled
@@ -1654,9 +1649,9 @@ def _aot_decode(cfg: LlamaConfig, decode_impl: str, kv_block: int,
                quant_kv, quant_weights,
                cache.k.shape, str(cache.k.dtype), table.shape,
                hash(shard), shard)
-    except Exception:
-        # unhashable sharding (exotic platform): lazy jit still works and
-        # still shares compiles process-wide
+    except (AttributeError, TypeError):
+        # params without a hashable sharding (plain numpy arrays): lazy jit
+        # still works and still shares compiles process-wide
         return fn
     name = (f"serve.decode[slots={state.last_tok.shape[0]},"
             f"blocks={cache.k.shape[1]},attended={table.shape[1]}]")
@@ -1679,7 +1674,7 @@ def _aot_prefill(cfg: LlamaConfig, bucket: int, max_top_k: int, params,
     try:
         shard = jax.tree.leaves(params)[0].sharding
         key = ("prefill", cfg, bucket, max_top_k, hash(shard), shard)
-    except Exception:
+    except (AttributeError, TypeError):  # params are not jax arrays
         return fn
     # live params (their real shardings bake into the executable — a
     # sharded-params engine must not compile against default layouts),
@@ -1698,7 +1693,7 @@ def _aot_tail_prefill(cfg: LlamaConfig, tb: int, ctx: int, max_top_k: int,
     try:
         shard = jax.tree.leaves(params)[0].sharding
         key = ("tail", cfg, tb, ctx, max_top_k, hash(shard), shard)
-    except Exception:
+    except (AttributeError, TypeError):  # params are not jax arrays
         return fn
     kv = _sds((cfg.n_layers, 1, ctx, cfg.n_kv_heads, cfg.head_dim), cfg.dtype)
     avals = (
@@ -2159,7 +2154,7 @@ def _aot_spec_decode(cfg: LlamaConfig, decode_impl: str, kv_block: int,
                monitors, quant_kv, quant_weights,
                cache.k.shape, str(cache.k.dtype), table.shape,
                hash(shard), shard)
-    except Exception:
+    except (AttributeError, TypeError):  # params are not jax arrays
         return fn
     name = (f"serve.decode_spec[slots={S},blocks={cache.k.shape[1]},"
             f"attended={table.shape[1]},k={draft_k}]")

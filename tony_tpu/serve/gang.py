@@ -243,6 +243,7 @@ class DecodeHostService(ServeRpcServicer):
         from tony_tpu.obs.reporter import MetricsReporter
 
         self._reporter = MetricsReporter()
+        self._first_push_extra: dict[str, float] = {}
         self._last_push = 0.0
         # live per-request plumbing, owned by the engine thread; the lock
         # only guards the dict shape (handler threads read membership for
@@ -266,6 +267,18 @@ class DecodeHostService(ServeRpcServicer):
             raise
         self._started.set()
         eng = self.engine
+        # what this host came up on, said once in the log and pushed with
+        # the first stats sample (obs.metrics.device_samples): a decode
+        # host that fell to the CPU must be visible in the job history
+        from tony_tpu.obs.metrics import device_identity, device_samples
+
+        identity = device_identity()
+        log.info(
+            "%s devices: platform=%s kind=%s count=%d", self.host_id,
+            identity["platform"], identity["device_kind"],
+            identity["device_count"],
+        )
+        self._first_push_extra = device_samples(identity)
         while not self._stop.is_set():
             eng = self._apply_mailbox(eng)
             with self._streams_lock:
@@ -305,7 +318,8 @@ class DecodeHostService(ServeRpcServicer):
         if recorder is not None:
             recorder.force_sample()
         if self._reporter.active:
-            self._reporter.push(eng.stats_snapshot())
+            self._reporter.push({**eng.stats_snapshot(), **self._first_push_extra})
+            self._first_push_extra = {}
 
     def _apply_mailbox(self, eng: Engine) -> Engine:
         while True:
@@ -672,6 +686,13 @@ def main() -> int:
 
     profile.install_from_env()
     settings = _load_settings()
+    # persistent compile cache, same rule as fit(): a restarted or
+    # resubmitted gang host loads its prefill/decode executables
+    from tony_tpu.utils.compile_cache import enable_from_job_env
+
+    cache_dir = enable_from_job_env()
+    if cache_dir:
+        log.info("persistent compile cache: %s", cache_dir)
     job_name = os.environ.get("TONY_JOB_NAME", settings.job_type)
     host_id = f"{job_name}:{os.environ.get('TONY_TASK_INDEX', '0')}"
     # pool membership comes from the container's task type: a heterogeneous

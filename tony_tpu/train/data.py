@@ -197,6 +197,9 @@ def make_batches(
 def _make_batches_raw(
     cfg: DataConfig, sharding: NamedSharding | None = None, start_step: int = 0
 ) -> Iterator[Batch]:
+    import logging
+
+    log = logging.getLogger(__name__)
     if cfg.path:
         if cfg.native:
             from tony_tpu.train import native_loader
@@ -205,6 +208,7 @@ def _make_batches_raw(
                 # in a gang, every process must take this same branch; a
                 # process whose build fails raises below instead of silently
                 # mixing shuffled and sequential sampling in one global batch
+                log.info("data loader: native (tony_tpu/native/tonyloader.cpp)")
                 return native_batches(cfg, sharding, start_step)
             if jax.process_count() > 1:
                 raise RuntimeError(
@@ -213,13 +217,13 @@ def _make_batches_raw(
                     "would mix sampling schemes. Install g++ everywhere or "
                     "set DataConfig(native=False)."
                 )
-            import logging
-
-            logging.getLogger(__name__).warning(
+            log.warning(
                 "native loader unavailable; falling back to sequential "
                 "mmap windows (different sampling + resume stream)"
             )
+        log.info("data loader: numpy mmap windows")
         return mmap_batches(cfg, sharding, start_step)
+    log.info("data loader: synthetic tokens")
     return synthetic_batches(cfg, sharding, start_step)
 
 

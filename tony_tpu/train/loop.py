@@ -15,7 +15,7 @@ Startup and the steady-state loop are overlapped (docs/PERF.md "Overlap"):
   thread, concurrently with sharded state init, checkpoint restore, and
   input warmup — registered->first-step pays max(compile, restore,
   first-batch) instead of their sum, compounding with the persistent XLA
-  cache (TONY_JAX_CACHE_DIR).
+  cache (utils/compile_cache.py).
 - **device prefetch**: with DataConfig.prefetch > 0 (default 2) the batch
   stream runs on a background thread (train/prefetch.py), so host batch
   synthesis + H2D placement for step N+1 overlap the device's step N.
@@ -43,7 +43,7 @@ from jax.sharding import NamedSharding
 from tony_tpu.models.llama import LlamaConfig, train_flops_per_token
 from tony_tpu.obs import hbm, health, profile, series, slo, trace
 from tony_tpu.obs import compiles as compile_ledger
-from tony_tpu.obs.metrics import StepTimer, chip_peak_flops
+from tony_tpu.obs.metrics import StepTimer, device_identity, device_samples
 from tony_tpu.obs.registry import HistogramWindow, Registry, snapshot_to_app_dir
 from tony_tpu.parallel.mesh import MeshShape, build_mesh
 from tony_tpu.parallel.sharding import DEFAULT_RULES, Rules, spec_for
@@ -56,6 +56,7 @@ from tony_tpu.train.trainer import (
     make_train_step,
     train_state_avals,
 )
+from tony_tpu.utils import compile_cache
 
 log = logging.getLogger(__name__)
 
@@ -197,10 +198,7 @@ def _start_async_host_copy(metrics: dict) -> None:
     for key in ("loss", "grad_norm"):
         arr = metrics.get(key)
         if hasattr(arr, "copy_to_host_async"):
-            try:
-                arr.copy_to_host_async()
-            except Exception:
-                pass
+            arr.copy_to_host_async()
 
 
 class _Elastic:
@@ -396,6 +394,14 @@ class _Elastic:
 
 def _fit(cfg: FitConfig, fit_span=trace.NOOP_SPAN) -> dict:
     jax_tpu.initialize()  # no-op outside a tony-tpu job
+    # what this process came up on, said once: a job that fell to the CPU
+    # must be visible in the log and (first metrics push below) the job
+    # history, not only slow
+    identity = device_identity()
+    log.info(
+        "devices: platform=%(platform)s kind=%(device_kind)s "
+        "count=%(device_count)d", identity,
+    )
     # always-on compile journal (obs/compiles.py): every XLA backend
     # compile during this run is an entry; the shutdown summary and
     # `tony compiles <app_id>` report from it
@@ -427,8 +433,7 @@ def _fit(cfg: FitConfig, fit_span=trace.NOOP_SPAN) -> dict:
         if cfg.moe_overlap_chunk:
             overrides["moe_overlap_chunk"] = cfg.moe_overlap_chunk
         cfg.model = _replace(cfg.model, **overrides)
-    cache_dir = os.environ.get("TONY_JAX_CACHE_DIR", "")
-    if cache_dir and cfg.elastic_members >= 2:
+    if cfg.elastic_members >= 2:
         # elastic runs re-lower the step per generation; round-tripping
         # those executables through the persistent cache corrupts the
         # process on this jax line (a deserialized executable for a
@@ -436,15 +441,15 @@ def _fit(cfg: FitConfig, fit_span=trace.NOOP_SPAN) -> dict:
         # boundary). The cache's win is submit->first-step; the elastic
         # warm path keeps survivors' executables in memory anyway.
         log.info("elastic fit: persistent XLA cache disabled")
-        cache_dir = ""
-    if cache_dir:
-        # persistent XLA compilation cache (train.jax_cache, default on):
+        compile_cache.disable_compile_cache()
+    else:
+        # persistent XLA compilation cache (train.jax_cache, default on in
+        # a tony job; outside one only when JAX_COMPILATION_CACHE_DIR asks):
         # a resubmitted or gang-restarted job loads its executables instead
-        # of recompiling — the dominant submit->first-step cost on TPU
-        # (docs/PERF.md latency section)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+        # of recompiling
+        cache_dir = compile_cache.enable_from_job_env()
+        if cache_dir:
+            log.info("persistent compile cache: %s", cache_dir)
     if os.environ.get("TONY_PROFILER_PORT"):
         from tony_tpu.obs.profiler import start_server
 
@@ -452,16 +457,16 @@ def _fit(cfg: FitConfig, fit_span=trace.NOOP_SPAN) -> dict:
         # (the local backend) don't collide on the port
         start_server(int(os.environ["TONY_PROFILER_PORT"]) + jax_tpu.process_id())
     reporter = None
-    on_metrics = cfg.on_metrics
-    if on_metrics is None and jax_tpu.in_tony_job():
-        # push step metrics to the AM (TaskMonitor/MetricsRpc pipeline);
-        # pushes are queued + drained by a daemon thread so an AM stall
-        # can never block the step loop
+    sinks = [cfg.on_metrics] if cfg.on_metrics is not None else []
+    if jax_tpu.in_tony_job():
+        # push step metrics to the AM (TaskMonitor/MetricsRpc pipeline)
+        # beside any hook of the user's own; pushes are queued + drained by
+        # a daemon thread so an AM stall can never block the step loop
         from tony_tpu.obs.reporter import MetricsReporter
 
         reporter = MetricsReporter()
         if reporter.active:
-            on_metrics = reporter.push
+            sinks.append(reporter.push)
     el = None
     if cfg.elastic_members >= 2:
         # elastic job: the mesh is a function of the current membership
@@ -516,16 +521,16 @@ def _fit(cfg: FitConfig, fit_span=trace.NOOP_SPAN) -> dict:
                         aot["step"] = step_fn.lower(
                             state_avals, batch_aval, batch_aval
                         ).compile()
-                except Exception:
-                    log.debug(
-                        "compile-ahead failed; jit dispatch compiles lazily",
-                        exc_info=True,
-                    )
+                except Exception as e:
+                    # re-raised on the main thread at the join: a step the
+                    # compiler refuses must fail the job there, not
+                    # resurface later from a lazy jit dispatch
+                    aot["error"] = e
+                    return
             startup["compile_s"] = round(time.perf_counter() - t0, 3)
-            if "step" in aot:
-                # AOT entry point: journal the measured memory plan
-                # (temp/arg/output/code bytes) + cost-analysis FLOPs
-                ledger.record_aot("train.step", aot["step"], startup["compile_s"])
+            # AOT entry point: journal the measured memory plan
+            # (temp/arg/output/code bytes) + cost-analysis FLOPs
+            ledger.record_aot("train.step", aot["step"], startup["compile_s"])
 
         compile_thread = threading.Thread(
             target=_compile_ahead, name="tony-compile-ahead", daemon=True
@@ -562,10 +567,15 @@ def _fit(cfg: FitConfig, fit_span=trace.NOOP_SPAN) -> dict:
         batches = make_batches(cfg.data, batch_sharding, start_step=start_step)
     if compile_thread is not None:
         compile_thread.join()
+        if "error" in aot:
+            close_batches(batches)
+            raise aot["error"]
     compiled_step = aot.get("step")
 
     flops_per_token = train_flops_per_token(cfg.model, cfg.data.seq_len)
     tokens_per_step = cfg.data.global_batch * cfg.data.seq_len
+    # off-chip the MFU is against obs.metrics.NOMINAL_CPU_FLOPS: say so
+    mfu_note = " (nominal CPU peak)" if identity["platform"] == "cpu" else ""
 
     def _emit(snap: dict) -> None:
         """Resolve a log boundary: device sync on the (already in-flight)
@@ -596,11 +606,13 @@ def _fit(cfg: FitConfig, fit_span=trace.NOOP_SPAN) -> dict:
             "grad_norm": round(float(jax.device_get(m["grad_norm"])), 4),
             "host_blocked_ms_per_step": round(timer.host_blocked_ms_per_step, 2),
         }
-        if snap.get("startup"):
+        if snap["startup"] is not None:
             # first step only: the startup-phase breakdown rides the first
             # METRICS push so submit_latency() can report compile vs restore
-            # vs first-batch (am/events.py)
+            # vs first-batch (am/events.py), and the device identity says
+            # what the job actually came up on
             out.update({f"startup_{k}": v for k, v in snap["startup"].items()})
+            out.update(device_samples(identity))
         # HBM usage from the device this process owns (the nvidia-smi
         # sampling analogue; empty on platforms without memory_stats)
         from tony_tpu.obs.tpu_metrics import tpu_metrics_dict
@@ -619,10 +631,10 @@ def _fit(cfg: FitConfig, fit_span=trace.NOOP_SPAN) -> dict:
         if jax.process_index() == 0:
             log.info(
                 "step %(step)d loss=%(loss)s %(tokens_per_sec_per_chip)s tok/s/chip "
-                "mfu=%(mfu)s", out,
+                "mfu=%(mfu)s" + mfu_note, out,
             )
-        if on_metrics:
-            on_metrics(out)
+        for sink in sinks:
+            sink(out)
 
     metrics: dict = {}
     pending = None          # boundary snapshot deferred past the next dispatch

@@ -5,13 +5,16 @@ memory-mapped corpus on a real thread, off the GIL — the trainer's host step
 overlaps with input IO. Built on demand with g++ (pybind11 is not in the
 image; the C ABI + ctypes needs no build-time Python headers).
 
-Falls back cleanly: ``available()`` is False when no compiler/binary exists,
-and train/data.py keeps its pure-numpy path.
+``available()`` is False when no compiler exists or the build fails;
+train/data.py then takes its pure-numpy path and says so. The library is
+built from the source file as it stands and from nothing else: its name
+carries the source's hash, so a binary of any other source is never loaded.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import os
 import subprocess
@@ -23,39 +26,46 @@ import numpy as np
 log = logging.getLogger(__name__)
 
 _SRC = os.path.join(os.path.dirname(__file__), "..", "native", "tonyloader.cpp")
-_LIB_NAME = "libtonyloader.so"
 _lock = threading.Lock()
 _lib: ctypes.CDLL | None = None
 
 
 def _build_dir() -> str:
+    # default: inside the checkout (listed in .gitignore), not under ~
     d = os.environ.get("TONY_NATIVE_DIR") or os.path.join(
-        os.path.expanduser("~"), ".cache", "tony-tpu"
+        os.path.dirname(os.path.abspath(_SRC)), "..", "..", ".native_build"
     )
+    d = os.path.abspath(d)
     os.makedirs(d, exist_ok=True)
     return d
 
 
 def _load() -> ctypes.CDLL | None:
     global _lib
+    if _lib is not None:
+        return _lib
+    src = os.path.abspath(_SRC)
+    try:
+        with open(src, "rb") as f:
+            digest = hashlib.sha1(f.read()).hexdigest()[:12]
+    except OSError:
+        return None
     # the lock's purpose is to serialize the ONE-TIME native build across
     # threads racing the first loader construction; after that it guards a
     # cached-handle read. Holding it across the compile is the design.
     with _lock:
         if _lib is not None:
             return _lib
-        lib_path = os.path.join(_build_dir(), _LIB_NAME)  # graft-lint: disable=GL004
-        src = os.path.abspath(_SRC)
-        if not os.path.exists(src):
-            return None
-        if (not os.path.exists(lib_path)
-                or os.path.getmtime(lib_path) < os.path.getmtime(src)):
+        lib_path = os.path.join(_build_dir(), f"libtonyloader-{digest}.so")  # graft-lint: disable=GL004
+        if not os.path.exists(lib_path):
+            tmp_path = f"{lib_path}.{os.getpid()}.tmp"
             try:
                 subprocess.run(  # graft-lint: disable=GL004
                     ["g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-pthread",
-                     src, "-o", lib_path],
+                     src, "-o", tmp_path],
                     check=True, capture_output=True, timeout=120,
                 )
+                os.replace(tmp_path, lib_path)  # graft-lint: disable=GL004
             except (OSError, subprocess.SubprocessError) as e:
                 log.warning("native loader build failed: %s", e)
                 return None

@@ -20,23 +20,9 @@ import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from tony_tpu.models import llama
-from tony_tpu.ops.compat import pcast_varying as _pcast_varying, shard_map_compat as _shard_map
 from tony_tpu.parallel.sharding import DEFAULT_RULES, Rules, spec_for, tree_shardings
 
 Params = dict[str, Any]
-
-
-def _ensure_partitionable_threefry() -> None:
-    """Partitionable threefry makes jax.random values independent of the
-    mesh/sharding they are generated under (the default on current jax
-    lines; old 0.4.x defaults to False, under which make_train_state's
-    jit-sharded init produced DIFFERENT params per mesh — a pp mesh and
-    its sequential reference trained two different models, and
-    schedule-parity could only fail). Flipped at the trainer entrypoints
-    rather than at import so merely importing configs doesn't mutate
-    process-global RNG semantics."""
-    if not jax.config.jax_threefry_partitionable:
-        jax.config.update("jax_threefry_partitionable", True)
 
 
 @jax.tree_util.register_dataclass
@@ -128,7 +114,6 @@ def make_train_state(
 ) -> TrainState:
     """Initialise the TrainState directly sharded (no host-side full copy --
     required for models that don't fit one host/chip)."""
-    _ensure_partitionable_threefry()
     shardings = state_shardings(cfg, mesh, optimizer, rules)
 
     def init(rng: jax.Array) -> TrainState:
@@ -181,7 +166,6 @@ def make_train_step(
     never changes the sums, so the loss trajectory is bitwise-identical to
     the unbucketed (single-bucket) manual path.
     """
-    _ensure_partitionable_threefry()
     if pp_schedule not in ("gpipe", "1f1b"):
         # validate even on pp=1 meshes: a typo'd schedule must fail loudly,
         # not silently run the sequential loss
@@ -220,7 +204,6 @@ def make_train_step(
         # mean over this shard's rows; psum/dp restores the global mean
         # (equal shard sizes), and grads pre-scale by 1/dp so the bucketed
         # psums land on the global-mean gradient directly.
-        from tony_tpu.ops.compat import axis_size as _axis_size
         from tony_tpu.ops.overlap import bucketed_psum
 
         # no activation pinning inside the manual region: the constraint
@@ -232,7 +215,7 @@ def make_train_step(
             loss, grads = jax.value_and_grad(inner_loss)(
                 params, inputs, targets
             )
-            n = _axis_size("dp")
+            n = jax.lax.axis_size("dp")
             loss = jax.lax.psum(loss, "dp") / n
             grads = jax.tree.map(lambda g: g / n, grads)
             grads = bucketed_psum(
@@ -241,7 +224,7 @@ def make_train_step(
             return loss, grads
 
         batch_spec = P("dp", None)  # [B, S] token pairs, rows over dp
-        bucketed_vg = _shard_map(
+        bucketed_vg = jax.shard_map(
             _local_vg, mesh=mesh,
             in_specs=(P(), batch_spec, batch_spec),
             out_specs=(P(), P()),
@@ -375,7 +358,7 @@ def _pp_stage_fn(cfg: llama.LlamaConfig, cos: jax.Array, sin: jax.Array):
                 blk, policy=jax.checkpoint_policies.nothing_saveable
             )
         # the aux carry must be pp-varying like the stage's layer params
-        aux0 = _pcast_varying(jnp.zeros((), jnp.float32), ("pp",))
+        aux0 = jax.lax.pcast(jnp.zeros((), jnp.float32), ("pp",), to="varying")
         (y, aux), _ = jax.lax.scan(blk, (mb, aux0), lp_stack)
         return y, aux
 
@@ -415,7 +398,7 @@ def pp_loss_from_pairs(
         )
 
     layer_specs = jax.tree.map(lambda _: P("pp"), params["layers"])
-    h, aux = _shard_map(
+    h, aux = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(layer_specs, P(), P(), P()),
