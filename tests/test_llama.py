@@ -180,4 +180,17 @@ def test_mesh_shape_validation():
 
 
 def test_train_flops_positive(tiny):
-    assert llama.train_flops_per_token(tiny, 64) > 6 * tiny.n_params
+    # 6*N over the parameters a token is multiplied by (the embedding
+    # lookup is a gather and the norm gains elementwise), plus attention
+    d, L = tiny.dim, tiny.n_layers
+    assert tiny.n_matmul_params == tiny.n_params - tiny.vocab_size * d - (2 * L + 1) * d
+    flops = llama.train_flops_per_token(tiny, 64)
+    assert flops == 6 * tiny.n_matmul_params + 6 * L * d * 64
+    assert 6 * tiny.n_matmul_params < flops < 6 * tiny.n_params
+    # the benchmark's hand-checked count at the train cell's sizes
+    # (benchmark/tests/test_flops.py): 6.241 GFLOP/token
+    mistral4 = llama.LlamaConfig(
+        vocab_size=32768, dim=4096, n_layers=4, n_heads=32, n_kv_heads=8,
+        ffn_dim=14336, max_seq_len=2048,
+    )
+    assert llama.train_flops_per_token(mistral4, 2048) == pytest.approx(6.241e9, rel=1e-3)

@@ -455,3 +455,32 @@ def test_disarmed_series_sample_is_within_noise_of_noop():
         assert rec is series.active_recorder()
     finally:
         series.uninstall()
+
+
+def test_disarmed_annotate_is_within_noise_of_noop():
+    """The device-timeline bridge's no-op contract (the sixth twin): an
+    ``annotate(...)`` block with no profiler session on is one small
+    object and the profiler's own active check on enter and exit — cheap
+    enough to sit ten times in every engine step (serve/engine.py) and
+    around every sampled train step. An ``annotate`` that grew work of its
+    own (formatting a name, reading a clock) shows up here."""
+    import time
+
+    from tony_tpu.obs.profiler import annotate
+
+    N = 50_000
+    for _ in range(1000):
+        with annotate("serve.plan"):
+            pass
+    per_call = math.inf
+    for _ in range(5):
+        t0 = time.perf_counter()
+        for _ in range(N):
+            with annotate("serve.plan"):
+                pass
+        per_call = min(per_call, (time.perf_counter() - t0) / N)
+    assert per_call < 5e-6, (
+        f"disarmed annotate costs {per_call * 1e9:.0f}ns/block — the no-op "
+        "path regressed (is a profiler session left on, or is annotate "
+        "doing work of its own?)"
+    )

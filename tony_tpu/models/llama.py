@@ -138,6 +138,15 @@ class LlamaConfig:
         inactive = 3 * (self.n_experts - self.moe_top_k) * self.dim * self.ffn_dim
         return self.n_params - self.n_layers * inactive
 
+    @property
+    def n_matmul_params(self) -> int:
+        """Active parameters a token is MULTIPLIED by: the layers' matrices
+        (MoE: router + the top_k experts that fire) and the output head.
+        The embedding table is a gather and the norm gains are elementwise
+        — neither is a matmul, so neither belongs in 6*N."""
+        not_matmul = self.vocab_size * self.dim + (2 * self.n_layers + 1) * self.dim
+        return self.n_active_params - not_matmul
+
     # --- presets -----------------------------------------------------------
 
     @classmethod
@@ -607,10 +616,11 @@ def loss_fn(params: Params, tokens: jax.Array, cfg: LlamaConfig) -> jax.Array:
 
 
 def train_flops_per_token(cfg: LlamaConfig, seq_len: int) -> float:
-    """Approximate training FLOPs per token: 6*N_active (param matmuls,
-    fwd+bwd; MoE counts only the top-k experts that fire per token) plus the
-    causal-attention score/value matmuls (12*L*D*S/2)."""
-    return 6.0 * cfg.n_active_params + 6.0 * cfg.n_layers * cfg.dim * seq_len
+    """Training FLOPs per token the algorithm needs: 6*N over the matmul
+    parameters (fwd+bwd; MoE counts only the top-k experts that fire per
+    token; the embedding lookup is a gather, not a matmul, and is left
+    out) plus the causal-attention score/value matmuls (12*L*D*S/2)."""
+    return 6.0 * cfg.n_matmul_params + 6.0 * cfg.n_layers * cfg.dim * seq_len
 
 
 __all__ = [

@@ -110,7 +110,15 @@ def trace_window(log_dir: str, enabled: bool = True):
 
 
 def annotate(name: str):
-    """Named region in traces: ``with annotate('data-load'): ...``"""
+    """Named region in traces: ``with annotate('data-load'): ...``
+
+    The one spelling of ``jax.profiler.TraceAnnotation`` in ``tony_tpu/``
+    (train/loop.py, serve/engine.py, obs/profile.py). Pass a literal, bare
+    dotted name (``serve.plan``): no arguments, no digits — request identity
+    belongs in the journal span beside it (obs/trace.py). With no profiler
+    session on, a block costs one small object and the profiler's own
+    active check (tests/test_perf_guard.py holds it to the other disarmed
+    hooks' bound)."""
     return jax.profiler.TraceAnnotation(name)
 
 

@@ -31,7 +31,10 @@ The contract mirrors the chaos hooks (chaos/faults.py):
 This module is stdlib-only on purpose: executors for non-JAX frameworks
 arm it, so it must not pay (or fail on) a jax import. The device-timeline
 bridge (``jax.profiler.TraceAnnotation`` with the same span names) lives
-at the call sites that already import jax (train/loop.py).
+at the call sites that already import jax (train/loop.py). The serving
+engine mirrors its spans too (``serve.prefill``, ``serve.prefill_chunk``,
+``serve.step``), and adds profiler-only phase names inside ``Engine.step``
+that the journal does not carry (serve/engine.py).
 """
 
 from __future__ import annotations

@@ -100,6 +100,9 @@ def _flash_fwd(q, k, v, *, scale, blk_q, blk_k, causal, heads, kv_heads):
     rowspec = pl.BlockSpec((1, 1, blk_q), lambda b, i, j: (b, 0, i))
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, blk_q=blk_q, blk_k=blk_k, causal=causal),
+        # a name of its own: a partial has none, and the kernel would go
+        # by the JAX primitive that wraps it (closed_call, checkpoint)
+        name="flash_fwd",
         grid=(BH, nq, nk),
         in_specs=[qspec, kspec, kspec],
         out_specs=[qspec, rowspec],
@@ -225,6 +228,7 @@ def flash_dq_pass(q, k, v, do, lse, delta, *, scale, blk_q, blk_k, causal,
     rowspec = pl.BlockSpec((1, 1, blk_q), lambda b, i, j: (b, 0, i))
     return pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, blk_q=blk_q, blk_k=blk_k, causal=causal),
+        name="flash_bwd_dq",
         grid=(BH, nq, nk),
         in_specs=[qspec, kspec, kspec, qspec, rowspec, rowspec],
         out_specs=[qspec],
@@ -260,6 +264,7 @@ def flash_dkv_pass(q, k, v, do, lse, delta, *, scale, blk_q, blk_k, causal,
             _bwd_dkv_kernel, scale=scale, blk_q=blk_q, blk_k=blk_k,
             causal=causal, nq=nq,
         ),
+        name="flash_bwd_dkv",
         grid=(BKV, nk, rep * nq),
         in_specs=[qspec_t, kspec_t, kspec_t, qspec_t, rowspec_t, rowspec_t],
         out_specs=[kspec_t, kspec_t],

@@ -65,6 +65,7 @@ from tony_tpu.models.llama import LlamaConfig, Params, rms_norm, rope_freqs
 from tony_tpu.obs import hbm, health, profile, series, slo, trace
 from tony_tpu.obs import compiles as compile_ledger
 from tony_tpu.obs.metrics import DecodeMetrics
+from tony_tpu.obs.profiler import annotate
 from tony_tpu.obs.registry import HistogramWindow, Registry, snapshot_to_app_dir
 from tony_tpu.ops.decode_attention import decode_attention
 from tony_tpu.ops.quant_mm import quant_matmul, quantize_weights
@@ -849,9 +850,14 @@ class Engine:
     # --- admission ------------------------------------------------------------
 
     def _admit(self) -> None:
+        if not self._queue:
+            return
         free = [s for s, r in enumerate(self._slot_rid) if r is None]
-        while free and self._queue:
-            self._admit_one(free.pop(0), *self._queue.popleft())
+        if not free:
+            return
+        with annotate("serve.admit"):
+            while free and self._queue:
+                self._admit_one(free.pop(0), *self._queue.popleft())
 
     def _bucket_for(self, plen: int) -> int:
         for b in self.serve.prefill_buckets:
@@ -907,7 +913,8 @@ class Engine:
             # batch (state.live False, decode writes scratch-steered)
             # until the final chunk samples the first token.
             with trace.span("serve.prefill", rid=rid, bucket=bucket,
-                            slot=slot, matched=matched, chunked=1):
+                            slot=slot, matched=matched, chunked=1), \
+                    annotate("serve.prefill"):
                 self._plan_blocks(slot, plen, match)
             self._slot_rid[slot] = rid
             self._chunking[slot] = _ChunkedPrefill(
@@ -916,7 +923,7 @@ class Engine:
             self._prefill_chunk(slot)  # first chunk rides the admission step
             return
         with trace.span("serve.prefill", rid=rid, bucket=bucket, slot=slot,
-                        matched=matched):
+                        matched=matched), annotate("serve.prefill"):
             self._plan_blocks(slot, plen, match)
             if match is None:
                 padded = np.zeros((1, bucket), np.int32)
@@ -936,7 +943,8 @@ class Engine:
             # EXPLICIT sync: the sampled first token steers admission on
             # the host (transfer-guard-clean under GRAFT_SANITIZE)
             tok = int(jax.device_get(tok))
-        self._activate_slot(slot, rid, req, prompt, tok, carry, t0)
+        with annotate("serve.activate"):
+            self._activate_slot(slot, rid, req, prompt, tok, carry, t0)
 
     def _prefill_chunk(self, slot: int) -> None:
         """Advance one chunked-prefill slot by ONE chunk (at most
@@ -950,7 +958,8 @@ class Engine:
         end = min(job.pos + self.serve.chunk_tokens, plen)
         final = 1 if end == plen else 0
         with trace.span("serve.prefill_chunk", rid=job.rid, slot=slot,
-                        start=job.pos, end=end, final=final):
+                        start=job.pos, end=end, final=final), \
+                annotate("serve.prefill_chunk"):
             tok, carry = self._tail_prefill(
                 slot, job.prompt, job.pos, job.req, job.key, end=end
             )
@@ -960,9 +969,10 @@ class Engine:
             job.pos = end
             return
         del self._chunking[slot]
-        self._activate_slot(
-            slot, job.rid, job.req, job.prompt, tok, carry, job.t0
-        )
+        with annotate("serve.activate"):
+            self._activate_slot(
+                slot, job.rid, job.req, job.prompt, tok, carry, job.t0
+            )
 
     def _activate_slot(self, slot: int, rid: int, req: Request,
                        prompt: np.ndarray, tok: int, carry, t0: float) -> None:
@@ -1473,96 +1483,109 @@ class Engine:
         return drafts, dlens
 
     def _decode_once(self) -> None:
-        # per-step block planning: a live row allocates blocks NOW to
-        # cover every position this step may write (host-side, before
-        # dispatch) — position pos autoregressively, pos..pos+draft_len
-        # speculatively; the attended table width tracks the live maximum
-        B = self.serve.kv_block
-        live_before = [
-            s for s, r in enumerate(self._slot_rid)
-            if r is not None and s not in self._chunking
-        ]
-        drafts_np, dlens = self._propose_step_drafts(live_before)
-        spec_step = any(dlens)
-        need = 1
-        for s in live_before:
-            last = self._slot_len[s] + (dlens[s] if spec_step else 0)
-            while self._slot_blocks[s] * B <= last:
-                self._table[s, self._slot_blocks[s]] = self._alloc_block()
-                self._slot_blocks[s] += 1
-                self._table_dirty = True
-            need = max(need, last // B + 1)
-        if self.cache.quantized:
-            self._flush_fresh_scales()
-        self._set_attended(need)
+        # device-timeline bridge: the host phases of one decode step as
+        # profiler annotations (plan / step{dispatch, sync} / emit), so a
+        # capture names every idle gap; request identity stays in the
+        # journal spans, the profiler side carries phase names only
+        with annotate("serve.plan"):
+            # per-step block planning: a live row allocates blocks NOW to
+            # cover every position this step may write (host-side, before
+            # dispatch) — position pos autoregressively, pos..pos+draft_len
+            # speculatively; the attended table width tracks the live
+            # maximum
+            B = self.serve.kv_block
+            live_before = [
+                s for s, r in enumerate(self._slot_rid)
+                if r is not None and s not in self._chunking
+            ]
+            drafts_np, dlens = self._propose_step_drafts(live_before)
+            spec_step = any(dlens)
+            need = 1
+            for s in live_before:
+                last = self._slot_len[s] + (dlens[s] if spec_step else 0)
+                while self._slot_blocks[s] * B <= last:
+                    self._table[s, self._slot_blocks[s]] = self._alloc_block()
+                    self._slot_blocks[s] += 1
+                    self._table_dirty = True
+                need = max(need, last // B + 1)
+            if self.cache.quantized:
+                self._flush_fresh_scales()
+            self._set_attended(need)
         tracer = trace.active_tracer()
         sp = trace.NOOP_SPAN
         if tracer is not None:
             sp = tracer.sampled_span("serve.step", live=len(live_before))
-        with sp:
+        with sp, annotate("serve.step"):
             t0 = time.perf_counter()
-            sig = (self.cache.n_blocks, self._attended)
-            if spec_step:
-                self.cache, self.state, toks, n_emit, hmon = \
-                    self._get_spec_decode(sig)(
-                        self._dec_params, self.cache, self._table_dev,
-                        self.state, jnp.asarray(drafts_np),
-                        jnp.asarray(np.asarray(dlens, np.int32)),
-                    )
-            else:
-                # no live slot drafted: the plain 1-wide step (also the
-                # only step compiled with spec off — same signatures as
-                # the pre-spec engine)
-                self.cache, self.state, toks, hmon = self._get_decode(sig)(
-                    self._dec_params, self.cache, self._table_dev, self.state
-                )
+            with annotate("serve.dispatch"):
+                sig = (self.cache.n_blocks, self._attended)
+                if spec_step:
+                    self.cache, self.state, toks, n_emit, hmon = \
+                        self._get_spec_decode(sig)(
+                            self._dec_params, self.cache, self._table_dev,
+                            self.state, jnp.asarray(drafts_np),
+                            jnp.asarray(np.asarray(dlens, np.int32)),
+                        )
+                else:
+                    # no live slot drafted: the plain 1-wide step (also the
+                    # only step compiled with spec off — same signatures as
+                    # the pre-spec engine)
+                    self.cache, self.state, toks, hmon = \
+                        self._get_decode(sig)(
+                            self._dec_params, self.cache, self._table_dev,
+                            self.state,
+                        )
             # EXPLICIT per-step sync: continuous batching needs the sampled
             # tokens + done flags on host to steer admission — this is the
             # engine's one designed sync point per decode step
-            toks_np = np.asarray(jax.device_get(toks))
-            emit_np = np.asarray(jax.device_get(n_emit)) if spec_step else None
-            done_np = jax.device_get(self.state.done)
+            with annotate("serve.sync"):
+                toks_np = np.asarray(jax.device_get(toks))
+                emit_np = (
+                    np.asarray(jax.device_get(n_emit)) if spec_step else None
+                )
+                done_np = jax.device_get(self.state.done)
             dt = time.perf_counter() - t0
-        if spec_step:
-            new_total = int(sum(int(emit_np[s]) for s in live_before))
-            prop_total = sum(dlens[s] for s in live_before)
-            acc_total = sum(max(int(emit_np[s]) - 1, 0) for s in live_before)
-            self.metrics.record_spec(prop_total, acc_total)
-            self._c_draft_prop.inc(prop_total)
-            self._c_draft_acc.inc(acc_total)
-        else:
-            new_total = len(live_before)
-        self.metrics.record_decode(
-            dt, new_total, len(live_before), self.serve.slots
-        )
-        hbm.sample()  # stride-counted device-memory reading (no sync)
-        if hmon:
-            # stride-counted health sample: DEVICE references + the host
-            # slot->request map for per-request trip attribution; the
-            # device_get sync happens on the sentinel's worker thread
-            slot_rids = list(self._slot_rid)
-            health.sample(
-                metrics=hmon, slot_rids=slot_rids, live_slots=live_before
-            )
-        series.sample()  # stride-counted scrape of the attached sources
-        self._h_step.observe(dt)
-        self._c_tokens.inc(new_total)
-        for s in live_before:
+        with annotate("serve.emit"):
             if spec_step:
-                n = int(emit_np[s])
-                new_toks = [int(t) for t in toks_np[s, :n]]
+                new_total = int(sum(int(emit_np[s]) for s in live_before))
+                prop_total = sum(dlens[s] for s in live_before)
+                acc_total = sum(max(int(emit_np[s]) - 1, 0) for s in live_before)
+                self.metrics.record_spec(prop_total, acc_total)
+                self._c_draft_prop.inc(prop_total)
+                self._c_draft_acc.inc(acc_total)
             else:
-                n = 1
-                new_toks = [int(toks_np[s])]
-            self._slot_len[s] += n
-            self._completions[self._slot_rid[s]].tokens.extend(new_toks)
-            if self.serve.spec:
-                self._slot_ctx[s].extend(new_toks)
-            self._slot_remaining[s] -= n
-            if done_np[s]:
-                self._finish(s, "eos")
-            elif self._slot_remaining[s] <= 0:
-                self._finish(s, "length")
+                new_total = len(live_before)
+            self.metrics.record_decode(
+                dt, new_total, len(live_before), self.serve.slots
+            )
+            hbm.sample()  # stride-counted device-memory reading (no sync)
+            if hmon:
+                # stride-counted health sample: DEVICE references + the host
+                # slot->request map for per-request trip attribution; the
+                # device_get sync happens on the sentinel's worker thread
+                slot_rids = list(self._slot_rid)
+                health.sample(
+                    metrics=hmon, slot_rids=slot_rids, live_slots=live_before
+                )
+            series.sample()  # stride-counted scrape of the attached sources
+            self._h_step.observe(dt)
+            self._c_tokens.inc(new_total)
+            for s in live_before:
+                if spec_step:
+                    n = int(emit_np[s])
+                    new_toks = [int(t) for t in toks_np[s, :n]]
+                else:
+                    n = 1
+                    new_toks = [int(toks_np[s])]
+                self._slot_len[s] += n
+                self._completions[self._slot_rid[s]].tokens.extend(new_toks)
+                if self.serve.spec:
+                    self._slot_ctx[s].extend(new_toks)
+                self._slot_remaining[s] -= n
+                if done_np[s]:
+                    self._finish(s, "eos")
+                elif self._slot_remaining[s] <= 0:
+                    self._finish(s, "length")
 
     def _decode_impl(self, params, cache: PagedKVCache, table, state: _SlotState):
         """One token for every slot (test/guard hook; the hot path goes
@@ -1579,19 +1602,31 @@ class Engine:
 @functools.lru_cache(maxsize=512)
 def _prefill_fn(cfg: LlamaConfig, bucket: int, max_top_k: int):
     """Jitted bucketed prefill, cached per (model config, bucket): engines
-    with the same model share prefill compiles process-wide."""
-    return jax.jit(partial(
-        _prefill_step, cfg=cfg, bucket=bucket, max_top_k=max_top_k
-    ))
+    with the same model share prefill compiles process-wide. A named
+    function, not a ``partial``: jit names the program after it, and a
+    device trace then reads ``jit_serve_prefill`` where a partial gives
+    ``jit__unknown`` (the same holds for every ``serve_*`` below)."""
+    def serve_prefill(params, prompt, last_index, temp, top_k, top_p, key):
+        return _prefill_step(
+            params, prompt, last_index, temp, top_k, top_p, key,
+            cfg=cfg, bucket=bucket, max_top_k=max_top_k,
+        )
+
+    return jax.jit(serve_prefill)
 
 
 @functools.lru_cache(maxsize=512)
 def _tail_fn(cfg: LlamaConfig, tb: int, max_top_k: int):
     """Jitted tail prefill (prefix-matched admissions), cached per (model
     config, tail bucket); jit itself caches per context width."""
-    return jax.jit(partial(
-        _tail_prefill_step, cfg=cfg, tb=tb, max_top_k=max_top_k
-    ))
+    def serve_tail_prefill(params, ctx_k, ctx_v, tail, start, last_index,
+                           temp, top_k, top_p, key):
+        return _tail_prefill_step(
+            params, ctx_k, ctx_v, tail, start, last_index, temp, top_k,
+            top_p, key, cfg=cfg, tb=tb, max_top_k=max_top_k,
+        )
+
+    return jax.jit(serve_tail_prefill)
 
 
 @functools.lru_cache(maxsize=512)
@@ -1602,14 +1637,14 @@ def _decode_fn(cfg: LlamaConfig, decode_impl: str, kv_block: int,
     per pool-size/table-width: jit itself caches per argument shape, so
     all engines with the same model reuse every compiled signature. The
     block table (arg 2) is NOT donated — it is reused across steps."""
-    return jax.jit(
-        partial(
-            _decode_step, cfg=cfg, decode_impl=decode_impl,
+    def serve_decode(params, cache, table, state):
+        return _decode_step(
+            params, cache, table, state, cfg=cfg, decode_impl=decode_impl,
             kv_block=kv_block, max_top_k=max_top_k, monitors=monitors,
             quant_kv=quant_kv, quant_weights=quant_weights,
-        ),
-        donate_argnums=(1, 3),
-    )
+        )
+
+    return jax.jit(serve_decode, donate_argnums=(1, 3))
 
 
 # AOT executables shared module-wide: keyed by model/kernel knobs + the
@@ -1722,8 +1757,8 @@ def _scatter_fn(quant_kv: str = ""):
             in_axes=(0, 0, 0, None, None, None),
         )
 
-        def insert_q(cache: PagedKVCache, pk, pv, pids, offs, ub, slot,
-                     plen):
+        def serve_scatter(cache: PagedKVCache, pk, pv, pids, offs, ub, slot,
+                          plen):
             k, ksc = span(cache.k, cache.k_scale, pk, pids, offs, ub)
             v, vsc = span(cache.v, cache.v_scale, pv, pids, offs, ub)
             lengths = lax.dynamic_update_slice(
@@ -1731,9 +1766,9 @@ def _scatter_fn(quant_kv: str = ""):
             )
             return PagedKVCache(k, v, lengths, ksc, vsc)
 
-        return jax.jit(insert_q, donate_argnums=(0,))
+        return jax.jit(serve_scatter, donate_argnums=(0,))
 
-    def insert(cache: PagedKVCache, pk, pv, pids, offs, slot, plen):
+    def serve_scatter(cache: PagedKVCache, pk, pv, pids, offs, slot, plen):
         # pk/pv [L, Hkv, W, hd]; advanced indices (pids axis 1, offs axis
         # 3) are non-adjacent, so the indexed result moves to the front:
         # [W, L, Hkv, hd] — match it by transposing the span
@@ -1742,7 +1777,7 @@ def _scatter_fn(quant_kv: str = ""):
         lengths = lax.dynamic_update_slice(cache.lengths, plen[None], (slot,))
         return PagedKVCache(k, v, lengths)
 
-    return jax.jit(insert, donate_argnums=(0,))
+    return jax.jit(serve_scatter, donate_argnums=(0,))
 
 
 @functools.lru_cache(maxsize=2)
@@ -1752,7 +1787,7 @@ def _copy_block_fn(quant: bool = False):
     shared block writes into its private copy instead. A quantized pool
     copies the block's scale rows with it — the COW copy dequantizes to
     exactly what the shared source did."""
-    def cp(cache: PagedKVCache, src, dst):
+    def serve_copy_block(cache: PagedKVCache, src, dst):
         kb = lax.dynamic_slice_in_dim(cache.k, src, 1, axis=1)
         vb = lax.dynamic_slice_in_dim(cache.v, src, 1, axis=1)
         k = lax.dynamic_update_slice_in_dim(cache.k, kb, dst, axis=1)
@@ -1769,7 +1804,7 @@ def _copy_block_fn(quant: bool = False):
             return PagedKVCache(k, v, cache.lengths, ksc, vsc)
         return PagedKVCache(k, v, cache.lengths)
 
-    return jax.jit(cp, donate_argnums=(0,))
+    return jax.jit(serve_copy_block, donate_argnums=(0,))
 
 
 @functools.lru_cache(maxsize=1)
@@ -1777,13 +1812,13 @@ def _zero_scales_fn():
     """Jitted batched scale-row reset (DONATED cache): freshly allocated
     blocks' K and V scale rows go to zero across all layers — the
     nothing-real-stored marker the first quantized write keys off."""
-    def zero(cache: PagedKVCache, pids):
+    def serve_zero_scales(cache: PagedKVCache, pids):
         return cache._replace(
             k_scale=cache.k_scale.at[:, pids, :].set(0.0),
             v_scale=cache.v_scale.at[:, pids, :].set(0.0),
         )
 
-    return jax.jit(zero, donate_argnums=(0,))
+    return jax.jit(serve_zero_scales, donate_argnums=(0,))
 
 
 @functools.lru_cache(maxsize=4)
@@ -1793,7 +1828,7 @@ def _gather_fn(quant: bool = False, out_dtype=None):
     the pool is NOT donated — the slot keeps serving from it). Quantized
     pools dequantize through the gathered blocks' scale rows into
     ``out_dtype`` — the tail prefill attends real-valued context."""
-    def gat(cache: PagedKVCache, pids):
+    def serve_gather(cache: PagedKVCache, pids):
         def one(pool, scale):
             g = jnp.take(pool, pids, axis=1)           # [L, nC, Hkv, blk, hd]
             if quant:
@@ -1805,7 +1840,7 @@ def _gather_fn(quant: bool = False, out_dtype=None):
             )[:, None]                                 # [L, 1, C, Hkv, hd]
         return one(cache.k, cache.k_scale), one(cache.v, cache.v_scale)
 
-    return jax.jit(gat)
+    return jax.jit(serve_gather)
 
 
 _QUANT_WEIGHT_NAMES = ("wq", "wk", "wv", "wo", "w1", "w2", "w3")
@@ -2130,15 +2165,15 @@ def _spec_decode_fn(cfg: LlamaConfig, decode_impl: str, kv_block: int,
                     quant_kv: str = "", quant_weights: bool = False):
     """Jitted speculative verify step — same cache discipline as
     :func:`_decode_fn` (per model/kernel knobs, table not donated)."""
-    return jax.jit(
-        partial(
-            _spec_decode_step, cfg=cfg, decode_impl=decode_impl,
-            kv_block=kv_block, max_top_k=max_top_k, draft_k=draft_k,
-            monitors=monitors, quant_kv=quant_kv,
+    def serve_spec_decode(params, cache, table, state, drafts, draft_len):
+        return _spec_decode_step(
+            params, cache, table, state, drafts, draft_len, cfg=cfg,
+            decode_impl=decode_impl, kv_block=kv_block, max_top_k=max_top_k,
+            draft_k=draft_k, monitors=monitors, quant_kv=quant_kv,
             quant_weights=quant_weights,
-        ),
-        donate_argnums=(1, 3),
-    )
+        )
+
+    return jax.jit(serve_spec_decode, donate_argnums=(1, 3))
 
 
 def _aot_spec_decode(cfg: LlamaConfig, decode_impl: str, kv_block: int,

@@ -587,6 +587,22 @@ def _fit(cfg: FitConfig, fit_span=trace.NOOP_SPAN) -> dict:
         # device-to-host transfers disallowed — the log boundary is the
         # one place a sync is intended, so it is spelled out
         loss = float(jax.device_get(m["loss"]))
+        # the window's clock: from the moment the previous boundary's step
+        # was known finished to the moment this one's is (the sync above,
+        # or the sampled step's drain where that came first). The host's
+        # clock at the boundary's DISPATCH is no such point: it runs up to
+        # a window ahead of the device, and falls back into step with it at
+        # every sampled-span drain, so windows timed there came out bimodal
+        # (PERF.md section 6, PR 25)
+        nonlocal t_resolved
+        now = snap.get("t_done") or time.perf_counter()
+        dt, t_resolved = now - t_resolved, now
+        if tracer is None and snap["startup"] is None:
+            # disarmed step-time source: the window mean (wall time over
+            # completed steps — accurate without a per-step sync). The
+            # first window is excluded like everywhere else: it absorbs
+            # compile/warmup.
+            h_step.observe(dt / max(snap["window"], 1))
         # scale from the snapshot, not the live loop: the deferred emit
         # may resolve after an elastic reshard rebound tokens_per_step
         # and the mesh, and the straddling window must report at the
@@ -596,7 +612,7 @@ def _fit(cfg: FitConfig, fit_span=trace.NOOP_SPAN) -> dict:
             tokens_per_step=snap.get("tokens_per_step", tokens_per_step),
             n_chips=snap.get("n_chips", mesh.size),
         )
-        timer.record(snap["dt"], snap["window"], host_blocked_s=snap["host_s"])
+        timer.record(dt, snap["window"], host_blocked_s=snap["host_s"])
         out = {
             "step": snap["step"],
             "loss": round(loss, 4),
@@ -641,7 +657,7 @@ def _fit(cfg: FitConfig, fit_span=trace.NOOP_SPAN) -> dict:
     host_window_s = 0.0     # input-blocked time in the current log window
     host_steady_s = 0.0     # input-blocked time after the first step
     steady_t0 = None        # wall clock after the first step fully resolved
-    t_window = time.perf_counter()
+    t_resolved = time.perf_counter()  # when the last boundary's step was known done
     window = 0
 
     def _dispatch(state, inputs, targets):
@@ -775,6 +791,10 @@ def _fit(cfg: FitConfig, fit_span=trace.NOOP_SPAN) -> dict:
                 # log-boundary sync.
                 jax.block_until_ready(state)
                 t_sync = time.perf_counter()
+                if pending is not None:
+                    # the drain resolved the pending boundary's step: its
+                    # window closes here, not after this step's sync
+                    pending["t_done"] = t_sync
                 with sp, annotate("train.step"):
                     state, metrics = _dispatch(state, inputs, targets)
                     jax.block_until_ready(metrics)
@@ -810,11 +830,9 @@ def _fit(cfg: FitConfig, fit_span=trace.NOOP_SPAN) -> dict:
             # timestamps the resulting METRICS event) and gives users signal
             # before a long log_every window elapses
             if step == start_step or (step + 1) % cfg.log_every == 0 or step + 1 == cfg.steps:
-                now = time.perf_counter()
                 snap = {
                     "step": step + 1,
                     "metrics": metrics,
-                    "dt": now - t_window,
                     "window": window,
                     "host_s": host_window_s,
                     "startup": dict(startup) if step == start_step else None,
@@ -823,19 +841,12 @@ def _fit(cfg: FitConfig, fit_span=trace.NOOP_SPAN) -> dict:
                     "n_chips": mesh.size,
                 }
                 _start_async_host_copy(metrics)
-                if tracer is None and step != start_step:
-                    # disarmed step-time source: the window mean (wall time
-                    # over completed steps — accurate without a per-step
-                    # sync). The first window is excluded like everywhere
-                    # else: it absorbs compile/warmup.
-                    h_step.observe(snap["dt"] / max(snap["window"], 1))
                 if step == start_step or step + 1 == cfg.steps:
                     # first step: latency metric, sync now; last step: the
                     # loop ends here, nothing left to overlap with
                     _emit(snap)
                 else:
                     pending = snap
-                t_window = time.perf_counter()
                 window = 0
                 host_window_s = 0.0
                 if step == start_step:
