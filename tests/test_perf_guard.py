@@ -20,6 +20,7 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from tony_tpu.models.llama import LlamaConfig
 from tony_tpu.parallel.mesh import MeshShape, build_mesh
@@ -173,6 +174,64 @@ def test_decode_step_reads_kv_proportional_to_active_blocks():
     # and the full-capacity cost is dominated by the KV buffers (the guard
     # is measuring the cache, not fixed per-step overhead)
     assert full - small > kv_full, (small, full, kv_full)
+
+
+@pytest.mark.parametrize("step", ["plain", "spec", "quant_kv"])
+def test_decode_step_keeps_the_pool_in_one_buffer(step):
+    """Compile the decode step (cache and state donated, nothing executed)
+    at a FIXED table width over pools of 1x, 4x and 16x the blocks and
+    assert the pool is one buffer from argument to result: the compiled
+    step's temp bytes do not grow with the pool, and its outputs alias
+    both pools. With the pools handed to the layer scan as ``xs`` and taken
+    back as stacked ``ys`` (before PR 26), or with the K/V rows written by
+    a gather/scatter on non-adjacent axes, temp grew by 1.5-1.8x the
+    pool's bytes: the copies the serve cell's trace showed as 19 ms of
+    each 48 ms decode step on the chip (PERF.md section 6, PR 26)."""
+    from tony_tpu.models.llama import init_params
+    from tony_tpu.serve import Engine, ServeConfig
+    from tony_tpu.serve.cache import create_cache
+    from tony_tpu.serve.engine import _spec_decode_fn
+
+    slots, block, width, draft_k = 4, 16, 8, 2
+    cfg = dataclasses.replace(LlamaConfig.tiny(), max_seq_len=block * width)
+    params = init_params(jax.random.key(0), cfg)
+    quant_kv = "int8" if step == "quant_kv" else ""
+    eng = Engine(params, cfg, ServeConfig(
+        slots=slots, max_len=block * width, kv_block=block, quant_kv=quant_kv,
+    ))
+    table = jnp.zeros((slots, width), jnp.int32)
+
+    def plan(n_blocks):
+        cache = create_cache(cfg, slots, n_blocks, block, quant_kv=quant_kv)
+        if step == "spec":
+            fn = _spec_decode_fn(
+                cfg, "scan", block, eng.serve.max_top_k, draft_k
+            )
+            lowered = fn.lower(
+                params, cache, table, eng.state,
+                jnp.zeros((slots, draft_k), jnp.int32),
+                jnp.zeros((slots,), jnp.int32),
+            )
+        else:
+            lowered = jax.jit(eng._decode_impl, donate_argnums=(1, 3)).lower(
+                params, cache, table, eng.state
+            )
+        ma = lowered.compile().memory_analysis()
+        pools = cache.k.nbytes + cache.v.nbytes
+        return ma.temp_size_in_bytes, ma.alias_size_in_bytes, pools
+
+    base = 1 + slots * width
+    plans = [plan(1 + (base - 1) * m) for m in (1, 4, 16)]
+    for temp, alias, pools in plans:
+        assert alias >= pools, (
+            f"decode step aliases {alias} B of outputs to inputs, less than "
+            f"its two pools ({pools} B): a donated pool comes back as a copy"
+        )
+    (t1, _, p1), _, (t16, _, p16) = plans
+    assert t16 - t1 < 0.05 * (p16 - p1), (
+        f"decode step temp grows with the pool: {t1} B at {p1} B of pool, "
+        f"{t16} B at {p16} B — the step copies or relayouts the pool again"
+    )
 
 
 def test_disarmed_trace_span_is_within_noise_of_noop():

@@ -210,6 +210,99 @@ def test_engine_decode_impls_agree(setup):
     assert outs["scan"] == outs["pallas"]
 
 
+# --- the carried pool: every layer writes its own blocks, and only those ------
+
+
+@pytest.mark.parametrize("impl", ["scan", "pallas"])
+@pytest.mark.parametrize("step", ["decode", "spec"])
+def test_decode_step_writes_each_layers_own_blocks(impl, step):
+    """The decode programs carry the pools as one ``[L * P, ...]`` buffer
+    and reach layer ``l`` by adding ``l * P`` to block ids. After one step
+    on a 3-layer model over a pool of random content: every layer's new
+    K/V row sits at ``(table[s, pos // blk], pos % blk)`` of ITS OWN
+    layer, a dead slot's row sits in its layer's scratch block, the block
+    a dead slot's stale table still names (freed, then reallocated to a
+    live slot) is untouched at every layer, and every other value of both
+    pools is bitwise what it was."""
+    import dataclasses
+
+    from tony_tpu.serve.cache import SCRATCH_BLOCK, PagedKVCache
+    from tony_tpu.serve.engine import (
+        _decode_step, _SlotState, _spec_decode_step,
+    )
+
+    cfg = dataclasses.replace(llama.LlamaConfig.tiny(), n_layers=3)
+    params = llama.init_params(jax.random.key(1), cfg)
+    S, blk, M, draft_k = 4, 8, 3, 2
+    L, Hkv, hd = cfg.n_layers, cfg.n_kv_heads, cfg.head_dim
+    P = 1 + 3 * M
+    kk, kv = jax.random.split(jax.random.key(2))
+    shape = (L, P, Hkv, blk, hd)
+    old_k = jax.random.normal(kk, shape, cfg.dtype)
+    old_v = jax.random.normal(kv, shape, cfg.dtype)
+    # slots 0..2 own blocks 1..9; slot 3 is DEAD and its stale row still
+    # names slot 0's blocks (freed by slot 3, reallocated to slot 0)
+    table = np.arange(1, 1 + 3 * M, dtype=np.int32).reshape(3, M)
+    table = np.concatenate([table, table[:1]])
+    lengths = np.array([5, 8, 17, 3], np.int32)   # slot 1 opens a new block
+    live = np.array([True, True, True, False])
+    state = _SlotState(
+        last_tok=jnp.array([3, 9, 27, 81], jnp.int32),
+        rng=jnp.zeros((S, 2), jnp.uint32),
+        temp=jnp.zeros((S,), jnp.float32),
+        top_k=jnp.zeros((S,), jnp.int32),
+        top_p=jnp.ones((S,), jnp.float32),
+        eos=jnp.full((S,), -1, jnp.int32),
+        done=jnp.zeros((S,), bool),
+        live=jnp.asarray(live),
+    )
+    cache = PagedKVCache(old_k, old_v, jnp.asarray(lengths))
+    kw = dict(cfg=cfg, decode_impl=impl, kv_block=blk, max_top_k=8)
+    if step == "spec":
+        dlen = np.array([2, 0, 1, 2], np.int32)
+        drafts = jnp.array([[1, 2], [3, 4], [5, 6], [7, 8]], jnp.int32)
+        new, *_ = _spec_decode_step(
+            params, cache, jnp.asarray(table), state, drafts,
+            jnp.asarray(dlen), draft_k=draft_k, **kw,
+        )
+        # (slot, position) pairs written for real; padding beyond a row's
+        # draft length and the dead slot go to (scratch, offset 0)
+        real = [(s, lengths[s] + g) for s in range(S) if live[s]
+                for g in range(dlen[s] + 1)]
+        scratch_offs = {0}
+    else:
+        new, *_ = _decode_step(params, cache, jnp.asarray(table), state, **kw)
+        real = [(s, lengths[s]) for s in range(S) if live[s]]
+        scratch_offs = {int(lengths[3]) % blk}
+
+    expect = np.zeros((L, P, blk), bool)       # rows that may change
+    for s, pos in real:
+        expect[:, table[s, pos // blk], pos % blk] = True
+    wrote = expect.copy()
+    for off in scratch_offs:
+        expect[:, SCRATCH_BLOCK, off] = True
+    for old, got in ((old_k, new.k), (old_v, new.v)):
+        old, got = np.asarray(old), np.asarray(got)
+        assert got.shape == shape
+        changed = (old != got).any(axis=(2, 4))               # [L, P, blk]
+        # nothing outside the expected rows moved, at any layer — the
+        # dead slot's stale target (table[3, 0] = slot 0's block, offset
+        # 3) included
+        assert not (changed & ~expect).any(), np.argwhere(changed & ~expect)
+        assert not changed[:, table[3, 0], int(lengths[3]) % blk].any()
+        # every real row was written at EVERY layer (random old content:
+        # an unwritten row would compare equal), all kv heads of it
+        assert ((old != got).any(axis=4).all(axis=2) | ~wrote).all()
+        # the dead slot / padding rows landed in each layer's own scratch
+        for off in scratch_offs:
+            assert changed[:, SCRATCH_BLOCK, off].all()
+        # and the layers wrote different rows (their own projections)
+        s0, p0 = real[0]
+        rows = got[:, table[s0, p0 // blk], :, p0 % blk, :]
+        assert not np.allclose(rows[0], rows[1])
+        assert not np.allclose(rows[1], rows[2])
+
+
 # --- metrics ------------------------------------------------------------------
 
 

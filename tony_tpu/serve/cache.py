@@ -57,6 +57,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 # physical block 0 is reserved: dead slots' writes land here, and table
 # entries beyond a slot's allocation point at it (their tiles are masked
@@ -207,11 +208,35 @@ def _rescale_stored(q: jax.Array, factor: jax.Array, qmax: float) -> jax.Array:
     return f.astype(q.dtype)
 
 
+def _set_rows(pool: jax.Array, rows: jax.Array, pids: jax.Array,
+              offs: jax.Array | None = None) -> jax.Array:
+    """``rows[i]`` lands in block ``pids[i]`` of ``pool`` — the whole
+    block (``rows [n, *pool.shape[1:]]``), or with ``offs`` its position
+    ``offs[i]`` on axis 2 only (``rows [n, Hkv, 1, hd]``) — as one
+    ``dynamic_update_slice`` per row, applied in order. The row count is
+    static and small (slots x draft positions), and a chain of slice
+    updates is the form XLA performs in place on a donated or
+    loop-carried buffer: the per-row window touches nothing else, so no
+    copy or relayout of ``pool`` is needed to apply it (a gather/scatter
+    with index dims on non-adjacent axes makes the compiler relayout the
+    whole pool around the write; tests/test_perf_guard.py holds the
+    difference). Duplicate targets: the last row wins."""
+    n = pids.shape[0]
+    z = jnp.int32(0)
+    off_l = jnp.unstack(offs) if offs is not None else [z] * n
+    for row, pid, off in zip(jnp.split(rows, n), jnp.unstack(pids), off_l):
+        # start = (block, head, position, value); a scale pool has two axes
+        start = (pid, z, off, z)[:pool.ndim]
+        pool = lax.dynamic_update_slice(pool, row, start)
+    return pool
+
+
 def _quant_write_rows(pool, scale, new, pids, offs, qmax):
     """One quantized position-per-row write: ``new [S, Hkv, hd]`` lands at
     ``(pids[s], offs[s])``. Gather the touched blocks + scales, fold the
     written amax into the running block scale, requantize the existing
-    entries by old/new, insert the quantized row, scatter both back."""
+    entries by old/new, insert the quantized row, write both back (whole
+    blocks and scale rows, one in-place slice update each)."""
     S = new.shape[0]
     blk = jnp.take(pool, pids, axis=0)                  # [S, Hkv, blk, hd]
     sc = jnp.take(scale, pids, axis=0)                  # [S, Hkv]
@@ -225,34 +250,47 @@ def _quant_write_rows(pool, scale, new, pids, offs, qmax):
     blk = blk.at[jnp.arange(S), :, offs, :].set(row)
     # duplicate pids occur only for scratch-steered rows (dead slots,
     # padding) — scratch content is garbage by contract, any winner is fine
-    return pool.at[pids].set(blk), scale.at[pids].set(sc_new)
+    return _set_rows(pool, blk, pids), _set_rows(scale, sc_new, pids)
 
 
 def scatter_block_kv(pool: jax.Array, new: jax.Array, pids: jax.Array,
                      offs: jax.Array, scale: jax.Array | None = None,
                      qmax: float = 127.0):
-    """Paged KV write into ONE layer's ``[P, Hkv, block, hd]`` pool.
+    """Paged KV write into a ``[N, Hkv, block, hd]`` pool of physical
+    blocks: one layer's ``[P, ...]`` slab, or the whole cache viewed as
+    ``[L * P, ...]`` with the layer's offset ``l * P`` already added to
+    ``pids`` (how the decode steps carry it through their layer scan,
+    serve/engine.py ``_scan_layers_paged``).
 
-    ``pids``/``offs`` name each new entry's physical block and in-block
-    offset. With 1-D ``[S]`` indices ``new`` is ``[S, Hkv, hd]`` (the
-    classic one-token decode step); with 2-D ``[S, G]`` indices it is
+    **Contract.** The pool is the caller's donated or loop-carried
+    buffer and comes back as the SAME buffer: the write touches the
+    ``Hkv x hd`` values of each named ``(block, offset)`` and nothing
+    else, as a chain of ``dynamic_update_slice`` (:func:`_set_rows`), so
+    the compiler neither copies nor relayouts the pool for it. ``pids``
+    and ``offs`` must be in range (a slice update clamps where a scatter
+    would drop); entries that must not land anywhere real (dead slots,
+    padding beyond a row's draft length) are the CALLER's job to steer to
+    ``SCRATCH_BLOCK`` — of the layer being written, i.e. *before* the
+    layer offset is added, so block ``l * P`` takes them. Rows are
+    applied in order; duplicate targets occur only among scratch-steered
+    rows, whose content is garbage by contract.
+
+    With 1-D ``[S]`` indices ``new`` is ``[S, Hkv, hd]`` (the classic
+    one-token decode step); with 2-D ``[S, G]`` indices it is
     ``[S, G, Hkv, hd]`` — the speculative multi-position write
-    (serve/spec.py): row s's G draft positions land in one scatter.
-    Entries that must not land anywhere real (dead slots, padding beyond
-    a row's draft length) are the CALLER's job to steer to
-    ``SCRATCH_BLOCK``. The advanced indices (``pids`` on axis 0, ``offs``
-    on axis 2) are non-adjacent, so the indexed result moves the index
-    dims to the front — exactly ``new``'s layout, no transpose needed.
+    (serve/spec.py): row s's G draft positions.
 
-    With ``scale`` given (a quantized pool's ``[P, Hkv]`` scale rows for
-    this layer) the write QUANTIZES: the written positions' amax folds
-    into the running block scale, existing entries requantize by
-    old/new, and the return value is ``(pool, scale)``. The speculative
-    2-D form applies the G positions as G sequential single-position
-    passes (G is small and static) so two writes into the same block
-    compound their scale updates correctly."""
+    With ``scale`` given (a quantized pool's ``[N, Hkv]`` scale rows, same
+    leading axis as ``pool``) the write QUANTIZES: the written positions'
+    amax folds into the running block scale, existing entries requantize
+    by old/new (so the unit written back is the whole block), and the
+    return value is ``(pool, scale)``. The speculative 2-D form applies
+    the G positions as G sequential single-position passes (G is small
+    and static) so two writes into the same block compound their scale
+    updates correctly."""
     if scale is None:
-        return pool.at[pids, :, offs, :].set(new)
+        rows = new.reshape(-1, new.shape[-2], 1, new.shape[-1])
+        return _set_rows(pool, rows, pids.reshape(-1), offs.reshape(-1))
     if pids.ndim == 1:
         return _quant_write_rows(pool, scale, new, pids, offs, qmax)
     for g in range(pids.shape[1]):

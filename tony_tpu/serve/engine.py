@@ -1635,8 +1635,19 @@ def _decode_fn(cfg: LlamaConfig, decode_impl: str, kv_block: int,
                quant_weights: bool = False):
     """Jitted decode step, cached per (model config, kernel knobs) — NOT
     per pool-size/table-width: jit itself caches per argument shape, so
-    all engines with the same model reuse every compiled signature. The
-    block table (arg 2) is NOT donated — it is reused across steps."""
+    all engines with the same model reuse every compiled signature.
+
+    Contract: the cache (arg 1) and the slot state (arg 3) are DONATED and
+    the pools come back as the same buffers — carried through the layer
+    scan as ``[L * P, ...]`` and written in place, ``S x Hkv x hd`` values
+    per layer per pool (:func:`_scan_layers_paged`,
+    ``serve/cache.scatter_block_kv``); the compiled step's temporaries do
+    not grow with the pool (tests/test_perf_guard.py) and on the chip it
+    holds no pool- or slab-shaped copy (PERF.md §5). A dead slot's write
+    lands in the scratch block of the layer being written (block
+    ``l * P`` of the flat view). The block table (arg 2) is NOT donated —
+    it is reused across steps — and names blocks of ONE layer; the step
+    adds the layer's offset itself."""
     def serve_decode(params, cache, table, state):
         return _decode_step(
             params, cache, table, state, cfg=cfg, decode_impl=decode_impl,
@@ -1745,8 +1756,12 @@ def _aot_tail_prefill(cfg: LlamaConfig, tb: int, ctx: int, max_top_k: int,
 def _scatter_fn(quant_kv: str = ""):
     """Jitted position-wise KV scatter into the (DONATED) pool: position
     ``i`` of the prefilled span lands in physical block ``pids[i]`` at
-    offset ``offs[i]``; masked rows steer to the scratch block. One
-    in-place scatter instead of two whole-cache copies per admission.
+    offset ``offs[i]``; masked rows steer to the scratch block. Meant as
+    one in-place scatter instead of two whole-cache copies per admission
+    — UNPROVEN on the chip: the serve cell's trace shows this program
+    copying the pool four times and transposing it twice per admission
+    (13.7 ms each, PERF.md §5): its index dims sit on non-adjacent axes,
+    the form ``scatter_block_kv`` avoids.
     The quantized form additionally takes the touched-block set ``ub``
     and runs the per-block running-scale update + requantization
     (serve/cache.py quant_scatter_span, vmapped over layers)."""
@@ -1922,6 +1937,57 @@ def _q_mm(h, lp, name, quant_weights, impl):
     return h @ lp[name]
 
 
+def _scan_layers_paged(layer_fn, x, layers, cache: PagedKVCache):
+    """Run ``layer_fn`` over the stacked layer weights with the paged
+    pools CARRIED through the scan — the one pool discipline of the
+    decode programs (plain and speculative, quantized or not).
+
+    The cache's ``[L, P, ...]`` pools (and scale pools) are viewed as
+    ``[L * P, ...]`` (a bitcast of the donated argument) and ride the
+    scan's carry; its ``xs`` are the layer weights and the layer's block
+    offset ``l * P`` only. ``layer_fn(x, lp, pools, base)`` adds ``base``
+    to the block ids it writes (``scatter_block_kv``) and to the table it
+    attends through, so layer ``l`` reads and writes blocks
+    ``[l * P, (l + 1) * P)`` — its own scratch block is ``l * P``, which is
+    where a dead slot's ``SCRATCH_BLOCK`` lands after the offset. Carried
+    and written by slice updates, the pool is one buffer from the
+    program's donated argument to its result. Do NOT hand the pools to the
+    scan as ``xs`` and take them back as stacked ``ys``: those are two
+    buffers of the loop, so every layer's slab is sliced out, relaid and
+    re-stacked — several pool-sized copies a step, 19 ms of a 48 ms step
+    on the chip (PERF.md §6, PR 26). ``pools`` is ``(k, v, k_scale, v_scale)``, the
+    scales ``None`` on an unquantized cache; returns ``(x, pools)`` with
+    the pools back in the cache's ``[L, P, ...]`` shape."""
+    L, P = cache.k.shape[:2]
+    # scale pools are None on an unquantized cache: an empty pytree node,
+    # so one 4-tuple serves both kinds through the scan's carry
+    pools = (cache.k, cache.v, cache.k_scale, cache.v_scale)
+    flat = jax.tree.map(lambda a: a.reshape(L * P, *a.shape[2:]), pools)
+
+    def body(carry, layer):
+        x, pools = carry
+        lp, base = layer
+        x, pools = layer_fn(x, lp, pools, base)
+        return (x, pools), None
+
+    bases = jnp.arange(L, dtype=jnp.int32) * P
+    (x, flat), _ = lax.scan(body, (x, flat), (layers, bases))
+    return x, jax.tree.map(lambda a, full: a.reshape(full.shape), flat, pools)
+
+
+def _write_kv(pools, k_new, v_new, pids, offs, qmax):
+    """This layer's K/V rows into the carried pools ``(k, v, k_scale,
+    v_scale)``; with scale pools (a quantized cache) the written amax
+    folds into the block scale. ``pids`` already carry the layer's offset."""
+    k, v, ks, vs = pools
+    if ks is None:
+        return (scatter_block_kv(k, k_new, pids, offs),
+                scatter_block_kv(v, v_new, pids, offs), None, None)
+    k, ks = scatter_block_kv(k, k_new, pids, offs, scale=ks, qmax=qmax)
+    v, vs = scatter_block_kv(v, v_new, pids, offs, scale=vs, qmax=qmax)
+    return k, v, ks, vs
+
+
 def _decode_step(params, cache: PagedKVCache, table, state: _SlotState, *,
                  cfg: LlamaConfig, decode_impl: str, kv_block: int,
                  max_top_k: int, monitors: bool = False, quant_kv: str = "",
@@ -1930,7 +1996,11 @@ def _decode_step(params, cache: PagedKVCache, table, state: _SlotState, *,
     the physical block its table names — dead slots steer to the scratch
     block so a freed, possibly reallocated block can never be corrupted),
     attend over its written prefix through the table, sample with its own
-    stream. ``monitors`` additionally returns the fused per-slot health
+    stream. The pools ride the layer scan as its carry and are written in
+    place (:func:`_scan_layers_paged`); ``table`` and the write ids name
+    blocks of one layer and take the layer's offset inside the scan, so a
+    dead slot's row lands in that layer's scratch block.
+    ``monitors`` additionally returns the fused per-slot health
     monitors (logits nonfinite counts + sampling entropy, obs/health.py);
     the dict is empty when disarmed so the signature stays stable.
 
@@ -1966,47 +2036,28 @@ def _decode_step(params, cache: PagedKVCache, table, state: _SlotState, *,
         SCRATCH_BLOCK,
     )
 
-    def block(x, layer):
-        if quant_kv:
-            lp, k_pool, v_pool, k_sc, v_sc = layer
-        else:
-            lp, k_pool, v_pool = layer
-            k_sc = v_sc = None
+    def block(x, lp, pools, base):
         h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
         mm = partial(_q_mm, quant_weights=quant_weights, impl=decode_impl)
         q = rope(mm(h, lp, "wq").reshape(S, H, hd))
         k_new = rope(mm(h, lp, "wk").reshape(S, Hkv, hd))
         v_new = mm(h, lp, "wv").reshape(S, Hkv, hd)
-        # per-row scatter into the pool (advanced indices pid/off move the
-        # row dim to the front: the slice value is [S, Hkv, hd] directly);
-        # quantized pools fold the written amax into the block scale
-        if quant_kv:
-            k_pool, k_sc = scatter_block_kv(
-                k_pool, k_new, pid, off, scale=k_sc, qmax=qmax
-            )
-            v_pool, v_sc = scatter_block_kv(
-                v_pool, v_new, pid, off, scale=v_sc, qmax=qmax
-            )
-        else:
-            k_pool = scatter_block_kv(k_pool, k_new, pid, off)
-            v_pool = scatter_block_kv(v_pool, v_new, pid, off)
+        # in-place row writes into the carried pool at this layer's blocks
+        # (pid is a block of ONE layer; base = l * P moves it — and the
+        # scratch block — into layer l's range)
+        pools = _write_kv(pools, k_new, v_new, pid + base, off, qmax)
+        k_pool, v_pool, k_sc, v_sc = pools
         attn = decode_attention(
-            q, k_pool, v_pool, pos + 1, tables=table,
+            q, k_pool, v_pool, pos + 1, tables=table + base,
             impl=decode_impl, block=kv_block, k_scale=k_sc, v_scale=v_sc,
         )
         x = x + mm(attn.reshape(S, H * hd), lp, "wo")
         h2 = rms_norm(x, lp["ffn_norm"], cfg.norm_eps)
         delta = mm(jax.nn.silu(mm(h2, lp, "w1")) * mm(h2, lp, "w3"),
                    lp, "w2")
-        pools = (k_pool, v_pool) if not quant_kv else (
-            k_pool, v_pool, k_sc, v_sc
-        )
         return x + delta, pools
 
-    xs = (params["layers"], cache.k, cache.v)
-    if quant_kv:
-        xs = xs + (cache.k_scale, cache.v_scale)
-    x, pools = lax.scan(block, x, xs)
+    x, pools = _scan_layers_paged(block, x, params["layers"], cache)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     if quant_weights:
         logits = quant_matmul(
@@ -2084,46 +2135,27 @@ def _spec_decode_step(params, cache: PagedKVCache, table, state: _SlotState,
     )
     off = jnp.where(write_ok, off, 0)
 
-    def block(x, layer):
-        if quant_kv:
-            lp, k_pool, v_pool, k_sc, v_sc = layer
-        else:
-            lp, k_pool, v_pool = layer
-            k_sc = v_sc = None
+    def block(x, lp, pools, base):
         h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
         mm = partial(_q_mm, quant_weights=quant_weights, impl=decode_impl)
         q = rope(mm(h, lp, "wq").reshape(S, G, H, hd))
         k_new = rope(mm(h, lp, "wk").reshape(S, G, Hkv, hd))
         v_new = mm(h, lp, "wv").reshape(S, G, Hkv, hd)
-        if quant_kv:
-            k_pool, k_sc = scatter_block_kv(
-                k_pool, k_new, pid, off, scale=k_sc, qmax=qmax
-            )
-            v_pool, v_sc = scatter_block_kv(
-                v_pool, v_new, pid, off, scale=v_sc, qmax=qmax
-            )
-        else:
-            k_pool = scatter_block_kv(k_pool, k_new, pid, off)
-            v_pool = scatter_block_kv(v_pool, v_new, pid, off)
+        pools = _write_kv(pools, k_new, v_new, pid + base, off, qmax)
+        k_pool, v_pool, k_sc, v_sc = pools
         # multi-query paged attention: query g of row s sees positions
         # < pos0[s] + g + 1 (lengths arg = pos0 + G, kernel offsets per g)
         attn = decode_attention(
-            q, k_pool, v_pool, pos0 + G, tables=table,
+            q, k_pool, v_pool, pos0 + G, tables=table + base,
             impl=decode_impl, block=kv_block, k_scale=k_sc, v_scale=v_sc,
         )
         x = x + mm(attn.reshape(S, G, H * hd), lp, "wo")
         h2 = rms_norm(x, lp["ffn_norm"], cfg.norm_eps)
         delta = mm(jax.nn.silu(mm(h2, lp, "w1")) * mm(h2, lp, "w3"),
                    lp, "w2")
-        pools = (k_pool, v_pool) if not quant_kv else (
-            k_pool, v_pool, k_sc, v_sc
-        )
         return x + delta, pools
 
-    xs = (params["layers"], cache.k, cache.v)
-    if quant_kv:
-        xs = xs + (cache.k_scale, cache.v_scale)
-    x, pools = lax.scan(block, x, xs)
+    x, pools = _scan_layers_paged(block, x, params["layers"], cache)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     if quant_weights:
         logits = quant_matmul(
@@ -2164,7 +2196,9 @@ def _spec_decode_fn(cfg: LlamaConfig, decode_impl: str, kv_block: int,
                     max_top_k: int, draft_k: int, monitors: bool = False,
                     quant_kv: str = "", quant_weights: bool = False):
     """Jitted speculative verify step — same cache discipline as
-    :func:`_decode_fn` (per model/kernel knobs, table not donated)."""
+    :func:`_decode_fn` (per model/kernel knobs; cache and state donated,
+    pools carried through the layer scan and written in place; table not
+    donated)."""
     def serve_spec_decode(params, cache, table, state, drafts, draft_len):
         return _spec_decode_step(
             params, cache, table, state, drafts, draft_len, cfg=cfg,
