@@ -248,3 +248,49 @@ def test_train_step_fsdp4_shards_the_state_over_the_2x2_mesh(topo):
     share = four.argument_size_in_bytes / one.argument_size_in_bytes
     assert 0.24 < share < 0.27, (share, four, one)
     assert "all-gather" in hlo
+
+
+@pytest.mark.parametrize("lanes", [128, 1], ids=["rows-640", "rows-576"])
+def test_latent_decode_step_at_published_widths_keeps_the_pool_in_place(one_chip, lanes,
+                                                                        monkeypatch):
+    """The latent-attention family's decode step at the serving cell's widths
+    (2 expert layers are enough for the plan; 48 slots, the 3136-block pool):
+    compiles for one chip, and with cache rows padded to the 128 lanes the
+    pool is one buffer — no pool-shaped copy, temporaries far under a pool.
+    With 576-wide rows the compiler moves the pool's block dimension
+    minor-most and relays the whole pool out, which is why the rows are padded
+    (models/latent_moe.py ``CACHE_LANES``, a constant: the unpadded case is
+    made here by patching it); that case is pinned so that a compiler which
+    stops doing it is noticed."""
+    from tony_tpu.models import latent_moe
+    from tony_tpu.models.latent_moe import LatentMoEConfig, init_params
+    from tony_tpu.serve.cache import PagedKVCache
+    from tony_tpu.serve.capacity import _state_avals
+    from tony_tpu.serve.engine import _decode_fn
+
+    monkeypatch.setattr(latent_moe, "CACHE_LANES", lanes)
+    cfg = LatentMoEConfig(vocab_size=16160, n_layers=3, n_dense_layers=1, n_local_experts=16,
+                          max_seq_len=4096)
+    assert cfg.cache_width == (640 if lanes == 128 else 576)
+    S, P = 48, 3136
+
+    def sds(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = sds(jax.eval_shape(partial(init_params, cfg=cfg), jax.random.key(0)))
+    pool = jax.ShapeDtypeStruct((3, P, 1, 64, cfg.cache_width), BF16, sharding=one_chip)
+    cache = PagedKVCache(pool, None, jax.ShapeDtypeStruct((S,), I32, sharding=one_chip))
+    table = jax.ShapeDtypeStruct((S, 64), I32, sharding=one_chip)
+    compiled = _decode_fn(cfg, "scan", 64, 64).lower(
+        params, cache, table, sds(_state_avals(S))).compile()
+    mem = compiled.memory_analysis()
+    pool_bytes = 3 * P * 64 * cfg.cache_width * 2
+    relaid = [l for l in compiled.as_text().splitlines()
+              if f"bf16[3,{P},1,64,{cfg.cache_width}]" in l and " copy(" in l]
+    if lanes == 128:
+        assert not relaid, relaid[:2]
+        assert mem.temp_size_in_bytes < pool_bytes // 4, mem
+        assert mem.alias_size_in_bytes >= pool_bytes
+    else:
+        assert relaid and mem.temp_size_in_bytes > pool_bytes

@@ -9,8 +9,10 @@ north-star metric (BASELINE.md: >= 45% MFU target).
 from __future__ import annotations
 
 from dataclasses import dataclass
+from typing import Any
 
 import jax
+import numpy as np
 
 # Peak dense bf16 FLOP/s per chip, keyed by the ``device_kind`` the installed
 # libtpu reports for each generation (public spec-sheet numbers; v5e: Google
@@ -154,10 +156,30 @@ class DecodeMetrics:
     spec_rollbacks: int = 0        # ... of which were rejected (discarded)
     kv_bytes_per_token: float = 0.0  # HBM per cached token (block bytes /
     #                                  positions; halves with quantized pools)
+    # expert layers (latent-attention family, serve/latent.py): what the
+    # programs routed to the experts held HERE, read with the tokens
+    moe_routes: Any = None         # [expert layers, local experts] int64 sum
+    moe_tokens: int = 0            # tokens routed (prompt + decode)
+    moe_experts_hit: Any = None    # [expert layers] int64, summed over steps
+    moe_steps: int = 0             # decode steps counted in moe_experts_hit
 
     def record_prompt(self, plen: int, hit_tokens: int = 0) -> None:
         self.prompt_tokens += plen
         self.prefix_hit_tokens += hit_tokens
+
+    def record_moe(self, routes, tokens, step: bool = True) -> None:
+        """One program's expert routes ``[expert layers, local experts]``
+        over ``tokens`` tokens; ``step`` = a decode step (its experts hit
+        count toward the per-step mean), else a prefill."""
+        routes = np.asarray(routes, np.int64)
+        if self.moe_routes is None:
+            self.moe_routes = np.zeros_like(routes)
+            self.moe_experts_hit = np.zeros(routes.shape[0], np.int64)
+        self.moe_routes += routes
+        self.moe_tokens += int(tokens)
+        if step:
+            self.moe_experts_hit += (routes > 0).sum(axis=1)
+            self.moe_steps += 1
 
     def record_spec(self, proposed: int, accepted: int) -> None:
         """One speculative step's draft accounting (serve/spec.py)."""

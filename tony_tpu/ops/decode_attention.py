@@ -151,28 +151,34 @@ def _decode_scan(q, k, v, lengths, *, scale, block):
 
 
 def _paged_scan(q, k, v, lengths, tables, *, scale, k_scale=None,
-                v_scale=None):
+                v_scale=None, v_width=0):
     """Online-softmax scan over *logical* blocks, each row's block gathered
     through its table entry (native GQA contraction, paged pools). Given
     ``k_scale``/``v_scale`` ``[P, Hkv]`` the pools are quantized: the scale
     row is gathered right next to the block gather and the tile is
-    dequantized in registers (serve/cache.py block-scaled quantization)."""
+    dequantized in registers (serve/cache.py block-scaled quantization).
+
+    Latent form (``v`` is None, ``v_width`` given): the pool holds one
+    shared row per position (``Hkv`` 1), every query head contracts against
+    all of it, and the values are its first ``v_width`` columns — each
+    block is gathered once and serves as keys and as values."""
     B, G, H, hd = q.shape
     Hkv, blk = k.shape[1], k.shape[2]
     rep = H // Hkv
     nb = tables.shape[1]
+    vd = hd if v is not None else v_width
     qg = q.reshape(B, G, Hkv, rep, hd)
     goff = jnp.arange(G, dtype=jnp.int32)
 
     m0 = jnp.full((B, G, Hkv, rep), _NEG, jnp.float32)
     l0 = jnp.zeros((B, G, Hkv, rep), jnp.float32)
-    acc0 = jnp.zeros((B, G, Hkv, rep, hd), jnp.float32)
+    acc0 = jnp.zeros((B, G, Hkv, rep, vd), jnp.float32)
 
     def body(carry, j):
         m, l, acc = carry
         pid = lax.dynamic_index_in_dim(tables, j, axis=1, keepdims=False)
         kb = jnp.take(k, pid, axis=0)                          # [B, Hkv, blk, hd]
-        vb = jnp.take(v, pid, axis=0)
+        vb = jnp.take(v, pid, axis=0) if v is not None else kb[..., :vd]
         if k_scale is not None:
             ksc = jnp.take(k_scale, pid, axis=0)               # [B, Hkv]
             vsc = jnp.take(v_scale, pid, axis=0)
@@ -202,7 +208,26 @@ def _paged_scan(q, k, v, lengths, tables, *, scale, k_scale=None,
         body, (m0, l0, acc0), jnp.arange(nb, dtype=jnp.int32)
     )
     out = acc / jnp.maximum(l, 1e-30)[..., None]
-    return out.reshape(B, G, H, hd).astype(q.dtype)
+    return out.reshape(B, G, H, vd).astype(q.dtype)
+
+
+def latent_decode_attention(q: jax.Array, pool: jax.Array, lengths: jax.Array,
+                            tables: jax.Array, *, v_width: int,
+                            scale: float) -> jax.Array:
+    """Absorbed latent attention, one query token per row, through the block
+    table: ``q [B, H, kv_rank + rope]`` (the key half of the up-projection
+    already folded into it) against the latent pool ``[P, 1, block, kv_rank
+    + rope]``; the values are the first ``v_width`` (= kv_rank) columns of
+    the very rows the scores read. Returns ``[B, H, v_width]`` — the caller
+    applies the value half of the up-projection. Scan form only. A pool
+    whose rows are wider than the query (zero lanes beyond the latent, the
+    cache's padding) is contracted whole against a zero-padded query."""
+    if pool.shape[1] != 1 or pool.shape[3] < q.shape[-1]:
+        raise ValueError(f"latent decode shapes q={q.shape} pool={pool.shape}")
+    q = jnp.pad(q, ((0, 0), (0, 0), (0, pool.shape[3] - q.shape[-1])))
+    out = _paged_scan(q[:, None], pool, None, lengths, tables, scale=scale,
+                      v_width=v_width)
+    return out[:, 0]
 
 
 # --- pallas (TPU) implementation ----------------------------------------------
@@ -546,4 +571,7 @@ def decode_attention(
     return out[:, 0] if squeeze else out
 
 
-__all__ = ["decode_attention", "reference_decode_attention"]
+__all__ = [
+    "decode_attention", "latent_decode_attention",
+    "reference_decode_attention",
+]

@@ -83,18 +83,29 @@ def _pick_block(n: int, pref: int) -> int:
 # --- scan (XLA) implementation ------------------------------------------------
 
 
-def _gmm_scan(x: jax.Array, w: jax.Array, tile_group: jax.Array) -> jax.Array:
+def _gmm_scan(x: jax.Array, w: jax.Array, tile_group: jax.Array,
+              n_valid_tiles: jax.Array | None = None) -> jax.Array:
     """lax.scan over row tiles: slice tile i, take its group's weights, dot.
     Autodiff transposes the slice/take into the scatter-adds of the
-    backward — no custom VJP needed."""
+    backward — no custom VJP needed. With ``n_valid_tiles`` (a traced
+    count) tiles at or beyond it are skipped — zeros out, weights unread:
+    the static tile bound of a dropless layout is mostly such tiles when
+    few of the routes are local (parallel/moe.py ``_local_grouped``)."""
     n_tiles = tile_group.shape[0]
     br = x.shape[0] // n_tiles
 
-    def body(_, i):
+    def tile(i):
         xt = lax.dynamic_slice_in_dim(x, i * br, br)
         wg = jnp.take(w, tile_group[i], axis=0)
         yt = jnp.dot(xt, wg, preferred_element_type=jnp.float32)
-        return None, yt.astype(x.dtype)
+        return yt.astype(x.dtype)
+
+    def body(_, i):
+        if n_valid_tiles is None:
+            return None, tile(i)
+        return None, lax.cond(
+            i < n_valid_tiles, tile,
+            lambda i: jnp.zeros((br, w.shape[-1]), x.dtype), i)
 
     _, ys = lax.scan(body, None, jnp.arange(n_tiles, dtype=jnp.int32))
     return ys.reshape(x.shape[0], w.shape[-1])
@@ -265,9 +276,12 @@ def grouped_matmul(
     *,
     impl: str = "scan",
     block_cols: int = 512,
+    n_valid_tiles: jax.Array | None = None,
 ) -> jax.Array:
     """``[N, D] x [G, D, F] -> [N, F]`` where row tile ``i`` (of
     ``N / len(tile_group)`` rows) contracts against ``w[tile_group[i]]``.
+    ``n_valid_tiles`` (scan impl; the Pallas kernel computes every tile):
+    tiles from that index on hold padding only and come back zero.
 
     ``x`` must be laid out by :func:`grouped_layout` (group-contiguous,
     block-aligned, zero padding rows). Differentiable under both impls.
@@ -283,7 +297,7 @@ def grouped_matmul(
         return _gmm_pallas(x, w, tile_group, block_cols)
     if impl != "scan":
         raise ValueError(f"unknown gmm impl {impl!r} (expected scan | pallas)")
-    return _gmm_scan(x, w, tile_group)
+    return _gmm_scan(x, w, tile_group, n_valid_tiles)
 
 
 __all__ = ["grouped_layout", "grouped_matmul"]

@@ -426,7 +426,155 @@ def moe_block(params: dict[str, Any], x: jax.Array, cfg: MoEConfig):
     return y.reshape(B, S, D), aux
 
 
+# --- sigmoid, group-limited routing over a LOCAL range of experts ---------------
+#
+# The serving path of the latent-attention expert decoder (models/latent_moe.py).
+# The softmax top-k block above is the training path and is untouched by it.
+
+
+@dataclass(frozen=True)
+class GroupRouting:
+    """Auxiliary-loss-free routing as published for sigmoid-scored experts:
+    ``n_experts`` router outputs in ``n_groups`` equal groups; a token may
+    only choose from its ``topk_groups`` best groups."""
+
+    n_experts: int
+    top_k: int
+    n_groups: int = 1
+    topk_groups: int = 1
+    routed_scale: float = 1.0
+    norm_topk_prob: bool = True
+
+
+def route_group_limited(flat: jax.Array, router: jax.Array, bias: jax.Array,
+                        r: GroupRouting) -> tuple[jax.Array, jax.Array]:
+    """``flat [T, D]`` -> ``(experts [T, k] int32, gates [T, k] float32)``.
+
+    ``s = sigmoid(flat @ router)`` in float32. SELECTION uses ``s + bias``
+    (the load-balancing correction): a group's score is the sum of its two
+    largest biased scores, the ``topk_groups`` best groups stay, and the
+    ``k`` largest biased scores among them are the token's experts. GATING
+    uses ``s`` itself: ``routed_scale * s_e / sum_{e in K} s_e`` (the sum
+    over ALL k chosen experts, wherever they live)."""
+    T = flat.shape[0]
+    logits = jnp.matmul(flat.astype(jnp.float32), router.astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    s = jax.nn.sigmoid(logits)                                # [T, E]
+    sb = s + bias.astype(jnp.float32)
+    if r.n_groups > 1:
+        g = sb.reshape(T, r.n_groups, r.n_experts // r.n_groups)
+        gscore = jnp.sum(jax.lax.top_k(g, 2)[0], axis=-1)     # [T, G]
+        _, gidx = jax.lax.top_k(gscore, r.topk_groups)
+        keep = jnp.any(gidx[..., None] == jnp.arange(r.n_groups), axis=1)
+        sb = jnp.where(keep[..., None], g, -jnp.inf).reshape(T, r.n_experts)
+    _, sel = jax.lax.top_k(sb, r.top_k)
+    gates = jnp.take_along_axis(s, sel, axis=-1)
+    if r.norm_topk_prob:
+        gates = gates / (jnp.sum(gates, axis=-1, keepdims=True) + 1e-20)
+    return sel.astype(jnp.int32), gates * r.routed_scale
+
+
+def _local_small(params, flat, rel, mine, gates, n_local, first_row):
+    """At most a row tile of tokens: every local expert runs ONE tile that
+    holds all ``T`` tokens, weighted by the token's gate for it (0 where it
+    was not chosen) — no sort, no scatter, no padding to ``group_block``
+    rows an expert; each expert's weights are read once, where they lie
+    (row ``first_row + e`` of the flat ``[rows, D, F]`` stacks), and an
+    expert no token chose is skipped (``lax.cond``: its weights are not
+    read)."""
+    onehot = (rel[..., None] == jnp.arange(n_local)) & mine[..., None]  # [T,k,n]
+    gate_e = jnp.sum(jnp.where(onehot, gates[..., None], 0.0), axis=1)  # [T,n]
+    routes = jnp.sum(onehot, axis=(0, 1)).astype(jnp.int32)             # [n]
+
+    def body(acc, eg):
+        e, g, n = eg
+
+        def run(acc):
+            w1, w3, w2 = (jax.lax.dynamic_index_in_dim(params[k], first_row + e, keepdims=False)
+                          for k in ("w1", "w3", "w2"))
+            h = jax.nn.silu(flat @ w1) * (flat @ w3)
+            return acc + (h @ w2).astype(jnp.float32) * g[:, None]
+
+        return jax.lax.cond(n > 0, run, lambda acc: acc, acc), None
+
+    acc, _ = jax.lax.scan(
+        body, jnp.zeros(flat.shape, jnp.float32),
+        (jnp.arange(n_local), gate_e.T, routes),
+    )
+    return acc.astype(flat.dtype), routes
+
+
+def _local_grouped(params, flat, rel, mine, gates, n_local, first_row, block):
+    """Dropless sorted grouped products over the LOCAL routes only: routes to
+    experts that live elsewhere sort behind the last local group and are
+    never gathered, and row tiles beyond the last used one are skipped."""
+    from tony_tpu.ops.grouped_mm import grouped_layout, grouped_matmul
+
+    T, D = flat.shape
+    k = rel.shape[1]
+    R = T * k
+    grp = jnp.where(mine, rel, n_local).reshape(R)
+    tok = jnp.repeat(jnp.arange(T, dtype=jnp.int32), k)
+    order = jnp.argsort(grp, stable=True)
+    g_s, tok_s, w_s = grp[order], tok[order], gates.reshape(R)[order]
+    routes = jnp.bincount(grp, length=n_local + 1)[:n_local].astype(jnp.int32)
+    n_tiles = -(-R // block) + n_local   # static bound: every route local
+    starts, tile_group = grouped_layout(routes, block, n_tiles)
+    n_used = jnp.sum(jnp.maximum((routes + block - 1) // block, 1))
+    local = g_s < n_local
+    g_c = jnp.minimum(g_s, n_local - 1)
+    compact_start = jnp.cumsum(routes) - routes
+    dst = jnp.where(
+        local, starts[g_c] + (jnp.arange(R, dtype=jnp.int32) - compact_start[g_c]),
+        n_tiles * block,                 # out of range: dropped below
+    )
+    x_pad = jnp.zeros((n_tiles * block, D), flat.dtype).at[dst].set(
+        flat[tok_s], mode="drop")
+    gmm = partial(grouped_matmul, tile_group=tile_group + first_row,
+                  n_valid_tiles=n_used)
+    h = jax.nn.silu(gmm(x_pad, params["w1"])) * gmm(x_pad, params["w3"])
+    y_pad = gmm(h, params["w2"])
+    rows = jnp.take(y_pad, dst, axis=0, mode="fill", fill_value=0)
+    contrib = jnp.where(local, w_s, 0.0).astype(flat.dtype)[:, None] * rows
+    return jnp.zeros((T, D), flat.dtype).at[tok_s].add(contrib), routes
+
+
+def local_expert_ffn(params: dict[str, Any], flat: jax.Array, sel: jax.Array,
+                     gates: jax.Array, *, first_expert: int = 0,
+                     layer: jax.Array | None = None,
+                     group_block: int = 128) -> tuple[jax.Array, jax.Array]:
+    """The part of an expert layer that THIS holder of experts computes:
+    ``sum_{e in K(t), first <= e < first + n_local} gates[t, e] * SwiGLU_e``.
+
+    ``params`` holds ``n_local`` experts (``w1/w3 [n_local, D, F]``, ``w2
+    [n_local, F, D]``), global ids ``first_expert ..``; ``sel``/``gates``
+    ``[T, k]`` are the router's choice over ALL experts (gates already
+    normalised over all ``k``; a ``sel`` of -1 names no expert). Terms of
+    experts that live elsewhere are left out — on an expert-parallel mesh
+    the caller sums the holders' parts; on one chip nothing stands in for
+    them. Dropless. Returns ``(y [T, D], routes [n_local] int32)``, the
+    routes each local expert served.
+
+    ``layer`` (a traced index): the weights are whole stacks ``[layers,
+    n_local, ...]`` and this is layer ``layer`` of them. They are viewed as
+    ``[layers * n_local, ...]`` and each expert's matrices are read where
+    they lie — a layer scan that sliced its own ``[n_local, ...]`` out would
+    copy every expert of the layer before the first product."""
+    if layer is None:
+        n_local, first_row = params["w1"].shape[0], 0
+    else:
+        n_local = params["w1"].shape[1]
+        params = {k: v.reshape(-1, *v.shape[2:]) for k, v in params.items()}
+        first_row = layer * n_local
+    rel = sel - first_expert
+    mine = (rel >= 0) & (rel < n_local)
+    if flat.shape[0] <= group_block:   # one tile holds every token
+        return _local_small(params, flat, rel, mine, gates, n_local, first_row)
+    return _local_grouped(params, flat, rel, mine, gates, n_local, first_row,
+                          group_block)
+
+
 __all__ = [
-    "MoEConfig", "init_moe_params", "logical_axes", "moe_block",
-    "routing_stats",
+    "GroupRouting", "MoEConfig", "init_moe_params", "local_expert_ffn",
+    "logical_axes", "moe_block", "route_group_limited", "routing_stats",
 ]

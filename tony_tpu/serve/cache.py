@@ -87,7 +87,13 @@ class PagedKVCache(NamedTuple):
 
     Quantized pools additionally carry ``k_scale``/``v_scale``
     ``[L, P, Hkv]`` float32 — one dequantization scale per physical block
-    per kv head (None on an unquantized cache)."""
+    per kv head (None on an unquantized cache).
+
+    A **latent cache** (:func:`pool_layout`) is ONE pool: ``k`` is
+    ``[L, P, 1, block, cache_width]`` — per token and layer the compressed
+    vector every head's keys and values are expanded from, then the rope
+    key all heads share, then zeros up to the lanes — and ``v`` is None (an empty pytree
+    node: every program that takes the cache simply has no second pool)."""
 
     k: jax.Array
     v: jax.Array
@@ -114,6 +120,18 @@ class PagedKVCache(NamedTuple):
         return self.k_scale is not None
 
 
+def pool_layout(cfg) -> tuple[int, int, int]:
+    """``(heads, width, pools)`` of the rows a model caches per token and
+    layer: ``n_kv_heads`` rows of ``head_dim`` in each of two pools (K and
+    V) for a grouped-query decoder; ONE row of ``kv_lora_rank +
+    qk_rope_head_dim`` values (padded to ``cfg.cache_width``) in one pool
+    for a latent-attention decoder (models/latent_moe.py), whose values
+    are a slice of the same row."""
+    if getattr(cfg, "kv_lora_rank", 0):
+        return 1, cfg.cache_width, 1
+    return cfg.n_kv_heads, cfg.head_dim, 2
+
+
 def create_cache(
     cfg, slots: int, n_blocks: int, block: int, dtype=None,
     quant_kv: str = "",
@@ -122,7 +140,15 @@ def create_cache(
     With ``quant_kv`` ('int8' | 'fp8_e4m3') the pools store the quantized
     dtype plus zeroed per-block-per-head scale pools (scale 0 = block
     holds nothing real yet)."""
-    shape = (cfg.n_layers, n_blocks, cfg.n_kv_heads, block, cfg.head_dim)
+    heads, width, pools = pool_layout(cfg)
+    shape = (cfg.n_layers, n_blocks, heads, block, width)
+    if pools == 1:
+        if quant_kv:
+            raise NotImplementedError("quant_kv: a latent cache has no quantized form")
+        return PagedKVCache(
+            jnp.zeros(shape, dtype or cfg.dtype), None,
+            jnp.zeros((slots,), jnp.int32),
+        )
     if quant_kv:
         qdt, _ = kv_quant_spec(quant_kv)
         sc = (cfg.n_layers, n_blocks, cfg.n_kv_heads)
@@ -150,6 +176,8 @@ def grow_cache(cache: PagedKVCache, n_blocks: int) -> PagedKVCache:
             jnp.pad(cache.k, pad), jnp.pad(cache.v, pad), cache.lengths,
             jnp.pad(cache.k_scale, spad), jnp.pad(cache.v_scale, spad),
         )
+    if cache.v is None:
+        return PagedKVCache(jnp.pad(cache.k, pad), None, cache.lengths)
     return PagedKVCache(
         jnp.pad(cache.k, pad), jnp.pad(cache.v, pad), cache.lengths
     )
@@ -167,6 +195,8 @@ def shrink_cache(cache: PagedKVCache, n_blocks: int) -> PagedKVCache:
             cache.k[:, :n_blocks], cache.v[:, :n_blocks], cache.lengths,
             cache.k_scale[:, :n_blocks], cache.v_scale[:, :n_blocks],
         )
+    if cache.v is None:
+        return PagedKVCache(cache.k[:, :n_blocks], None, cache.lengths)
     return PagedKVCache(
         cache.k[:, :n_blocks], cache.v[:, :n_blocks], cache.lengths
     )
@@ -260,7 +290,7 @@ def scatter_block_kv(pool: jax.Array, new: jax.Array, pids: jax.Array,
     blocks: one layer's ``[P, ...]`` slab, or the whole cache viewed as
     ``[L * P, ...]`` with the layer's offset ``l * P`` already added to
     ``pids`` (how the decode steps carry it through their layer scan,
-    serve/engine.py ``_scan_layers_paged``).
+    ``scan_layers_paged``).
 
     **Contract.** The pool is the caller's donated or loop-carried
     buffer and comes back as the SAME buffer: the write touches the
@@ -506,6 +536,50 @@ def unpack_payload(k: bytes, v: bytes, shape, dtype: str,
     )
 
 
+def scan_layers_paged(layer_fn, x, layers, cache: PagedKVCache,
+                       span: tuple[int, int] | None = None):
+    """Run ``layer_fn`` over the stacked layer weights with the paged
+    pools CARRIED through the scan — the one pool discipline of the
+    decode programs (plain and speculative, quantized or not).
+
+    The cache's ``[L, P, ...]`` pools (and scale pools) are viewed as
+    ``[L * P, ...]`` (a bitcast of the donated argument) and ride the
+    scan's carry; its ``xs`` are the layer weights and the layer's block
+    offset ``l * P`` only. ``layer_fn(x, lp, pools, base)`` adds ``base``
+    to the block ids it writes (``scatter_block_kv``) and to the table it
+    attends through, so layer ``l`` reads and writes blocks
+    ``[l * P, (l + 1) * P)`` — its own scratch block is ``l * P``, which is
+    where a dead slot's ``SCRATCH_BLOCK`` lands after the offset. Carried
+    and written by slice updates, the pool is one buffer from the
+    program's donated argument to its result. Do NOT hand the pools to the
+    scan as ``xs`` and take them back as stacked ``ys``: those are two
+    buffers of the loop, so every layer's slab is sliced out, relaid and
+    re-stacked — several pool-sized copies a step, 19 ms of a 48 ms step
+    on the chip (PERF.md §6, PR 26). ``pools`` is ``(k, v, k_scale, v_scale)``, the
+    scales ``None`` on an unquantized cache; returns ``(x, pools)`` with
+    the pools back in the cache's ``[L, P, ...]`` shape.
+
+    ``span = (lo, hi)``: ``layers`` is the stack of layers ``lo .. hi - 1``
+    of a model whose layers are not one stack (leading dense layers, then
+    expert layers: serve/latent.py scans each over the same carried pool)."""
+    L, P = cache.k.shape[:2]
+    lo, hi = span or (0, L)
+    # scale pools are None on an unquantized cache: an empty pytree node,
+    # so one 4-tuple serves both kinds through the scan's carry
+    pools = (cache.k, cache.v, cache.k_scale, cache.v_scale)
+    flat = jax.tree.map(lambda a: a.reshape(L * P, *a.shape[2:]), pools)
+
+    def body(carry, layer):
+        x, pools = carry
+        lp, base = layer
+        x, pools = layer_fn(x, lp, pools, base)
+        return (x, pools), None
+
+    bases = jnp.arange(lo, hi, dtype=jnp.int32) * P
+    (x, flat), _ = lax.scan(body, (x, flat), (layers, bases))
+    return x, jax.tree.map(lambda a, full: a.reshape(full.shape), flat, pools)
+
+
 def block_bytes(cfg, block: int, dtype=None, quant_kv: str = "") -> int:
     """HBM bytes one physical block costs (K + V across all layers).
     With ``quant_kv`` the payload is priced at the quantized dtype plus
@@ -517,7 +591,8 @@ def block_bytes(cfg, block: int, dtype=None, quant_kv: str = "") -> int:
         scales = 2 * cfg.n_layers * cfg.n_kv_heads * 4
         return payload + scales
     dt = jnp.dtype(dtype or cfg.dtype)
-    return 2 * cfg.n_layers * cfg.n_kv_heads * block * cfg.head_dim * dt.itemsize
+    heads, width, pools = pool_layout(cfg)
+    return pools * cfg.n_layers * heads * block * width * dt.itemsize
 
 
 class BlockPool:
@@ -627,8 +702,10 @@ __all__ = [
     "kv_quant_spec",
     "pack_payload",
     "payload_compatible",
+    "pool_layout",
     "quant_scatter_span",
     "quantize_values",
+    "scan_layers_paged",
     "scatter_block_kv",
     "shrink_cache",
     "unpack_payload",

@@ -33,11 +33,16 @@ import jax.numpy as jnp
 
 from tony_tpu.models.llama import LlamaConfig, init_params
 from tony_tpu.obs.compiles import aot_analysis
-from tony_tpu.serve.cache import PagedKVCache, blocks_for, kv_quant_spec
+from tony_tpu.serve.cache import (
+    PagedKVCache, blocks_for, kv_quant_spec, pool_layout,
+)
 
 
 def _param_avals(cfg: LlamaConfig):
-    return jax.eval_shape(partial(init_params, cfg=cfg), jax.random.key(0))
+    init = init_params
+    if pool_layout(cfg)[2] == 1:    # latent-attention family
+        from tony_tpu.models.latent_moe import init_params as init
+    return jax.eval_shape(partial(init, cfg=cfg), jax.random.key(0))
 
 
 def _tree_bytes(tree) -> int:
@@ -57,7 +62,8 @@ def _cache_avals(cfg: LlamaConfig, slots: int, capacity: int,
     exactly what the quantized engine allocates."""
     blocks = blocks_for(capacity, kv_block)
     n_phys = 1 + slots * blocks
-    shape = (cfg.n_layers, n_phys, cfg.n_kv_heads, kv_block, cfg.head_dim)
+    heads, width, pools = pool_layout(cfg)
+    shape = (cfg.n_layers, n_phys, heads, kv_block, width)
     pool_dtype = kv_quant_spec(quant_kv)[0] if quant_kv else cfg.dtype
     scale = None
     if quant_kv:
@@ -66,7 +72,7 @@ def _cache_avals(cfg: LlamaConfig, slots: int, capacity: int,
         )
     cache = PagedKVCache(
         k=jax.ShapeDtypeStruct(shape, pool_dtype),
-        v=jax.ShapeDtypeStruct(shape, pool_dtype),
+        v=jax.ShapeDtypeStruct(shape, pool_dtype) if pools == 2 else None,
         lengths=jax.ShapeDtypeStruct((slots,), jnp.int32),
         k_scale=scale,
         v_scale=scale,
@@ -115,7 +121,7 @@ def decode_step_analysis(cfg: LlamaConfig, *, slots: int, capacity: int,
     blocks = blocks_for(capacity, kv_block)
     from tony_tpu.serve.cache import block_bytes as _bb
 
-    pool_leaves = [cache.k, cache.v]
+    pool_leaves = [cache.k, cache.v]   # _tree_bytes skips a latent cache's None
     if cache.k_scale is not None:
         pool_leaves += [cache.k_scale, cache.v_scale]
     return {

@@ -72,9 +72,10 @@ from tony_tpu.ops.quant_mm import quant_matmul, quantize_weights
 from tony_tpu.serve.cache import (
     SCRATCH_BLOCK, BlockPayload, BlockPool, PagedKVCache, block_bytes,
     blocks_for, create_cache, dequantize_values, export_blocks, grow_cache,
-    kv_quant_spec, payload_compatible, quant_scatter_span, scatter_block_kv,
-    shrink_cache, write_block,
+    kv_quant_spec, payload_compatible, pool_layout, quant_scatter_span,
+    scan_layers_paged, scatter_block_kv, shrink_cache, write_block,
 )
+from tony_tpu.serve import latent as latent_steps
 from tony_tpu.serve.prefix import MatchResult, PrefixStore
 from tony_tpu.serve.spec import (
     DRAFT_SOURCES, propose_drafts, verify_and_accept,
@@ -275,7 +276,26 @@ class Engine:
     """
 
     def __init__(self, params: Params, cfg: LlamaConfig, serve: ServeConfig):
-        if cfg.is_moe:
+        """``cfg`` is a :class:`LlamaConfig` (dense grouped-query decoder)
+        or a ``models.latent_moe.LatentMoEConfig`` (latent attention,
+        sigmoid group-limited experts): same loop, pool and tables; the
+        step bodies and the cache's rows are the family's
+        (:func:`_is_latent`)."""
+        self._latent = _is_latent(cfg)
+        if self._latent:
+            for knob, why in latent_steps.REFUSED_KNOBS.items():
+                if getattr(serve, knob):
+                    raise NotImplementedError(
+                        f"{knob} is not supported for a latent-attention "
+                        f"model: {why}"
+                    )
+            if serve.decode_impl != "scan":
+                raise NotImplementedError(
+                    f"decode_impl={serve.decode_impl!r} is not supported for "
+                    "a latent-attention model: the absorbed latent decode "
+                    "has a scan form only"
+                )
+        elif cfg.is_moe:
             # forward_with_cache (the prefill path) has no expert FFN —
             # reject loudly instead of crashing at the first admission
             raise NotImplementedError(
@@ -932,17 +952,17 @@ class Engine:
                 # this call journals under the prefill's name, not
                 # anonymously
                 with self._ledger.label(f"serve.prefill[{bucket}]"):
-                    tok, carry, pk, pv = self._get_prefill(bucket)(
+                    tok, carry, pk, pv, *moe = self._get_prefill(bucket)(
                         self.params, jnp.asarray(padded), jnp.int32(plen - 1),
                         jnp.float32(req.temperature), jnp.int32(req.top_k),
                         jnp.float32(req.top_p), key,
                     )
                 self._scatter_prompt(slot, pk, pv, 0, plen)
             else:
-                tok, carry = self._tail_prefill(slot, prompt, matched, req, key)
+                tok, carry, *moe = self._tail_prefill(slot, prompt, matched, req, key)
             # EXPLICIT sync: the sampled first token steers admission on
             # the host (transfer-guard-clean under GRAFT_SANITIZE)
-            tok = int(jax.device_get(tok))
+            tok = self._fetch_first_token(tok, moe)
         with annotate("serve.activate"):
             self._activate_slot(slot, rid, req, prompt, tok, carry, t0)
 
@@ -960,11 +980,14 @@ class Engine:
         with trace.span("serve.prefill_chunk", rid=job.rid, slot=slot,
                         start=job.pos, end=end, final=final), \
                 annotate("serve.prefill_chunk"):
-            tok, carry = self._tail_prefill(
+            tok, carry, *moe = self._tail_prefill(
                 slot, job.prompt, job.pos, job.req, job.key, end=end
             )
             if final:
-                tok = int(jax.device_get(tok))
+                tok = self._fetch_first_token(tok, moe)
+            elif moe:
+                self.metrics.record_moe(*jax.device_get(
+                    (moe[0]["moe_routes"], moe[0]["moe_tokens"])), step=False)
         if not final:
             job.pos = end
             return
@@ -973,6 +996,16 @@ class Engine:
             self._activate_slot(
                 slot, job.rid, job.req, job.prompt, tok, carry, job.t0
             )
+
+    def _fetch_first_token(self, tok, moe: list) -> int:
+        """The prefill's one sync: the sampled token, and with it what the
+        prompt's expert layers routed here (latent-attention family)."""
+        if not moe:
+            return int(jax.device_get(tok))
+        tok, routes, n = jax.device_get(
+            (tok, moe[0]["moe_routes"], moe[0]["moe_tokens"]))
+        self.metrics.record_moe(routes, n, step=False)
+        return int(tok)
 
     def _activate_slot(self, slot: int, rid: int, req: Request,
                        prompt: np.ndarray, tok: int, carry, t0: float) -> None:
@@ -1246,14 +1279,14 @@ class Engine:
         tail = np.zeros((1, tb), np.int32)
         tail[0, :tail_len] = prompt[matched:plen]
         with self._ledger.label(f"serve.prefill_tail[{tb},{C}]"):
-            tok, carry, tk, tv = self._get_tail_prefill(tb, C)(
+            tok, carry, tk, tv, *moe = self._get_tail_prefill(tb, C)(
                 self.params, ctx_k, ctx_v, jnp.asarray(tail),
                 jnp.int32(matched), jnp.int32(tail_len - 1),
                 jnp.float32(req.temperature), jnp.int32(req.top_k),
                 jnp.float32(req.top_p), key,
             )
         self._scatter_prompt(slot, tk, tv, matched, plen)
-        return tok, carry
+        return (tok, carry, *moe)
 
     def _register_prompt(self, slot: int, prompt: np.ndarray) -> None:
         """Insert the prompt's full blocks into the prefix store (each new
@@ -1284,6 +1317,7 @@ class Engine:
         together. Each block is pinned (one extra pool reference) for the
         duration of the gather so LRU eviction cannot hand it away
         mid-export. None when nothing is resident."""
+        self._refuse_block_handoff()
         if self._store is None:
             return None
         B = self.serve.kv_block
@@ -1319,6 +1353,7 @@ class Engine:
         contract the chaos checker audits. Returns (adopted, freed);
         raises ValueError on an incompatible payload (the gang worker
         maps it to an error response, never a corrupted pool)."""
+        self._refuse_block_handoff()
         B = self.serve.kv_block
         nb = payload.n_blocks
         if len(tokens) != nb * B:
@@ -1355,6 +1390,13 @@ class Engine:
         self._c_handoff_adopted.inc(created)
         self._c_handoff_freed.inc(nb - created)
         return created, nb - created
+
+    def _refuse_block_handoff(self) -> None:
+        if self._latent:
+            raise NotImplementedError(
+                "gang block export/adopt is not supported for a "
+                "latent-attention model: BlockPayload ships (k, v) pools"
+            )
 
     def _maybe_shrink_pool(self) -> None:
         """Halve the pool while the trailing half is entirely free — a
@@ -1539,11 +1581,19 @@ class Engine:
             # tokens + done flags on host to steer admission — this is the
             # engine's one designed sync point per decode step
             with annotate("serve.sync"):
-                toks_np = np.asarray(jax.device_get(toks))
+                # a step's expert routes (latent-attention family) ride the
+                # tokens' own transfer: one device_get, no round trip of
+                # their own (a second one cost ~2 ms a step on the chip)
+                moe = ((hmon.pop("moe_routes"), hmon.pop("moe_tokens"))
+                       if "moe_routes" in hmon else ())
+                toks_np, *moe = jax.device_get((toks, *moe))
+                toks_np = np.asarray(toks_np)
                 emit_np = (
                     np.asarray(jax.device_get(n_emit)) if spec_step else None
                 )
                 done_np = jax.device_get(self.state.done)
+                if moe:
+                    self.metrics.record_moe(*moe)
             dt = time.perf_counter() - t0
         with annotate("serve.emit"):
             if spec_step:
@@ -1599,6 +1649,15 @@ class Engine:
         )
 
 
+def _is_latent(cfg) -> bool:
+    """The model family by what its cache holds (serve/cache.py
+    ``pool_layout``): a latent-attention decoder's step bodies are
+    serve/latent.py's, a dense grouped-query decoder's are below. The
+    programs keep ONE set of names (``jit_serve_prefill`` ...) whichever
+    bodies they run."""
+    return pool_layout(cfg)[2] == 1
+
+
 @functools.lru_cache(maxsize=512)
 def _prefill_fn(cfg: LlamaConfig, bucket: int, max_top_k: int):
     """Jitted bucketed prefill, cached per (model config, bucket): engines
@@ -1607,6 +1666,11 @@ def _prefill_fn(cfg: LlamaConfig, bucket: int, max_top_k: int):
     device trace then reads ``jit_serve_prefill`` where a partial gives
     ``jit__unknown`` (the same holds for every ``serve_*`` below)."""
     def serve_prefill(params, prompt, last_index, temp, top_k, top_p, key):
+        if _is_latent(cfg):
+            return latent_steps.prefill_step(
+                params, prompt, last_index, temp, top_k, top_p, key,
+                cfg=cfg, bucket=bucket, max_top_k=max_top_k,
+            )
         return _prefill_step(
             params, prompt, last_index, temp, top_k, top_p, key,
             cfg=cfg, bucket=bucket, max_top_k=max_top_k,
@@ -1621,6 +1685,11 @@ def _tail_fn(cfg: LlamaConfig, tb: int, max_top_k: int):
     config, tail bucket); jit itself caches per context width."""
     def serve_tail_prefill(params, ctx_k, ctx_v, tail, start, last_index,
                            temp, top_k, top_p, key):
+        if _is_latent(cfg):
+            return latent_steps.tail_prefill_step(
+                params, ctx_k, ctx_v, tail, start, last_index, temp, top_k,
+                top_p, key, cfg=cfg, tb=tb, max_top_k=max_top_k,
+            )
         return _tail_prefill_step(
             params, ctx_k, ctx_v, tail, start, last_index, temp, top_k,
             top_p, key, cfg=cfg, tb=tb, max_top_k=max_top_k,
@@ -1640,7 +1709,7 @@ def _decode_fn(cfg: LlamaConfig, decode_impl: str, kv_block: int,
     Contract: the cache (arg 1) and the slot state (arg 3) are DONATED and
     the pools come back as the same buffers — carried through the layer
     scan as ``[L * P, ...]`` and written in place, ``S x Hkv x hd`` values
-    per layer per pool (:func:`_scan_layers_paged`,
+    per layer per pool (:func:`scan_layers_paged`,
     ``serve/cache.scatter_block_kv``); the compiled step's temporaries do
     not grow with the pool (tests/test_perf_guard.py) and on the chip it
     holds no pool- or slab-shaped copy (PERF.md §5). A dead slot's write
@@ -1649,6 +1718,11 @@ def _decode_fn(cfg: LlamaConfig, decode_impl: str, kv_block: int,
     it is reused across steps — and names blocks of ONE layer; the step
     adds the layer's offset itself."""
     def serve_decode(params, cache, table, state):
+        if _is_latent(cfg):
+            return latent_steps.decode_step(
+                params, cache, table, state, cfg=cfg, kv_block=kv_block,
+                max_top_k=max_top_k, monitors=monitors,
+            )
         return _decode_step(
             params, cache, table, state, cfg=cfg, decode_impl=decode_impl,
             kv_block=kv_block, max_top_k=max_top_k, monitors=monitors,
@@ -1741,9 +1815,11 @@ def _aot_tail_prefill(cfg: LlamaConfig, tb: int, ctx: int, max_top_k: int,
         key = ("tail", cfg, tb, ctx, max_top_k, hash(shard), shard)
     except (AttributeError, TypeError):  # params are not jax arrays
         return fn
-    kv = _sds((cfg.n_layers, 1, ctx, cfg.n_kv_heads, cfg.head_dim), cfg.dtype)
+    heads, width, pools = pool_layout(cfg)
+    kv = _sds((cfg.n_layers, 1, ctx, heads, width), cfg.dtype)
     avals = (
-        params, kv, kv, _sds((1, tb), jnp.int32), _sds((), jnp.int32),
+        params, kv, kv if pools == 2 else None, _sds((1, tb), jnp.int32),
+        _sds((), jnp.int32),
         _sds((), jnp.int32), _sds((), jnp.float32), _sds((), jnp.int32),
         _sds((), jnp.float32), _sds((2,), jnp.uint32),
     )
@@ -1788,7 +1864,9 @@ def _scatter_fn(quant_kv: str = ""):
         # 3) are non-adjacent, so the indexed result moves to the front:
         # [W, L, Hkv, hd] — match it by transposing the span
         k = cache.k.at[:, pids, :, offs, :].set(pk.transpose(2, 0, 1, 3))
-        v = cache.v.at[:, pids, :, offs, :].set(pv.transpose(2, 0, 1, 3))
+        v = None    # a latent cache is one pool
+        if cache.v is not None:
+            v = cache.v.at[:, pids, :, offs, :].set(pv.transpose(2, 0, 1, 3))
         lengths = lax.dynamic_update_slice(cache.lengths, plen[None], (slot,))
         return PagedKVCache(k, v, lengths)
 
@@ -1804,8 +1882,10 @@ def _copy_block_fn(quant: bool = False):
     exactly what the shared source did."""
     def serve_copy_block(cache: PagedKVCache, src, dst):
         kb = lax.dynamic_slice_in_dim(cache.k, src, 1, axis=1)
-        vb = lax.dynamic_slice_in_dim(cache.v, src, 1, axis=1)
         k = lax.dynamic_update_slice_in_dim(cache.k, kb, dst, axis=1)
+        if cache.v is None:     # a latent cache is one pool
+            return PagedKVCache(k, None, cache.lengths)
+        vb = lax.dynamic_slice_in_dim(cache.v, src, 1, axis=1)
         v = lax.dynamic_update_slice_in_dim(cache.v, vb, dst, axis=1)
         if quant:
             ksb = lax.dynamic_slice_in_dim(cache.k_scale, src, 1, axis=1)
@@ -1853,6 +1933,8 @@ def _gather_fn(quant: bool = False, out_dtype=None):
             return g.transpose(0, 1, 3, 2, 4).reshape(
                 L, nC * blk, Hkv, hd
             )[:, None]                                 # [L, 1, C, Hkv, hd]
+        if cache.v is None:     # a latent cache is one pool
+            return one(cache.k, None), None
         return one(cache.k, cache.k_scale), one(cache.v, cache.v_scale)
 
     return jax.jit(serve_gather)
@@ -1937,44 +2019,6 @@ def _q_mm(h, lp, name, quant_weights, impl):
     return h @ lp[name]
 
 
-def _scan_layers_paged(layer_fn, x, layers, cache: PagedKVCache):
-    """Run ``layer_fn`` over the stacked layer weights with the paged
-    pools CARRIED through the scan — the one pool discipline of the
-    decode programs (plain and speculative, quantized or not).
-
-    The cache's ``[L, P, ...]`` pools (and scale pools) are viewed as
-    ``[L * P, ...]`` (a bitcast of the donated argument) and ride the
-    scan's carry; its ``xs`` are the layer weights and the layer's block
-    offset ``l * P`` only. ``layer_fn(x, lp, pools, base)`` adds ``base``
-    to the block ids it writes (``scatter_block_kv``) and to the table it
-    attends through, so layer ``l`` reads and writes blocks
-    ``[l * P, (l + 1) * P)`` — its own scratch block is ``l * P``, which is
-    where a dead slot's ``SCRATCH_BLOCK`` lands after the offset. Carried
-    and written by slice updates, the pool is one buffer from the
-    program's donated argument to its result. Do NOT hand the pools to the
-    scan as ``xs`` and take them back as stacked ``ys``: those are two
-    buffers of the loop, so every layer's slab is sliced out, relaid and
-    re-stacked — several pool-sized copies a step, 19 ms of a 48 ms step
-    on the chip (PERF.md §6, PR 26). ``pools`` is ``(k, v, k_scale, v_scale)``, the
-    scales ``None`` on an unquantized cache; returns ``(x, pools)`` with
-    the pools back in the cache's ``[L, P, ...]`` shape."""
-    L, P = cache.k.shape[:2]
-    # scale pools are None on an unquantized cache: an empty pytree node,
-    # so one 4-tuple serves both kinds through the scan's carry
-    pools = (cache.k, cache.v, cache.k_scale, cache.v_scale)
-    flat = jax.tree.map(lambda a: a.reshape(L * P, *a.shape[2:]), pools)
-
-    def body(carry, layer):
-        x, pools = carry
-        lp, base = layer
-        x, pools = layer_fn(x, lp, pools, base)
-        return (x, pools), None
-
-    bases = jnp.arange(L, dtype=jnp.int32) * P
-    (x, flat), _ = lax.scan(body, (x, flat), (layers, bases))
-    return x, jax.tree.map(lambda a, full: a.reshape(full.shape), flat, pools)
-
-
 def _write_kv(pools, k_new, v_new, pids, offs, qmax):
     """This layer's K/V rows into the carried pools ``(k, v, k_scale,
     v_scale)``; with scale pools (a quantized cache) the written amax
@@ -1997,7 +2041,7 @@ def _decode_step(params, cache: PagedKVCache, table, state: _SlotState, *,
     block so a freed, possibly reallocated block can never be corrupted),
     attend over its written prefix through the table, sample with its own
     stream. The pools ride the layer scan as its carry and are written in
-    place (:func:`_scan_layers_paged`); ``table`` and the write ids name
+    place (:func:`scan_layers_paged`); ``table`` and the write ids name
     blocks of one layer and take the layer's offset inside the scan, so a
     dead slot's row lands in that layer's scratch block.
     ``monitors`` additionally returns the fused per-slot health
@@ -2057,7 +2101,7 @@ def _decode_step(params, cache: PagedKVCache, table, state: _SlotState, *,
                    lp, "w2")
         return x + delta, pools
 
-    x, pools = _scan_layers_paged(block, x, params["layers"], cache)
+    x, pools = scan_layers_paged(block, x, params["layers"], cache)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     if quant_weights:
         logits = quant_matmul(
@@ -2155,7 +2199,7 @@ def _spec_decode_step(params, cache: PagedKVCache, table, state: _SlotState,
                    lp, "w2")
         return x + delta, pools
 
-    x, pools = _scan_layers_paged(block, x, params["layers"], cache)
+    x, pools = scan_layers_paged(block, x, params["layers"], cache)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     if quant_weights:
         logits = quant_matmul(
