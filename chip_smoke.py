@@ -32,6 +32,7 @@ rehearsal of the control flow, can get that far off-chip).
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 import math
 import os
@@ -279,23 +280,40 @@ def _near_tie(params, cfg, prompt, a, b) -> dict:
     ulp = 2.0 ** (math.floor(math.log2(max(abs(la), abs(lb), 1e-6))) - 7)
     return {"at": i, "tokens": [int(a[i]), int(b[i])],
             "logits": [round(la, 5), round(lb, 5)], "bf16_ulp": ulp,
-            "both_top2": {int(a[i]), int(b[i])}
-            == set(np.argsort(logits)[-2:].tolist())}
+            "below_top": round(float(logits.max()) - min(la, lb), 5)}
 
 
 def _same_or_tie(what: str, params, cfg, prompt, a, b) -> dict:
     """Greedy streams must be equal. bf16 matmuls of different shapes may
-    round a tie the other way; such a split is accepted only when the plain
-    forward pass rates both tokens top-2 and within two bfloat16 units in the
-    last place of each other, and is printed, never silent. Tokens after the
-    split are not compared."""
+    round a tie the other way, and so may the paged attention's two forms
+    (the scan rescales its sums every block, the kernel every 8 blocks);
+    such a split is accepted only when the plain forward pass rates both
+    tokens within two bfloat16 units in the last place of its best logit
+    (at random weights three tokens may tie there, so "the top two" is too
+    narrow), and is printed, never silent. Tokens after the split are not
+    compared."""
     if list(a) == list(b):
         return {"equal": True, "tokens": len(a)}
     tie = _near_tie(params, cfg, prompt, list(a), list(b))
-    gap = abs(tie["logits"][0] - tie["logits"][1])
-    check(tie["both_top2"] and gap <= 2 * tie["bf16_ulp"],
+    check(tie["below_top"] <= 2 * tie["bf16_ulp"],
           f"{what}: streams differ and it is no bf16 tie: {tie}")
     return {"equal": False, "equal_tokens": tie["at"], "bf16_tie": tie}
+
+
+@contextlib.contextmanager
+def paged_kernel(on: bool):
+    """The paged decode attention through its kernel (interpreted off the
+    chip) or through the XLA scan for the block's length: the op chooses from
+    the platform (ops/decode_attention.py ``_run_kernel``), so a comparison
+    of its two forms steers that name, as the tests do, and puts it back."""
+    from tony_tpu.ops import decode_attention as da
+
+    was = da._run_kernel
+    da._run_kernel = lambda: on
+    try:
+        yield
+    finally:
+        da._run_kernel = was
 
 
 def phase_engine(tiny: bool, seed: int) -> None:
@@ -319,13 +337,17 @@ def phase_engine(tiny: bool, seed: int) -> None:
     rng = np.random.default_rng(seed)
     prompts = [rng.integers(1, cfg.vocab_size, size=p).astype(np.int32) for p in plens]
     streams: dict[str, list[list[int]]] = {}
+    # two forms of the decode step at real width: the XLA scan over the whole
+    # table, and the paged kernel the chip runs. ``decode_impl`` reaches the
+    # attention no more, so the form is steered where the op chooses
     for impl in ("scan", "pallas"):
-        eng = Engine(params, cfg, ServeConfig(
-            slots=8, max_len=max_len, decode_impl=impl,
-        ))
-        t0 = time.perf_counter()
-        ids = [eng.submit(Request(prompt=p, max_new_tokens=new)) for p in prompts]
-        done = eng.run()
+        with paged_kernel(impl == "pallas"):
+            eng = Engine(params, cfg, ServeConfig(
+                slots=8, max_len=max_len, decode_impl=impl,
+            ))
+            t0 = time.perf_counter()
+            ids = [eng.submit(Request(prompt=p, max_new_tokens=new)) for p in prompts]
+            done = eng.run()
         streams[impl] = [list(done[i].tokens) for i in ids]
         summary = eng.close()
         say(f"engine.{impl}", n_params=cfg.n_params, requests=len(ids),
@@ -417,55 +439,64 @@ def phase_kernels(tiny: bool, expect: str) -> None:
         got, want = run("flash bwd", grads(flash), q, k, v), jax.jit(grads(ref))(q, k, v)
         for n, g, w in zip("qkv", got, want):
             errs[f"flash_d{n}[{tag}]"] = _close(f"flash d{n} {tag}", g, w, 4e-2)
-    # decode kernel: contiguous, paged, paged int8; G=1 and G=5
-    Bd, Hd, Hkv, hd, T, blkd = (2, 4, 2, 32, 128, 16) if tiny else (8, 32, 8, 128, 4096, 64)
-    m = T // blkd
-    kc, vc = rnd((Bd, Hkv, T, hd)), rnd((Bd, Hkv, T, hd))
-    lengths = jnp.asarray(
-        [T - 1 - 37 * i % (T // 2) for i in range(Bd)], jnp.int32)
-    # the same cache as a shuffled physical-block pool (block 0 = scratch)
-    perm = jax.random.permutation(next(keys), Bd * m) + 1
-    tables = perm.reshape(Bd, m).astype(jnp.int32)
+    # decode kernel: contiguous, paged, paged int8; G=1 and G=5. The paged
+    # form picks its kernel from the platform; the rehearsal off the chip asks
+    # for it too (interpreted), as the tests do
+    def decode_kernels(tag, Hd, Hkv, queries):
+        Bd, hd, T, blkd = (2, 32, 128, 16) if tiny else (8, 128, 4096, 64)
+        m = T // blkd
+        kc, vc = rnd((Bd, Hkv, T, hd)), rnd((Bd, Hkv, T, hd))
+        lengths = jnp.asarray(
+            [T - 1 - 37 * i % (T // 2) for i in range(Bd)], jnp.int32)
+        # the same cache as a shuffled physical-block pool (block 0 = scratch)
+        perm = jax.random.permutation(next(keys), Bd * m) + 1
+        tables = perm.reshape(Bd, m).astype(jnp.int32)
 
-    def to_pool(c):
-        blocks = c.reshape(Bd, Hkv, m, blkd, hd).transpose(0, 2, 1, 3, 4).reshape(
-            Bd * m, Hkv, blkd, hd)
-        return jnp.zeros((1 + Bd * m, Hkv, blkd, hd), c.dtype).at[perm].set(blocks)
+        def to_pool(c):
+            blocks = c.reshape(Bd, Hkv, m, blkd, hd).transpose(0, 2, 1, 3, 4).reshape(
+                Bd * m, Hkv, blkd, hd)
+            return jnp.zeros((1 + Bd * m, Hkv, blkd, hd), c.dtype).at[perm].set(blocks)
 
-    kp, vp = to_pool(kc), to_pool(vc)
+        kp, vp = to_pool(kc), to_pool(vc)
 
-    def quant(pool):
-        sc = jnp.abs(pool.astype(f32)).max(axis=(2, 3)) / 127.0       # [P, Hkv]
-        qp = jnp.round(pool.astype(f32) / jnp.maximum(sc, 1e-30)[:, :, None, None])
-        return qp.astype(jnp.int8), sc
+        def quant(pool):
+            sc = jnp.abs(pool.astype(f32)).max(axis=(2, 3)) / 127.0       # [P, Hkv]
+            qp = jnp.round(pool.astype(f32) / jnp.maximum(sc, 1e-30)[:, :, None, None])
+            return qp.astype(jnp.int8), sc
 
-    (kq, ksc), (vq, vsc) = quant(kp), quant(vp)
+        (kq, ksc), (vq, vsc) = quant(kp), quant(vp)
 
-    def dequant_cache(qp, sc):
-        pool = (qp.astype(f32) * sc[:, :, None, None]).astype(bf16)
-        return pool[tables].transpose(0, 2, 1, 3, 4).reshape(Bd, Hkv, T, hd)
+        def dequant_cache(qp, sc):
+            pool = (qp.astype(f32) * sc[:, :, None, None]).astype(bf16)
+            return pool[tables].transpose(0, 2, 1, 3, 4).reshape(Bd, Hkv, T, hd)
 
-    for G in (1, 5):
-        q = rnd((Bd, G, Hd, hd))
-        want = jax.jit(reference_decode_attention)(q, kc, vc, lengths)
-        errs[f"decode_contiguous[G{G}]"] = _close(
-            f"decode contiguous G{G}",
-            run("decode", lambda q, k, v, ln: decode_attention(
-                q, k, v, ln, impl="pallas", block=blkd), q, kc, vc, lengths),
-            want, 2e-2)
-        errs[f"decode_paged[G{G}]"] = _close(
-            f"decode paged G{G}",
-            run("paged", lambda q, k, v, ln, tb: decode_attention(
-                q, k, v, ln, tables=tb, impl="pallas"), q, kp, vp, lengths, tables),
-            want, 2e-2)
-        want_q = jax.jit(reference_decode_attention)(
-            q, dequant_cache(kq, ksc), dequant_cache(vq, vsc), lengths)
-        errs[f"decode_paged_int8[G{G}]"] = _close(
-            f"decode paged int8 G{G}",
-            run("paged int8", lambda q, k, v, ln, tb, ks, vs: decode_attention(
-                q, k, v, ln, tables=tb, impl="pallas", k_scale=ks, v_scale=vs),
-                q, kq, vq, lengths, tables, ksc, vsc),
-            want_q, 2e-2)
+        for G in queries:
+            q = rnd((Bd, G, Hd, hd))
+            want = jax.jit(reference_decode_attention)(q, kc, vc, lengths)
+            errs[f"decode_contiguous{tag}[G{G}]"] = _close(
+                f"decode contiguous{tag} G{G}",
+                run("decode", lambda q, k, v, ln: decode_attention(
+                    q, k, v, ln, impl="pallas", block=blkd), q, kc, vc, lengths),
+                want, 2e-2)
+            errs[f"decode_paged{tag}[G{G}]"] = _close(
+                f"decode paged{tag} G{G}",
+                run("paged", lambda q, k, v, ln, tb: decode_attention(
+                    q, k, v, ln, tables=tb), q, kp, vp, lengths, tables),
+                want, 2e-2)
+            want_q = jax.jit(reference_decode_attention)(
+                q, dequant_cache(kq, ksc), dequant_cache(vq, vsc), lengths)
+            errs[f"decode_paged_int8{tag}[G{G}]"] = _close(
+                f"decode paged int8{tag} G{G}",
+                run("paged int8", lambda q, k, v, ln, tb, ks, vs: decode_attention(
+                    q, k, v, ln, tables=tb, k_scale=ks, v_scale=vs),
+                    q, kq, vq, lengths, tables, ksc, vsc),
+                want_q, 2e-2)
+
+    with paged_kernel(True):
+        # GQA 32:8, then one query row a kv head at llama2_7b's 32 heads: the
+        # 512 KB tiles at which the paged kernel takes 4 blocks a step, not 8
+        decode_kernels("", *((4, 2) if tiny else (32, 8)), (1, 5))
+        decode_kernels("_mha", *((4, 4) if tiny else (32, 32)), (1,))
     # fused CE (pallas) value + grads vs the dense logsumexp reference
     for dim in ((64,) if tiny else (2048, 4096)):
         Bc, Sc, V = (2, 64, 256) if tiny else (2, 2048, 32000)

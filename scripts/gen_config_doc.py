@@ -248,9 +248,11 @@ def _serve_config_section() -> list[str]:
         "prefill_buckets": "prompt pad lengths — prefill compiles once per "
                            "bucket (bounded compile count); () -> powers "
                            "of two from 16 up to max_len",
-        "decode_impl": "decode attention kernel: scan (pure XLA, default) "
-                       "\\| pallas (TPU kernel, interpreted on CPU) — "
-                       "tony_tpu.ops.decode_attention",
+        "decode_impl": "form of the int8 weight matmul (quant.weights): "
+                       "scan (pure XLA, default) \\| pallas (fused kernel) "
+                       "— tony_tpu.ops.quant_mm; the paged decode attention "
+                       "does not read it (its kernel on a TPU, the scan "
+                       "elsewhere)",
         "max_top_k": "static top-k slice width for sampling; per-request "
                      "top_k clamps to it, and top-p-only requests use it "
                      "as the bounded nucleus candidate set",

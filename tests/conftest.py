@@ -40,6 +40,30 @@ def _reset_default_mesh():
     set_default_mesh(None)
 
 
+@pytest.fixture
+def paged_kernel(monkeypatch):
+    """``paged_kernel(on)``: run the paged decode attention through its Pallas
+    kernel (interpreted here) or through the XLA scan. The op chooses from the
+    platform (ops/decode_attention.py ``_run_kernel``), which is the CPU here,
+    so a test that wants the kernel steers that name — no option of the
+    program. Decode steps compiled under one choice must not serve the other:
+    the engine's module-wide step caches are dropped at every change."""
+    from tony_tpu.ops import decode_attention as da
+    from tony_tpu.serve import engine
+
+    def drop_steps():
+        engine._decode_fn.cache_clear()
+        engine._spec_decode_fn.cache_clear()
+        engine._aot_decode_cache.clear()
+
+    def steer(on: bool):
+        drop_steps()
+        monkeypatch.setattr(da, "_run_kernel", lambda: bool(on))
+
+    yield steer
+    drop_steps()
+
+
 def pytest_terminal_summary(terminalreporter, exitstatus, config):
     """scripts/lint.py-style budget line: tier-1 runs close to its 870s
     timeout, so every run prints the top-10 slowest tests — future PRs see

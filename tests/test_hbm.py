@@ -452,7 +452,14 @@ class TestShutdownSummaries:
     def test_engine_close_carries_ledger_lines(self):
         from tony_tpu.models.llama import LlamaConfig, init_params
         from tony_tpu.serve import Engine, Request, ServeConfig
+        from tony_tpu.serve import engine as engine_mod
 
+        # many test files run this very engine, and the worker that ran one
+        # of them before this file would serve every program from the
+        # module-wide step caches: no compile to count. Start from none.
+        engine_mod._prefill_fn.cache_clear()
+        engine_mod._decode_fn.cache_clear()
+        engine_mod._aot_decode_cache.clear()
         cfg = LlamaConfig.tiny()
         params = init_params(jax.random.key(0), cfg)
         eng = Engine(params, cfg, ServeConfig(slots=2, max_len=32, kv_block=8))

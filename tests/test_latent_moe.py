@@ -83,11 +83,15 @@ def _capture_logits(monkeypatch):
     return seen
 
 
+@pytest.mark.parametrize("kernel", [False, True], ids=["scan", "kernel"])
 @pytest.mark.parametrize("first,n_local", [(0, 0), (16, 8)])
-def test_prefill_then_paged_decode_matches_the_reference_logits(model, monkeypatch, first, n_local):
+def test_prefill_then_paged_decode_matches_the_reference_logits(model, monkeypatch, paged_kernel,
+                                                                 first, n_local, kernel):
     """Three slots of different prompt lengths: prefill each into the paged
     latent pool, then decode teacher-forced continuations through the block
-    table — every logit row against the reference's full forward."""
+    table — every logit row against the reference's full forward. Both forms
+    of the paged attention: the XLA scan, and the Pallas kernel (interpreted)."""
+    paged_kernel(kernel)
     cfg, params = model
     cfg = replace(cfg, first_expert=first, n_local_experts=n_local)
     params = share_of(params, first, cfg.n_local)
@@ -367,6 +371,24 @@ def test_engine_serves_mixed_requests_and_returns_every_slot_and_block(model, se
     assert m.moe_routes.shape == (2, 32) and m.moe_routes.sum() == m.moe_tokens * 4 * 2
     assert m.moe_steps == m.decode_steps and (m.moe_experts_hit <= 32 * m.moe_steps).all()
     assert (m.moe_experts_hit >= m.moe_steps).all()
+
+
+def test_engine_through_the_paged_kernel_serves_the_scan_paths_tokens(model, paged_kernel):
+    """The same requests end to end with the paged attention as the XLA scan
+    and as the Pallas kernel (interpreted; on the chip the op picks it): the
+    same greedy tokens, and the counters say how little of the table the
+    kernel had to read."""
+    outs = []
+    for kernel in (False, True):
+        paged_kernel(kernel)
+        eng = _engine(model, shrink=False)
+        rids = [eng.submit(Request(prompt=tokens_of(i, n), max_new_tokens=5))
+                for i, n in enumerate([5, 17, 30, 41])]
+        done = eng.run()
+        outs.append([done[r].tokens for r in rids])
+        m = eng.metrics
+        assert 0 < m.attn_blocks_live < m.attn_blocks_table <= m.decode_steps * 3 * (96 // 8)
+    assert outs[0] == outs[1]
 
 
 def test_counters_count_the_local_share_only(model):

@@ -163,7 +163,8 @@ class TestQuantKernel:
         (False, False, 3),   # G-query speculative verify form
         (True, True, 3),     # everything at once
     ])
-    def test_within_tolerance_of_bf16_and_impls_agree(self, shared, short, G):
+    def test_within_tolerance_of_bf16_and_impls_agree(self, shared, short, G,
+                                                      paged_kernel):
         q, (kq, vq, ksc, vsc), tables, lengths, (kp, vp) = self._case(
             seed=10 + G, G=G, shared=shared, short=short,
         )
@@ -172,8 +173,9 @@ class TestQuantKernel:
         )
         outs = {}
         for impl in ("scan", "pallas"):
+            paged_kernel(impl == "pallas")   # the paged form takes no impl
             out = decode_attention(
-                q, kq, vq, lengths, tables=tables, impl=impl,
+                q, kq, vq, lengths, tables=tables,
                 block=self.blk, k_scale=ksc, v_scale=vsc,
             )
             assert out.shape == ref.shape
@@ -186,15 +188,17 @@ class TestQuantKernel:
             outs["scan"], outs["pallas"], rtol=0, atol=1e-2,
         )
 
-    def test_poisoned_block_scale_hits_exactly_the_referencing_rows(self):
+    def test_poisoned_block_scale_hits_exactly_the_referencing_rows(
+            self, paged_kernel):
         q, (kq, vq, ksc, vsc), tables, lengths, _ = self._case(seed=20)
         # poison the scale row of row 0's second block; rows 1/2 never
         # reference it, so their outputs must stay finite
         bad = int(tables[0, 1])
         ksc = ksc.at[bad].set(jnp.nan)
         for impl in ("scan", "pallas"):
+            paged_kernel(impl == "pallas")
             out = np.asarray(decode_attention(
-                q, kq, vq, lengths, tables=tables, impl=impl,
+                q, kq, vq, lengths, tables=tables,
                 block=self.blk, k_scale=ksc, v_scale=vsc,
             ), np.float32)
             assert not np.isfinite(out[0]).all(), impl
@@ -335,11 +339,13 @@ class TestQuantEngine:
     # (TestQuantKernel) — the engine-level token identity re-pays two full
     # engine builds and tier-1 runs close to its wall-clock budget
     @pytest.mark.slow
-    def test_scan_and_pallas_quant_engines_emit_identical_tokens(self, setup):
+    def test_scan_and_pallas_quant_engines_emit_identical_tokens(
+            self, setup, paged_kernel):
         cfg, params = setup
         prompts = _prompts(cfg, [5, 9], seed=7)
         outs = []
         for impl in ("scan", "pallas"):
+            paged_kernel(impl == "pallas")
             eng = Engine(params, cfg, ServeConfig(
                 slots=2, max_len=24, kv_block=8, decode_impl=impl,
                 quant_kv="int8", quant_weights=True,

@@ -11,7 +11,7 @@ from tony_tpu.models import llama
 from tony_tpu.models.generate import generate
 from tony_tpu.ops.decode_attention import decode_attention, reference_decode_attention
 from tony_tpu.serve import Engine, Request, ServeConfig
-from tony_tpu.serve.cache import blocks_for
+from tony_tpu.serve.cache import SCRATCH_BLOCK, blocks_for
 
 
 @pytest.fixture(scope="module")
@@ -195,19 +195,198 @@ def test_decode_attention_ignores_positions_beyond_length():
         np.testing.assert_allclose(np.asarray(got), np.asarray(base), atol=1e-6)
 
 
-def test_engine_decode_impls_agree(setup):
-    """The engine produces identical greedy tokens under both decode
-    kernels (scan vs interpreted Pallas)."""
+def test_engine_decode_impls_agree(setup, paged_kernel):
+    """The engine produces identical greedy tokens under both forms of the
+    paged decode attention (the XLA scan vs the interpreted Pallas kernel,
+    which the op picks from the platform: the fixture steers it) and of
+    the ``decode_impl`` knob that still picks the int8 matmul's form."""
     cfg, params = setup
     prompts = _prompts(cfg, [3, 10], seed=6)
     outs = {}
     for impl in ("scan", "pallas"):
+        paged_kernel(impl == "pallas")
         eng = Engine(params, cfg, ServeConfig(
             slots=2, max_len=32, kv_block=8, decode_impl=impl,
         ))
         res = eng.run([Request(prompt=p, max_new_tokens=5) for p in prompts])
         outs[impl] = [res[i].tokens for i in range(len(prompts))]
     assert outs["scan"] == outs["pallas"]
+
+
+# --- the paged kernel: each row's live blocks only ------------------------------
+
+
+def _paged_case(form, G, tails):
+    """Six rows over a table of 20 blocks of 8 (the kernel walks 8 blocks a
+    step): lengths at 1 query's minimum, an exact multiple of the block,
+    one step's reach - 1 / exactly / + 1, mid-table, and the full table.
+    ``tails``: what the table names past a row's last block — ``"nan"``:
+    blocks of its own filled with NaN; ``"scratch"``: the scratch block,
+    filled with NaN too. ``form``: dense GQA (4 kv heads x 8), MHA (one
+    query row a kv head) or latent (one shared 32-wide row whose first 24
+    columns are the values and whose last 4 lanes are padding)."""
+    from tony_tpu.ops import decode_attention as da
+
+    blk, M, n = 8, 20, da._STEP_BLOCKS
+    Hkv, rep, hd, vw = {
+        "dense": (4, 8, 16, 0), "mha": (4, 1, 16, 0), "latent": (1, 16, 32, 24),
+    }[form]
+    lengths = np.asarray(
+        [G, 5 * blk, n * blk - 1, n * blk, n * blk + 1, M * blk], np.int32)
+    B = len(lengths)
+    rng = np.random.default_rng(5)
+    k = rng.standard_normal((1 + B * M, Hkv, blk, hd)).astype(np.float32)
+    v = rng.standard_normal((1 + B * M, Hkv, blk, hd)).astype(np.float32)
+    if vw:
+        k[..., hd - 4:] = 0.0                     # the cache's padded lanes
+    tables = 1 + np.arange(B * M, dtype=np.int32).reshape(B, M)
+    dead = np.arange(M)[None, :] >= -(-lengths[:, None] // blk)
+    clean_k, clean_v = k.copy(), v.copy()
+    if tails == "nan":
+        k[tables[dead]] = v[tables[dead]] = np.nan
+        clean_k[tables[dead]] = clean_v[tables[dead]] = 0.0
+    else:
+        tables[dead] = SCRATCH_BLOCK
+        k[SCRATCH_BLOCK] = v[SCRATCH_BLOCK] = np.nan
+        clean_k[SCRATCH_BLOCK] = clean_v[SCRATCH_BLOCK] = 0.0
+    q = rng.standard_normal((B, G, Hkv * rep, hd)).astype(np.float32)
+    if vw:
+        q[..., hd - 4:] = 0.0
+    kc = clean_k[tables].transpose(0, 2, 1, 3, 4).reshape(B, Hkv, M * blk, hd)
+    vc = clean_v[tables].transpose(0, 2, 1, 3, 4).reshape(B, Hkv, M * blk, hd)
+    ref = reference_decode_attention(
+        jnp.asarray(q), jnp.asarray(kc),
+        jnp.asarray(kc[..., :vw] if vw else vc), jnp.asarray(lengths),
+        scale=0.25,
+    )
+    args = (jnp.asarray(q), jnp.asarray(k), None if vw else jnp.asarray(v),
+            jnp.asarray(lengths), jnp.asarray(tables))
+    return args, dict(scale=0.25, **({"v_width": vw} if vw else {})), ref
+
+
+def _paged_kernel(args, kw):
+    """The kernel itself, at the blocks a step the op would give it."""
+    from tony_tpu.ops import decode_attention as da
+
+    q, k, v, _, tables = args
+    return np.asarray(da._paged_attend(
+        *args, n=da._step_blocks(k, v, tables), **kw))
+
+
+@pytest.mark.parametrize("tails", ["nan", "scratch"])
+@pytest.mark.parametrize("G", [1, 3])
+@pytest.mark.parametrize("form", ["dense", "mha", "latent"])
+def test_paged_kernel_reads_each_rows_live_blocks_only(form, G, tails):
+    """The paged kernel (interpreted) against the repeat-expanded reference
+    at ragged lengths, with every block past a row's length poisoned: the
+    output is finite and equal, so those blocks were neither fetched into
+    the result nor computed (the XLA scan, which gathers every table entry
+    and masks afterwards, reads NaN here)."""
+    from tony_tpu.ops import decode_attention as da
+
+    args, kw, ref = _paged_case(form, G, tails)
+    got = _paged_kernel(args, kw)
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got, np.asarray(ref), atol=2e-6, rtol=1e-5)
+    assert not np.isfinite(np.asarray(da._paged_scan(*args, **kw))).all()
+
+
+@pytest.mark.parametrize("step", [3, 4, 32])
+def test_paged_kernel_at_other_step_sizes(monkeypatch, step):
+    """Blocks a grid step: one that does not divide the table, one that
+    does, and more than the table holds — same result."""
+    from tony_tpu.ops import decode_attention as da
+
+    monkeypatch.setattr(da, "_STEP_BLOCKS", step)
+    args, kw, ref = _paged_case("dense", 1, "nan")
+    got = _paged_kernel(args, kw)
+    np.testing.assert_allclose(got, np.asarray(ref), atol=2e-6, rtol=1e-5)
+
+
+@pytest.mark.parametrize("Hkv,blk,hd,itemsize,pools,blocks", [
+    pytest.param(4, 64, 128, 2, 2, 8, id="yi-1.5-6b"),          # 64 KB tiles
+    pytest.param(1, 64, 640, 2, 1, 8, id="deepseek-v3-latent"),
+    pytest.param(32, 64, 128, 2, 2, 4, id="llama2_7b"),         # MHA: 512 KB
+    pytest.param(40, 64, 128, 2, 2, 3, id="llama2_13b"),
+    pytest.param(32, 64, 128, 4, 2, 2, id="llama2_7b-float32"),
+    pytest.param(40, 128, 128, 4, 2, 0, id="llama2_13b-float32-block128"),
+])
+def test_paged_kernel_sizes_its_step_from_the_tile_bytes(
+        Hkv, blk, hd, itemsize, pools, blocks):
+    """Blocks a grid step: 8 where the tiles are small, fewer so that the
+    step's double-buffered tiles stay inside ``_STEP_BYTES`` where a pool
+    has many kv heads, float32 rows or long blocks, and none (the scan)
+    where one block overruns it (the compiler's own verdict on these
+    shapes is in tests/test_tpu_compile.py)."""
+    from tony_tpu.ops import decode_attention as da
+
+    dtype = {2: jnp.bfloat16, 4: jnp.float32}[itemsize]
+    pool = jax.ShapeDtypeStruct((9, Hkv, blk, hd), dtype)
+    tables = jax.ShapeDtypeStruct((2, 64), jnp.int32)
+    n = da._step_blocks(pool, pool if pools == 2 else None, tables)
+    assert n == blocks
+    tile = Hkv * blk * hd * itemsize * pools
+    assert 2 * n * tile <= da._STEP_BYTES < 2 * (n + 1) * tile or n == 8
+
+
+def test_paged_form_keeps_the_scan_where_no_block_fits(monkeypatch, paged_kernel):
+    """A pool whose single block overruns a step's VMEM budget is served by
+    the scan, on the platform that would otherwise run the kernel: told
+    apart by the poisoned tails, which only the scan reads."""
+    from tony_tpu.ops import decode_attention as da
+
+    (q, k, v, ln, tb), kw, ref = _paged_case("dense", 1, "nan")
+    paged_kernel(True)
+    tile = 2 * k[0].size * k.dtype.itemsize
+    monkeypatch.setattr(da, "_STEP_BYTES", 2 * tile)      # one block a step
+    out = decode_attention(q[:, 0], k, v, ln, tables=tb, scale=0.25)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref[:, 0]),
+                               atol=2e-6, rtol=1e-5)
+    monkeypatch.setattr(da, "_STEP_BYTES", 2 * tile - 1)  # not even one
+    out = decode_attention(q[:, 0], k, v, ln, tables=tb, scale=0.25)
+    assert not np.isfinite(np.asarray(out)).all()
+
+
+def test_public_paged_forms_choose_the_kernel_by_platform(paged_kernel):
+    """``decode_attention(tables=...)`` and ``latent_decode_attention`` run
+    the scan here (no TPU) whatever ``impl`` says, and the kernel once the
+    platform check is steered: told apart by the poisoned tails."""
+    from tony_tpu.ops.decode_attention import latent_decode_attention
+
+    (q, k, v, ln, tb), kw, ref = _paged_case("dense", 1, "nan")
+    (ql, pool, _, _, _), kwl, refl = _paged_case("latent", 1, "nan")
+    for impl in ("scan", "pallas"):
+        out = decode_attention(q[:, 0], k, v, ln, tables=tb, impl=impl, scale=0.25)
+        assert not np.isfinite(np.asarray(out)).all()
+    paged_kernel(True)
+    out = decode_attention(q[:, 0], k, v, ln, tables=tb, scale=0.25)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref[:, 0]),
+                               atol=2e-6, rtol=1e-5)
+    out = latent_decode_attention(ql[:, 0, :, :28], pool, ln, tb, v_width=24,
+                                  scale=0.25)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(refl[:, 0]),
+                               atol=2e-6, rtol=1e-5)
+
+
+def test_attn_block_counters_count_what_the_table_says(setup):
+    """Two rows of known lengths: each decode step adds the blocks its live
+    rows reach and slots x the step's table width; ``reset_metrics`` zeroes
+    both."""
+    cfg, params = setup
+    eng = Engine(params, cfg, ServeConfig(
+        slots=2, max_len=32, kv_block=8, shrink=False,
+    ))
+    eng.run([Request(prompt=p, max_new_tokens=3)
+             for p in _prompts(cfg, [3, 10], seed=2)])
+    m = eng.metrics
+    # prefill samples the first token; two decode steps follow, attending
+    # 4 and 5 positions of the short row (1 block) and 11 and 12 of the
+    # long one (2 blocks); shrink=False keeps the table at max_len / block
+    assert m.decode_steps == 2
+    assert m.attn_blocks_live == 2 * (1 + 2)
+    assert m.attn_blocks_table == 2 * 2 * eng.attended_positions // 8
+    eng.reset_metrics()
+    assert eng.metrics.attn_blocks_live == eng.metrics.attn_blocks_table == 0
 
 
 # --- the carried pool: every layer writes its own blocks, and only those ------

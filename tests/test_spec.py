@@ -269,7 +269,7 @@ def test_multi_query_decode_attention_matches_reference(impl):
 
 
 @pytest.mark.parametrize("impl", ["scan", "pallas"])
-def test_multi_query_paged_shared_tables_and_scratch_tails(impl):
+def test_multi_query_paged_shared_tables_and_scratch_tails(impl, paged_kernel):
     """The paged G-query form through block tables where (a) two rows
     SHARE physical blocks (prefix sharing live during a spec step) and
     (b) table tails beyond each row's length point at the scratch block
@@ -287,8 +287,9 @@ def test_multi_query_paged_shared_tables_and_scratch_tails(impl):
         [5, SCRATCH_BLOCK, SCRATCH_BLOCK, SCRATCH_BLOCK],
     ], jnp.int32)
     lengths = jnp.asarray([18, 30, 7], jnp.int32)
+    paged_kernel(impl == "pallas")   # the paged form takes no ``impl``
     got = decode_attention(
-        q, k_pool, v_pool, lengths, tables=tables, impl=impl, block=block,
+        q, k_pool, v_pool, lengths, tables=tables, block=block,
     )
     # reference: gather each row's contiguous K/V through its table
     kc = k_pool[tables].transpose(0, 2, 1, 3, 4).reshape(B, Hkv, M * block, hd)
@@ -453,11 +454,12 @@ def test_engine_spec_eos_inside_accepted_draft(setup):
     assert res[1].tokens == want
 
 
-def test_engine_spec_decode_impls_agree(setup):
+def test_engine_spec_decode_impls_agree(setup, paged_kernel):
     cfg, params = setup
     prompts = _prompts(cfg, [3, 10], seed=6)
     outs = {}
     for impl in ("scan", "pallas"):
+        paged_kernel(impl == "pallas")
         eng = Engine(params, cfg, ServeConfig(
             slots=2, max_len=32, kv_block=8, decode_impl=impl,
             spec=True, spec_max_draft=3,

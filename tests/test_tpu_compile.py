@@ -122,11 +122,13 @@ def test_decode_attention(one_chip, form, queries):
     m = _T // _BLK
     pool = 1 + _B * m
     tables = ((_B, m), I32)
+    # the paged form takes no ``impl``: it runs its kernel wherever
+    # ``_use_interpret`` is False, which the fixture above has arranged
     if form == "paged":
         kv = ((pool, _HKV, _BLK, _HD), BF16)
 
         def fn(q, k, v, ln, tb):
-            return decode_attention(q, k, v, ln, tables=tb, impl="pallas")
+            return decode_attention(q, k, v, ln, tables=tb)
 
         _compile(fn, one_chip, q, kv, kv, lengths, tables)
         return
@@ -135,10 +137,73 @@ def test_decode_attention(one_chip, form, queries):
 
     def fn(q, k, v, ln, tb, ks, vs):
         return decode_attention(
-            q, k, v, ln, tables=tb, impl="pallas", k_scale=ks, v_scale=vs
+            q, k, v, ln, tables=tb, k_scale=ks, v_scale=vs
         )
 
     _compile(fn, one_chip, q, kv, kv, lengths, tables, sc, sc)
+
+
+@pytest.mark.parametrize("cell", ["yi-chat-closed16", "dsv3-reason-closed48"])
+def test_paged_decode_attention_at_the_serving_cells_shapes(one_chip, cell):
+    """The paged kernel at the shapes the two serving cells run it with
+    (PERF.md §4): the dense pool of Yi-1.5-6B (16 slots, 32/4 heads x 128,
+    a table of 32 blocks of 64) and the latent pool of DeepSeek-V3 (48
+    slots, 128 heads against one 640-lane row block, values its first 512
+    columns, a table of 64), each pool at all its layers' blocks."""
+    from tony_tpu.ops.decode_attention import (
+        decode_attention, latent_decode_attention,
+    )
+
+    if cell == "yi-chat-closed16":
+        kv = ((32 * 513, 4, 64, 128), BF16)
+
+        def fn(q, k, v, ln, tb):
+            return decode_attention(q, k, v, ln, tables=tb)
+
+        _compile(fn, one_chip, ((16, 32, 128), BF16), kv, kv,
+                 ((16,), I32), ((16, 32), I32))
+        return
+
+    def fn(q, pool, ln, tb):
+        return latent_decode_attention(q, pool, ln, tb, v_width=512,
+                                       scale=0.1)
+
+    _compile(fn, one_chip, ((48, 128, 576), BF16),
+             ((6 * 3136, 1, 64, 640), BF16), ((48,), I32), ((48, 64), I32))
+
+
+@pytest.mark.parametrize("kv_heads,block,dtype,blocks", [
+    pytest.param(32, 64, BF16, 4, id="llama2_7b"),
+    pytest.param(40, 64, BF16, 3, id="llama2_13b"),
+    pytest.param(32, 64, F32, 2, id="llama2_7b-float32"),
+    pytest.param(32, 64, I8, 8, id="llama2_7b-int8"),
+    pytest.param(40, 128, F32, 0, id="llama2_13b-float32-block128"),
+])
+def test_paged_decode_attention_at_one_query_row_a_kv_head(
+        one_chip, kv_heads, block, dtype, blocks):
+    """MHA pools (``LlamaConfig``'s default: as many kv heads as heads, so
+    one query row a kv head and tiles of 512 KB and more): the kernel takes
+    as many blocks a step as fit the 16 MiB of VMEM a v5e scopes to it —
+    at 8 a step these shapes run out of it — and where not even one block
+    fits the op hands the shape to the scan, which compiles as it always
+    did."""
+    from tony_tpu.ops import decode_attention as da
+
+    B, M, hd = 8, 4096 // block, 128
+    pool = ((1 + B * M, kv_heads, block, hd), dtype)
+    shapes = [((B, kv_heads, hd), BF16 if dtype == I8 else dtype), pool, pool,
+              ((B,), I32), ((B, M), I32)]
+    if dtype == I8:
+        shapes += [((1 + B * M, kv_heads), F32)] * 2
+
+    def fn(q, k, v, ln, tb, ks=None, vs=None):
+        return da.decode_attention(q, k, v, ln, tables=tb, k_scale=ks, v_scale=vs)
+
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    assert da._step_blocks(args[1], args[2], args[4]) == blocks
+    lowered = jax.jit(fn).lower(*args)
+    assert ("tpu_custom_call" in lowered.as_text()) == bool(blocks)
+    lowered.compile()
 
 
 # --- fused cross-entropy, pallas impl -----------------------------------------
@@ -282,8 +347,10 @@ def test_latent_decode_step_at_published_widths_keeps_the_pool_in_place(one_chip
     pool = jax.ShapeDtypeStruct((3, P, 1, 64, cfg.cache_width), BF16, sharding=one_chip)
     cache = PagedKVCache(pool, None, jax.ShapeDtypeStruct((S,), I32, sharding=one_chip))
     table = jax.ShapeDtypeStruct((S, 64), I32, sharding=one_chip)
-    compiled = _decode_fn(cfg, "scan", 64, 64).lower(
-        params, cache, table, sds(_state_avals(S))).compile()
+    lowered = _decode_fn(cfg, "scan", 64, 64).lower(
+        params, cache, table, sds(_state_avals(S)))
+    assert "paged_decode_attention" in lowered.as_text()   # the kernel, in the layer scans
+    compiled = lowered.compile()
     mem = compiled.memory_analysis()
     pool_bytes = 3 * P * 64 * cfg.cache_width * 2
     relaid = [l for l in compiled.as_text().splitlines()

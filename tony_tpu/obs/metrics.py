@@ -162,6 +162,11 @@ class DecodeMetrics:
     moe_tokens: int = 0            # tokens routed (prompt + decode)
     moe_experts_hit: Any = None    # [expert layers] int64, summed over steps
     moe_steps: int = 0             # decode steps counted in moe_experts_hit
+    # paged decode attention, summed over decode steps: the table blocks the
+    # live rows reach (what the kernel has to read) and the blocks the step's
+    # table names for all slots (what a gather of every entry reads)
+    attn_blocks_live: int = 0
+    attn_blocks_table: int = 0
 
     def record_prompt(self, plen: int, hit_tokens: int = 0) -> None:
         self.prompt_tokens += plen
@@ -195,13 +200,17 @@ class DecodeMetrics:
         self.generated_tokens += 1  # prefill samples the first token
 
     def record_decode(self, dt_s: float, new_tokens: int, live: int,
-                      slots: int) -> None:
+                      slots: int, attn_blocks: tuple[int, int] = (0, 0)) -> None:
+        """``attn_blocks``: (blocks the live rows reach, slots x table width)
+        of this step, from the host's own bookkeeping."""
         self.decode_s += dt_s
         self.decode_steps += 1
         self.generated_tokens += new_tokens
         self.decode_tokens += new_tokens
         self.decode_live_sum += live
         self.occupancy_sum += live / max(slots, 1)
+        self.attn_blocks_live += attn_blocks[0]
+        self.attn_blocks_table += attn_blocks[1]
 
     @property
     def elapsed_s(self) -> float:

@@ -11,32 +11,48 @@ bandwidth (one query row per request). Here queries fold to
 cache, and per-row ``lengths`` bound the attended positions so work stops at
 the written prefix instead of ``max_len``.
 
-Two interchangeable implementations (the ``fused_ce``/``grouped_mm``
-pattern), dispatched on ``impl``:
+**Contiguous form**: two interchangeable implementations (the
+``fused_ce``/``grouped_mm`` pattern), dispatched on ``impl``:
 
 - ``'scan'`` — ``lax.scan`` over KV blocks with an online softmax (the
   flash recurrence). Pure XLA: runs anywhere, is the default, and keeps the
   score transient at ``[B, Hkv, rep, block]`` instead of ``[B, H, T]``.
 - ``'pallas'`` — a TPU kernel over a ``(B * n_kv_heads, T/block)`` grid.
   Per-row lengths ride as a scalar-prefetch argument; KV tiles entirely
-  beyond a row's length skip their FLOPs via ``pl.when`` (the DMA win comes
-  from the caller sizing the cache to the active block count — see
-  serve/cache.py). Interpreter mode on CPU.
+  beyond a row's length skip their FLOPs via ``pl.when``. Interpreter mode
+  on CPU.
 
 Cache layout is head-major ``[B, n_kv_heads, T, head_dim]`` (the serve
 engine's block cache flattens to exactly this), so the kernel fold is a
 reshape, not a transpose of the whole cache every step.
 
-**Paged form** (``tables`` given): K/V are physical-block *pools*
-``[P, n_kv_heads, block, head_dim]`` and ``tables [B, M]`` maps row ``b``'s
-logical block ``j`` to a physical block id — the indirection that lets the
-prefix store (serve/prefix.py) share one physical block across many rows.
-The scan impl gathers each step's blocks through the table; the pallas impl
-rides the table as a second scalar-prefetch argument whose values steer the
-K/V BlockSpec index map (the grouped_mm tile->expert pattern), so the DMA
-fetches exactly the mapped block. Rows beyond their length still skip their
-FLOPs; table entries beyond a row's allocation must point at a valid id
-(the engine uses the scratch block 0).
+**Paged form** (``tables`` given; what the serve engine runs): K/V are
+physical-block *pools* ``[P, n_kv_heads, block, head_dim]`` and ``tables
+[B, M]`` maps row ``b``'s logical block ``j`` to a physical block id — the
+indirection that lets the prefix store (serve/prefix.py) share one physical
+block across many rows. The latent form (:func:`latent_decode_attention`)
+is the same with one shared row per position whose first columns are the
+values. The op takes no ``impl`` here: it chooses from what it can observe
+(:func:`_run_kernel`).
+
+- On a TPU, ONE Pallas kernel ``paged_decode_attention`` for the dense, the
+  quantized and the latent pool (:func:`_paged_attend`): a grid step is one
+  row, all of its kv heads, ``_STEP_BLOCKS`` table blocks; lengths and
+  table ride as scalar prefetch and steer the pool's index maps, clamped to
+  the row's last live block — so a row's DMA and FLOPs stop at its own
+  ``ceil(lengths[b] / block)`` blocks whatever the table's width, and no
+  shape depends on the lengths (one compiled decode step serves every
+  step, ``shrink=False`` included; PERF.md §6, PR 28). A step takes fewer
+  blocks where the tiles are large (MHA pools, float32 rows, long blocks),
+  so that they fit the kernel's VMEM (:func:`_step_blocks`).
+- Elsewhere, and for a pool one block of which does not fit that VMEM,
+  :func:`_paged_scan`: a ``lax.scan`` over all ``M`` table
+  entries that gathers block ``j`` of every row and masks afterwards. Pure
+  XLA, the reference form of the tests; it reads the whole table's worth
+  of pool each call.
+
+Table entries beyond a row's allocation must point at a valid id (the
+engine uses the scratch block 0); the kernel never fetches them.
 
 No backward: decode is inference-only. ``T`` must be a multiple of
 ``block`` (the block cache guarantees it); ``lengths`` must be >= 1 — the
@@ -219,14 +235,15 @@ def latent_decode_attention(q: jax.Array, pool: jax.Array, lengths: jax.Array,
     already folded into it) against the latent pool ``[P, 1, block, kv_rank
     + rope]``; the values are the first ``v_width`` (= kv_rank) columns of
     the very rows the scores read. Returns ``[B, H, v_width]`` — the caller
-    applies the value half of the up-projection. Scan form only. A pool
-    whose rows are wider than the query (zero lanes beyond the latent, the
-    cache's padding) is contracted whole against a zero-padded query."""
+    applies the value half of the up-projection. Kernel or scan as the
+    paged form chooses (:func:`_run_kernel`). A pool whose rows are wider
+    than the query (zero lanes beyond the latent, the cache's padding) is
+    contracted whole against a zero-padded query."""
     if pool.shape[1] != 1 or pool.shape[3] < q.shape[-1]:
         raise ValueError(f"latent decode shapes q={q.shape} pool={pool.shape}")
     q = jnp.pad(q, ((0, 0), (0, 0), (0, pool.shape[3] - q.shape[-1])))
-    out = _paged_scan(q[:, None], pool, None, lengths, tables, scale=scale,
-                      v_width=v_width)
+    out = _paged(q[:, None], pool, None, lengths, tables, scale=scale,
+                 v_width=v_width)
     return out[:, 0]
 
 
@@ -323,11 +340,52 @@ def _decode_pallas(q, k, v, lengths, *, scale, block):
     )
 
 
-def _paged_body(j, nb, row_len, q_ref, read_kv, o_ref, acc, m_sc, l_sc,
-                *, scale, block, rep, queries):
-    """Shared paged tile body: ``read_kv`` hands back this tile's (k, v) in
-    the query dtype — the plain kernel reads the refs directly, the quant
-    kernel dequantizes through its scale refs first."""
+# table blocks one grid step of the paged kernel fetches and attends: a step
+# then moves enough bytes (Hkv * block * head_dim each) to be worth its fixed
+# cost, and a row's dead steps are table_width / _STEP_BLOCKS at most
+_STEP_BLOCKS = 8
+# what a step's pool tiles may take of VMEM, the pipeline's second buffer
+# counted: half of the 16 MiB a v5e scopes to one kernel, the other half left
+# to q, out, the scratch and the kernel's own values
+_STEP_BYTES = 8 * 2**20
+
+
+def _step_blocks(k, v, tables) -> int:
+    """Table blocks a grid step of :func:`_paged_attend` takes at these
+    shapes: ``_STEP_BLOCKS``, or as many fewer as fit ``_STEP_BYTES`` where
+    the tiles are large (many kv heads, float32 pools, long blocks). 0 where
+    not even one block fits: the form :func:`_paged_scan` keeps."""
+    tile = math.prod(k.shape[1:]) * k.dtype.itemsize * (1 if v is None else 2)
+    return min(_STEP_BLOCKS, tables.shape[1], _STEP_BYTES // (2 * tile))
+
+
+def _paged_kernel(len_ref, ids_ref, q_ref, *refs, scale, block, n, kv_heads,
+                  rep, queries, v_width, shared_kv, quant):
+    """One grid step = one row, all of its kv heads, ``n`` table blocks.
+
+    ``refs``: the ``n`` key tiles ``[1, Hkv, block, hd]`` the index maps
+    steered through ``ids``, the ``n`` value tiles (none when
+    ``shared_kv``: the values are the keys' first ``v_width`` columns), the
+    two scale tables in SMEM when ``quant`` (laid out as ``ids`` is: entry
+    ``[(b, head), j * n + i]`` is the scale of tile ``i`` of step ``j``),
+    the output and the scratch.
+
+    A row's chunks of ``n`` blocks are walked from the table's END: the
+    steps past the row's last live chunk come first, name that chunk's
+    tiles (so the pipeline fetches them while the row before still
+    computes, and fetches nothing more until the row's own first live
+    step has run) and compute nothing. Tiles of a live step that lie past
+    the row's last block are copies of that block, every position masked."""
+    k_refs, refs = refs[:n], refs[n:]
+    if not shared_kv:
+        v_refs, refs = refs[:n], refs[n:]
+    ksc_ref = vsc_ref = None
+    if quant:
+        (ksc_ref, vsc_ref), refs = refs[:2], refs[2:]
+    o_ref, acc, m_sc, l_sc = refs
+    b, j = pl.program_id(0), pl.program_id(1)
+    first = (pl.num_programs(1) - 1 - j) * n        # this step's first table block
+    row_len = len_ref[b]
 
     @pl.when(j == 0)
     def _init():
@@ -335,143 +393,155 @@ def _paged_body(j, nb, row_len, q_ref, read_kv, o_ref, acc, m_sc, l_sc,
         m_sc[:] = jnp.full_like(m_sc, _NEG)
         l_sc[:] = jnp.zeros_like(l_sc)
 
-    @pl.when(j * block < row_len)
-    def _block():
-        q = q_ref[0]
-        k, v = read_kv()
-        s = lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale                                      # [queries*rep, block]
-        pos = j * block + lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        # same affine speculative mask as _decode_kernel: row r is query
-        # g = r // rep, attending < row_len - (G-1) + g
-        gq = lax.broadcasted_iota(jnp.int32, s.shape, 0) // rep
-        valid = pos < row_len - (queries - 1) + gq
-        s = jnp.where(valid, s, _NEG)
-        m_prev = m_sc[:, 0]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        p = jnp.where(valid, jnp.exp(s - m_new[:, None]), 0.0)
-        corr = jnp.exp(m_prev - m_new)
-        l_sc[:, 0] = l_sc[:, 0] * corr + jnp.sum(p, axis=1)
-        acc[:] = acc[:] * corr[:, None] + lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_sc[:, 0] = m_new
+    def tiles(t_refs, sc_ref, x):
+        """Head ``x``'s ``[n * block, hd]`` of this step, dequantized per
+        block through its scale (one SMEM scalar) when the pool is
+        quantized — the wide cache never exists outside registers."""
+        if sc_ref is None:
+            return jnp.concatenate([r[0, x] for r in t_refs], axis=0)
+        return jnp.concatenate([
+            (r[0, x].astype(jnp.float32) * sc_ref[b * kv_heads + x, j * n + i]
+             ).astype(q_ref.dtype)
+            for i, r in enumerate(t_refs)
+        ], axis=0)
 
-    @pl.when(j == nb - 1)
+    @pl.when(first * block < row_len)
+    def _attend():
+        for x in range(kv_heads):
+            q = q_ref[0, x]                                 # [G * rep, hd]
+            k = tiles(k_refs, ksc_ref, x)
+            v = k[:, :v_width] if shared_kv else tiles(v_refs, vsc_ref, x)
+            s = lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * scale                                       # [G * rep, n * block]
+            pos = first * block + lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            # folded row r is query g = r // rep: speculative query g sees
+            # positions < row_len - (G-1) + g (row_len counts the cache AFTER
+            # all G writes; G=1 is the plain < row_len rule)
+            gq = lax.broadcasted_iota(jnp.int32, s.shape, 0) // rep
+            valid = pos < row_len - (queries - 1) + gq
+            s = jnp.where(valid, s, _NEG)
+            m_prev = m_sc[x]                                # [G * rep, 1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+            corr = jnp.exp(m_prev - m_new)
+            l_sc[x] = l_sc[x] * corr + jnp.sum(p, axis=1, keepdims=True)
+            acc[x] = acc[x] * corr + lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            m_sc[x] = m_new
+
+    @pl.when(j == pl.num_programs(1) - 1)
     def _finalize():
-        l = jnp.maximum(l_sc[:, 0], 1e-30)
-        o_ref[0] = (acc[:] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0] = (acc[:] / jnp.maximum(l_sc[:], 1e-30)).astype(o_ref.dtype)
 
 
-def _paged_kernel(len_ref, tbl_ref, q_ref, k_ref, v_ref, o_ref, acc, m_sc,
-                  l_sc, *, scale, block, kv_heads, rep, queries):
-    i, j = pl.program_id(0), pl.program_id(1)
-    nb = pl.num_programs(1)
-    row_len = len_ref[i // kv_heads]
-    _paged_body(
-        j, nb, row_len, q_ref, lambda: (k_ref[0, 0], v_ref[0, 0]),
-        o_ref, acc, m_sc, l_sc,
-        scale=scale, block=block, rep=rep, queries=queries,
-    )
+def _paged_attend(q, k, v, lengths, tables, *, n, scale, k_scale=None,
+                  v_scale=None, v_width=0):
+    """The paged form as one kernel: grid ``(B, ceil(M / n))``; lengths
+    and ``ids`` as scalar prefetch. The pool is handed over ``n`` times
+    (twice that with a value pool); operand ``i``'s index map names pool
+    block ``ids[b, j * n + i]``: table entry ``min(min(c, last // n) * n +
+    i, last)`` of row ``b`` for the step's chunk ``c``, ``last =
+    (lengths[b] - 1) // block`` the row's last live block — so a row
+    fetches its own live blocks (rounded up to ``n``) and nothing else,
+    whatever the table's width, and no shape depends on the lengths.
+    (``ids`` is worked out here, vectorised, because the pipeline evaluates
+    every operand's index map at every step, dead ones too: scalar work the
+    kernel's fixed cost is made of.) Same mathematics as
+    :func:`_paged_scan` (whose arguments these are): operands in the pool's
+    stated dtype, float32 scores and softmax, ``p`` cast before p.v.
 
-
-def _paged_quant_kernel(len_ref, tbl_ref, q_ref, k_ref, v_ref, ksc_ref,
-                        vsc_ref, o_ref, acc, m_sc, l_sc, *, scale, block,
-                        kv_heads, rep, queries):
-    """Quantized pools: the per-block-per-head scales arrive as whole
-    ``[B * Hkv, M]`` float32 tables in SMEM (gathered through the block
-    table by the caller — a (1, 1) VMEM tile per scale breaks the TPU's
-    (8, 128) tiling rule), so tile (i, j)'s scale is one scalar load and
-    the dequant happens in registers — the bf16 cache never exists in HBM."""
-    i, j = pl.program_id(0), pl.program_id(1)
-    nb = pl.num_programs(1)
-    row_len = len_ref[i // kv_heads]
-
-    def read_kv():
-        k = (k_ref[0, 0].astype(jnp.float32) * ksc_ref[i, j]).astype(
-            q_ref.dtype
-        )
-        v = (v_ref[0, 0].astype(jnp.float32) * vsc_ref[i, j]).astype(
-            q_ref.dtype
-        )
-        return k, v
-
-    _paged_body(
-        j, nb, row_len, q_ref, read_kv, o_ref, acc, m_sc, l_sc,
-        scale=scale, block=block, rep=rep, queries=queries,
-    )
-
-
-def _paged_pallas(q, k, v, lengths, tables, *, scale, k_scale=None,
-                  v_scale=None):
-    """Grid (B * Hkv, M): the table rides as scalar prefetch and its values
-    steer the K/V BlockSpec index map, so each tile's DMA fetches the
-    physical block the row's table names (no gather materialised). With
-    ``k_scale``/``v_scale`` ``[P, Hkv]`` the scales of the blocks the table
-    names are gathered here (``B * Hkv * M`` floats — the size of the
-    attention work, not of the pool) and ride whole in SMEM."""
+    Quantized pools: the scales of the blocks ``ids`` names are gathered
+    here (``B * Hkv * M`` floats — the size of the attention work, not of
+    the pool) and ride whole in SMEM (a (1, 1) VMEM tile per scale breaks
+    the TPU's (8, 128) tiling rule)."""
     B, G, H, hd = q.shape
     Hkv, blk = k.shape[1], k.shape[2]
     rep = H // Hkv
-    nb = tables.shape[1]
+    M = tables.shape[1]
     R = G * rep
+    steps = pl.cdiv(M, n)
+    shared_kv = v is None
+    vd = v_width if shared_kv else hd
     quant = k_scale is not None
     qf = q.reshape(B, G, Hkv, rep, hd).transpose(0, 2, 1, 3, 4).reshape(
-        B * Hkv, R, hd
+        B, Hkv, R, hd
     )
 
-    def kv_spec():
+    last = jnp.clip((lengths.astype(jnp.int32) - 1) // blk, 0, M - 1)[:, None, None]
+    chunk = jnp.minimum(                  # [B, steps, 1], from the table's end
+        (steps - 1 - jnp.arange(steps, dtype=jnp.int32))[:, None], last // n)
+    walk = jnp.minimum(chunk * n + jnp.arange(n, dtype=jnp.int32), last)
+    ids = jnp.take_along_axis(            # [b, j * n + i]: pool block to fetch
+        tables.astype(jnp.int32), walk.reshape(B, steps * n), axis=1)
+
+    def kv_spec(i):
         return pl.BlockSpec(
-            (1, 1, blk, hd),
-            lambda i, j, ln, tb, kv_heads=Hkv: (
-                tb[i // kv_heads, j], i % kv_heads, 0, 0
-            ),
+            (1, Hkv, blk, hd), lambda b, j, ln, ids: (ids[b, j * n + i], 0, 0, 0),
         )
 
-    def row_scales(pool):  # [P, Hkv] -> [B * Hkv, M], row i = (b, kv head)
-        return pool[tables].transpose(0, 2, 1).reshape(B * Hkv, nb).astype(
+    def row_scales(pool):  # [P, Hkv] -> [B * Hkv, steps * n], row = (b, kv head)
+        return pool[ids].transpose(0, 2, 1).reshape(B * Hkv, steps * n).astype(
             jnp.float32
         )
 
-    in_specs = [
-        pl.BlockSpec((1, R, hd), lambda i, j, ln, tb: (i, 0, 0)),
-        kv_spec(),
-        kv_spec(),
-    ]
-    operands = [qf, k, v]
-    kernel = _paged_kernel
+    kv_specs = [kv_spec(i) for i in range(n)]
+    in_specs = [pl.BlockSpec((1, Hkv, R, hd), lambda b, j, ln, ids: (b, 0, 0, 0))]
+    in_specs += kv_specs
+    operands = [qf] + [k] * n
+    if not shared_kv:
+        in_specs += kv_specs
+        operands += [v] * n
     if quant:
         in_specs += [pl.BlockSpec(memory_space=pltpu.SMEM)] * 2
         operands += [row_scales(k_scale), row_scales(v_scale)]
-        kernel = _paged_quant_kernel
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B * Hkv, nb),
+        grid=(B, steps),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, R, hd), lambda i, j, ln, tb: (i, 0, 0)),
+        out_specs=pl.BlockSpec((1, Hkv, R, vd), lambda b, j, ln, ids: (b, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((R, hd), jnp.float32),
-            pltpu.VMEM((R, 1), jnp.float32),
-            pltpu.VMEM((R, 1), jnp.float32),
+            pltpu.VMEM((Hkv, R, vd), jnp.float32),
+            pltpu.VMEM((Hkv, R, 1), jnp.float32),
+            pltpu.VMEM((Hkv, R, 1), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         functools.partial(
-            kernel, scale=scale, block=blk, kv_heads=Hkv,
-            rep=rep, queries=G,
+            _paged_kernel, scale=scale, block=blk, n=n, kv_heads=Hkv, rep=rep,
+            queries=G, v_width=vd, shared_kv=shared_kv, quant=quant,
         ),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B * Hkv, R, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, R, vd), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=_use_interpret(),
-    )(lengths.astype(jnp.int32), tables.astype(jnp.int32), *operands)
-    return out.reshape(B, Hkv, G, rep, hd).transpose(0, 2, 1, 3, 4).reshape(
-        B, G, H, hd
+        name="paged_decode_attention",
+    )(lengths.astype(jnp.int32), ids, *operands)
+    return out.reshape(B, Hkv, G, rep, vd).transpose(0, 2, 1, 3, 4).reshape(
+        B, G, H, vd
     )
+
+
+def _run_kernel() -> bool:
+    """Whether the paged form may run :func:`_paged_attend`: where the
+    platform is a TPU. Elsewhere :func:`_paged_scan` does the work; a test
+    that wants the kernel interpreted on the CPU steers this name, as
+    tests/test_tpu_compile.py steers ``_use_interpret``."""
+    return not _use_interpret()
+
+
+def _paged(q, k, v, lengths, tables, **kw):
+    """Kernel or scan, from the platform and the shapes: the scan off the
+    TPU, and for a pool one block of which overruns a step's VMEM."""
+    n = _step_blocks(k, v, tables) if _run_kernel() else 0
+    if n:
+        return _paged_attend(q, k, v, lengths, tables, n=n, **kw)
+    return _paged_scan(q, k, v, lengths, tables, **kw)
 
 
 # --- public entry -------------------------------------------------------------
@@ -502,20 +572,22 @@ def decode_attention(
     ``tables[b, j]`` — the serve engine's copy-on-write sharing substrate
     (serve/cache.py, serve/prefix.py). Entries beyond a row's length must
     still be valid pool ids (the engine points them at the scratch block).
+    ``impl`` is not read here: the kernel on a TPU, the scan elsewhere
+    (:func:`_run_kernel`).
 
     Speculative form: q ``[B, G, H, head_dim]`` verifies G query positions
     per row in one call (serve/spec.py) — ``lengths`` counts the cache
     AFTER all G writes, and query g of row b attends positions
     ``< lengths[b] - (G - 1) + g`` (for G=1 exactly the one-token rule).
     Returns [B, G, H, head_dim]. Works in both contiguous and paged form;
-    both impls fold the G positions into the existing tile rows, so the
-    per-step K/V traffic does not grow with G.
+    every implementation folds the G positions into the existing tile
+    rows, so the per-step K/V traffic does not grow with G.
 
     Quantized paged form (``k_scale``/``v_scale [P, Hkv]`` given, paged
     only): the pools hold int8 or fp8 payloads quantized per physical
-    block per kv-head (serve/cache.py); both impls dequantize each tile
-    inline — scan gathers the scale row next to the block gather, pallas
-    reads the table-gathered scales as scalars from SMEM.
+    block per kv-head (serve/cache.py); scan and kernel dequantize each
+    tile inline — the scan gathers the scale row next to the block gather,
+    the kernel reads the table-gathered scales as scalars from SMEM.
     """
     squeeze = q.ndim == 3
     if squeeze:
@@ -545,16 +617,10 @@ def decode_attention(
             raise ValueError(
                 f"n_heads {H} not a multiple of n_kv_heads {k.shape[1]}"
             )
-        if impl == "pallas":
-            out = _paged_pallas(
-                q, k, v, lengths, tables, scale=scale,
-                k_scale=k_scale, v_scale=v_scale,
-            )
-        else:
-            out = _paged_scan(
-                q, k, v, lengths, tables, scale=scale,
-                k_scale=k_scale, v_scale=v_scale,
-            )
+        out = _paged(
+            q, k, v, lengths, tables, scale=scale,
+            k_scale=k_scale, v_scale=v_scale,
+        )
         return out[:, 0] if squeeze else out
     if k.shape != v.shape or k.shape[0] != B or k.shape[3] != hd:
         raise ValueError(f"decode_attention shapes q={q.shape} k={k.shape} v={v.shape}")

@@ -98,8 +98,9 @@ class ServeConfig:
     # prefill pad lengths; () -> powers of two from 16 up to max_len.
     # Prefill compiles once per bucket (the compile-count bound).
     prefill_buckets: tuple[int, ...] = ()
-    # decode attention kernel: 'scan' (pure XLA, default) | 'pallas'
-    # (TPU kernel, interpreted on CPU) — ops/decode_attention.py
+    # form of the int8 weight matmul (quant_weights): 'scan' (pure XLA,
+    # default) | 'pallas' (fused kernel) — ops/quant_mm.py. The paged decode
+    # attention does not read it: its kernel on a TPU, the scan elsewhere
     decode_impl: str = "scan"
     # static top-k slice width for sampling: per-request top_k clamps to
     # this, and top-p-only requests use it as the bounded nucleus candidate
@@ -1543,6 +1544,7 @@ class Engine:
             drafts_np, dlens = self._propose_step_drafts(live_before)
             spec_step = any(dlens)
             need = 1
+            reached = 0     # table blocks the live rows attend this step
             for s in live_before:
                 last = self._slot_len[s] + (dlens[s] if spec_step else 0)
                 while self._slot_blocks[s] * B <= last:
@@ -1550,6 +1552,7 @@ class Engine:
                     self._slot_blocks[s] += 1
                     self._table_dirty = True
                 need = max(need, last // B + 1)
+                reached += last // B + 1
             if self.cache.quantized:
                 self._flush_fresh_scales()
             self._set_attended(need)
@@ -1606,7 +1609,8 @@ class Engine:
             else:
                 new_total = len(live_before)
             self.metrics.record_decode(
-                dt, new_total, len(live_before), self.serve.slots
+                dt, new_total, len(live_before), self.serve.slots,
+                attn_blocks=(reached, self.serve.slots * self._attended),
             )
             hbm.sample()  # stride-counted device-memory reading (no sync)
             if hmon:
@@ -2093,7 +2097,7 @@ def _decode_step(params, cache: PagedKVCache, table, state: _SlotState, *,
         k_pool, v_pool, k_sc, v_sc = pools
         attn = decode_attention(
             q, k_pool, v_pool, pos + 1, tables=table + base,
-            impl=decode_impl, block=kv_block, k_scale=k_sc, v_scale=v_sc,
+            block=kv_block, k_scale=k_sc, v_scale=v_sc,
         )
         x = x + mm(attn.reshape(S, H * hd), lp, "wo")
         h2 = rms_norm(x, lp["ffn_norm"], cfg.norm_eps)
@@ -2191,7 +2195,7 @@ def _spec_decode_step(params, cache: PagedKVCache, table, state: _SlotState,
         # < pos0[s] + g + 1 (lengths arg = pos0 + G, kernel offsets per g)
         attn = decode_attention(
             q, k_pool, v_pool, pos0 + G, tables=table + base,
-            impl=decode_impl, block=kv_block, k_scale=k_sc, v_scale=v_sc,
+            block=kv_block, k_scale=k_sc, v_scale=v_sc,
         )
         x = x + mm(attn.reshape(S, G, H * hd), lp, "wo")
         h2 = rms_norm(x, lp["ffn_norm"], cfg.norm_eps)
