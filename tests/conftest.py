@@ -53,7 +53,6 @@ def paged_kernel(monkeypatch):
 
     def drop_steps():
         engine._decode_fn.cache_clear()
-        engine._spec_decode_fn.cache_clear()
         engine._aot_decode_cache.clear()
 
     def steer(on: bool):

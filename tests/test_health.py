@@ -341,8 +341,11 @@ class TestServeRules:
         params = init_params(jax.random.key(0), cfg)
         eng = Engine(params, cfg, ServeConfig(slots=2, max_len=32, kv_block=8))
         assert eng._monitors is False
-        out = eng._decode_impl(params, eng.cache, eng._table_dev, eng.state)
-        assert out[3] == {}
+        from tony_tpu.serve.engine import _decode_fn
+
+        step = _decode_fn(cfg, "scan", 8, eng.serve.max_top_k, eng._monitors)
+        out = step(params, eng.cache, eng._table_dev, eng.state)
+        assert out[-1] == {}
 
 
 # --- fit() integration --------------------------------------------------------

@@ -18,7 +18,7 @@ from tony_tpu.models import latent_moe as lm
 from tony_tpu.models.llama import LlamaConfig, init_params as llama_init
 from tony_tpu.parallel.moe import GroupRouting, local_expert_ffn, route_group_limited
 from tony_tpu.serve import latent as steps
-from tony_tpu.serve.cache import PagedKVCache, block_bytes, create_cache, pool_layout
+from tony_tpu.serve.cache import PagedKVCache, block_bytes, create_cache
 from tony_tpu.serve.engine import Engine, Request, ServeConfig, _scatter_fn, _SlotState
 
 TOL = dict(rtol=2e-4, atol=2e-4)
@@ -307,7 +307,7 @@ def test_latent_cache_is_one_pool_of_576_value_rows_padded_to_the_lanes():
     576-wide row to 640 anyway): 640 x 2 x L bytes a token."""
     cfg = lm.LatentMoEConfig(n_layers=6, n_dense_layers=1)
     assert cfg.latent_dim == 576 and cfg.cache_width == 640
-    assert pool_layout(cfg) == (1, 640, 1) and block_bytes(cfg, 64) == 64 * 640 * 2 * 6
+    assert cfg.cache_layout == (1, 640, 1) and block_bytes(cfg, 64) == 64 * 640 * 2 * 6
     tiny = lm.LatentMoEConfig.tiny()
     assert tiny.latent_dim == 24 and tiny.cache_width == 128
     cache = create_cache(tiny, 4, 9, 8)
@@ -318,7 +318,7 @@ def test_latent_cache_is_one_pool_of_576_value_rows_padded_to_the_lanes():
 
 def test_dense_cache_layout_is_unchanged():
     cfg = LlamaConfig.tiny()
-    assert pool_layout(cfg) == (2, 16, 2)
+    assert cfg.cache_layout == (2, 16, 2)
     cache = create_cache(cfg, 4, 9, 8)
     assert cache.k.shape == cache.v.shape == (2, 9, 2, 8, 16)
     assert block_bytes(cfg, 8) == 2 * 2 * 2 * 8 * 16 * 4
@@ -402,15 +402,27 @@ def test_counters_count_the_local_share_only(model):
     assert 0 < m.moe_routes.sum() < m.moe_tokens * 4 * 2
 
 
-@pytest.mark.parametrize("knob,serve", [
+REFUSALS = [
     ("quant_kv", {"quant_kv": "int8"}),
     ("quant_weights", {"quant_weights": True}),
     ("spec", {"spec": True}),
     ("decode_impl", {"decode_impl": "pallas"}),
-])
+    ("block_handoff", {}),      # no ServeConfig field: refused where it is called
+]
+
+
+@pytest.mark.parametrize("knob,serve", REFUSALS)
 def test_engine_refuses_what_the_latent_family_lacks_by_name(model, knob, serve):
     with pytest.raises(NotImplementedError, match=knob):
-        _engine(model, **serve)
+        eng = _engine(model, **serve)
+        eng.export_prefix_blocks(list(range(16)))
+
+
+def test_every_refused_knob_of_the_latent_family_has_a_case():
+    """A knob added to the table without a case above fails here."""
+    from tony_tpu.serve import latent
+
+    assert {knob for knob, _ in REFUSALS} == set(latent.REFUSED_KNOBS)
 
 
 @pytest.mark.parametrize("call", ["export", "adopt"])

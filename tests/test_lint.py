@@ -93,13 +93,65 @@ class TestGL001:
         """}, select="GL001")
         assert fs == []
 
+    def test_follows_a_module_chosen_by_a_function_that_returns_modules(self, tmp_path):
+        """``m = pick(cfg)`` where every ``return`` of ``pick`` names an
+        imported module, then ``m.step(x)`` under jit: the call reaches
+        ``step`` of EACH module ``pick`` can return (how serve/engine.py
+        chooses a model family) — a sync in one of them fires, and a
+        module ``pick`` cannot return stays outside the gate."""
+        fs = lint_src(tmp_path, {
+            "fam_a.py": """
+                def step(x):
+                    return x + 1
+            """,
+            "fam_b.py": """
+                def step(x):
+                    return x.sum().item()
+            """,
+            "fam_c.py": """
+                def step(x):
+                    return x.sum().item()   # never chosen: not traced
+            """,
+            "eng.py": """
+                import jax
+                import fam_a
+                import fam_b
+                import fam_c
+
+                def pick(cfg):
+                    if cfg:
+                        return fam_a
+                    return fam_b
+
+                def build(cfg):
+                    steps = pick(cfg)
+
+                    def program(x):
+                        return steps.step(x)
+
+                    return jax.jit(program)
+            """,
+        }, select="GL001")
+        assert [os.path.basename(f.path) for f in fs] == ["fam_b.py"], fs
+
     def test_real_engine_decode_path_is_traced(self):
-        """The live tree's six jitted hot paths are reachable: the decode
-        step's transitive callees (sampling, kernels) are in the traced
+        """The live tree's jitted hot paths are reachable: both model
+        families' three serving steps (chosen through
+        ``serve.engine.steps_for`` — the module-returning idiom
+        analysis/callgraph.py follows) and the decode step's transitive
+        callees (the layer body, sampling, kernels) are in the traced
         closure — the gate actually covers them."""
         project = load_project([os.path.join(REPO, "tony_tpu")])
         for probe in (
-            "tony_tpu.serve.engine:_decode_step",
+            "tony_tpu.serve.dense:prefill_step",
+            "tony_tpu.serve.dense:tail_prefill_step",
+            "tony_tpu.serve.dense:decode_step",
+            "tony_tpu.serve.latent:prefill_step",
+            "tony_tpu.serve.latent:tail_prefill_step",
+            "tony_tpu.serve.latent:decode_step",
+            "tony_tpu.models.generate:layer",
+            "tony_tpu.models.latent_moe:layer",
+            "tony_tpu.serve.spec:verify_and_accept",
             "tony_tpu.models.generate:sample_tokens",
             "tony_tpu.ops.decode_attention:decode_attention",
             "tony_tpu.models.llama:loss_from_pairs",

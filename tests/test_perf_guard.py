@@ -15,6 +15,7 @@ temp-buffer assignment, measured without running anything.
 """
 
 import dataclasses
+from functools import partial
 import math
 
 import jax
@@ -137,7 +138,7 @@ def test_decode_step_reads_kv_proportional_to_active_blocks():
     ~5x the one-block step; the guard asserts 2.5x headroom on both
     sides."""
     from tony_tpu.models.llama import LlamaConfig, init_params
-    from tony_tpu.serve import Engine, ServeConfig
+    from tony_tpu.serve import Engine, ServeConfig, dense
     from tony_tpu.serve.cache import create_cache
 
     slots, block, max_len = 4, 16, 512
@@ -153,7 +154,11 @@ def test_decode_step_reads_kv_proportional_to_active_blocks():
         # footprint a trace with n_blocks-long rows actually holds
         cache = create_cache(cfg, slots, 1 + slots * n_blocks, block)
         table = jnp.zeros((slots, n_blocks), jnp.int32)
-        compiled = jax.jit(eng._decode_impl).lower(
+        # the family's step itself, NOT the engine's donating builder: the
+        # bounds below were set on a program that also copies its pools out
+        step = partial(dense.decode_step, cfg=cfg, decode_impl="scan",
+                       kv_block=block, max_top_k=eng.serve.max_top_k)
+        compiled = jax.jit(step).lower(
             params, cache, table, eng.state
         ).compile()
         ca = compiled.cost_analysis()
@@ -190,7 +195,7 @@ def test_decode_step_keeps_the_pool_in_one_buffer(step):
     from tony_tpu.models.llama import init_params
     from tony_tpu.serve import Engine, ServeConfig
     from tony_tpu.serve.cache import create_cache
-    from tony_tpu.serve.engine import _spec_decode_fn
+    from tony_tpu.serve.engine import _decode_fn
 
     slots, block, width, draft_k = 4, 16, 8, 2
     cfg = dataclasses.replace(LlamaConfig.tiny(), max_seq_len=block * width)
@@ -203,19 +208,12 @@ def test_decode_step_keeps_the_pool_in_one_buffer(step):
 
     def plan(n_blocks):
         cache = create_cache(cfg, slots, n_blocks, block, quant_kv=quant_kv)
-        if step == "spec":
-            fn = _spec_decode_fn(
-                cfg, "scan", block, eng.serve.max_top_k, draft_k
-            )
-            lowered = fn.lower(
-                params, cache, table, eng.state,
-                jnp.zeros((slots, draft_k), jnp.int32),
-                jnp.zeros((slots,), jnp.int32),
-            )
-        else:
-            lowered = jax.jit(eng._decode_impl, donate_argnums=(1, 3)).lower(
-                params, cache, table, eng.state
-            )
+        k = draft_k if step == "spec" else 0
+        drafts = (jnp.zeros((slots, k), jnp.int32),
+                  jnp.zeros((slots,), jnp.int32)) if k else ()
+        lowered = _decode_fn(
+            cfg, "scan", block, eng.serve.max_top_k, False, quant_kv, draft_k=k,
+        ).lower(params, cache, table, eng.state, *drafts)
         ma = lowered.compile().memory_analysis()
         pools = cache.k.nbytes + cache.v.nbytes
         return ma.temp_size_in_bytes, ma.alias_size_in_bytes, pools

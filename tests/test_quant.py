@@ -276,7 +276,7 @@ class TestQuantCache:
             k_scale=cache.k_scale.at[:, 1].set(0.37),
             v_scale=cache.v_scale.at[:, 1].set(0.11),
         )
-        out = _copy_block_fn(True)(cache, 1, 2)
+        out = _copy_block_fn()(cache, 1, 2)
         np.testing.assert_array_equal(
             np.asarray(out.k[:, 2]), np.asarray(out.k[:, 1])
         )

@@ -406,9 +406,8 @@ def test_decode_step_writes_each_layers_own_blocks(impl, step):
     import dataclasses
 
     from tony_tpu.serve.cache import SCRATCH_BLOCK, PagedKVCache
-    from tony_tpu.serve.engine import (
-        _decode_step, _SlotState, _spec_decode_step,
-    )
+    from tony_tpu.serve.dense import decode_step
+    from tony_tpu.serve.engine import _SlotState
 
     cfg = dataclasses.replace(llama.LlamaConfig.tiny(), n_layers=3)
     params = llama.init_params(jax.random.key(1), cfg)
@@ -440,7 +439,7 @@ def test_decode_step_writes_each_layers_own_blocks(impl, step):
     if step == "spec":
         dlen = np.array([2, 0, 1, 2], np.int32)
         drafts = jnp.array([[1, 2], [3, 4], [5, 6], [7, 8]], jnp.int32)
-        new, *_ = _spec_decode_step(
+        new, *_ = decode_step(
             params, cache, jnp.asarray(table), state, drafts,
             jnp.asarray(dlen), draft_k=draft_k, **kw,
         )
@@ -450,7 +449,7 @@ def test_decode_step_writes_each_layers_own_blocks(impl, step):
                 for g in range(dlen[s] + 1)]
         scratch_offs = {0}
     else:
-        new, *_ = _decode_step(params, cache, jnp.asarray(table), state, **kw)
+        new, *_ = decode_step(params, cache, jnp.asarray(table), state, **kw)
         real = [(s, lengths[s]) for s in range(S) if live[s]]
         scratch_offs = {int(lengths[3]) % blk}
 
@@ -532,3 +531,70 @@ def test_engine_shutdown_summary(setup, tmp_path, monkeypatch, caplog):
     names = {m["name"] for m in snap["metrics"]}
     assert {"tony_ttft_seconds", "tony_decode_step_seconds",
             "tony_requests_finished_total"} <= names
+
+
+# --- the seam between the engine and a model family's steps -------------------
+
+
+@pytest.mark.parametrize("family", ["dense", "latent"])
+def test_a_model_family_keeps_the_steps_contract(family):
+    """What ``Engine`` asks of a family (docs/SERVE.md "Model families"):
+    ``steps_for`` maps the configuration's class to ONE module with the
+    four names and ``REFUSED_KNOBS``; the configuration says what a cache
+    row is (``cache_layout``); each of the three programs lowers through
+    the engine's builder for either family and its LAST result is a dict
+    (prefill: 5 results, the V rows None where the cache is one pool;
+    decode: 4); and the engine refuses every key of the table by name."""
+    from dataclasses import fields
+    from functools import partial
+
+    from tony_tpu.models.latent_moe import LatentMoEConfig
+    from tony_tpu.serve import dense, engine, latent
+    from tony_tpu.serve.cache import create_cache
+
+    cfg, steps = {
+        "dense": (llama.LlamaConfig.tiny(), dense),
+        "latent": (LatentMoEConfig.tiny(), latent),
+    }[family]
+    assert engine.steps_for(cfg) is steps
+    for name in ("prefill_step", "tail_prefill_step", "decode_step", "init_params"):
+        assert callable(getattr(steps, name)), name
+    knobs = {f.name for f in fields(ServeConfig)} | {"block_handoff"}
+    assert set(steps.REFUSED_KNOBS) <= knobs
+    heads, width, pools = cfg.cache_layout
+
+    S, blk, P, bucket, M = 2, 8, 5, 16, 2
+    sds = jax.ShapeDtypeStruct
+    params = jax.eval_shape(partial(steps.init_params, cfg=cfg), jax.random.key(0))
+    sample = (sds((), jnp.int32), sds((), jnp.float32), sds((), jnp.int32),
+              sds((), jnp.float32), sds((2,), jnp.uint32))
+    out = engine._prefill_fn(cfg, bucket, 8).lower(
+        params, sds((1, bucket), jnp.int32), *sample).out_info
+    assert len(out) == 5 and isinstance(out[-1], dict)
+    assert out[2].shape[0] == cfg.n_layers and (out[3] is None) == (pools == 1)
+    ctx = sds((cfg.n_layers, 1, 2 * bucket, heads, width), cfg.dtype)
+    out = engine._tail_fn(cfg, bucket, 8).lower(
+        params, ctx, ctx if pools == 2 else None, sds((1, bucket), jnp.int32),
+        sds((), jnp.int32), *sample).out_info
+    assert len(out) == 5 and isinstance(out[-1], dict)
+    assert (out[3] is None) == (pools == 1)
+    cache = jax.eval_shape(partial(create_cache, cfg, S, P, blk))
+    assert (cache.v is None) == (pools == 1)
+    state = engine._SlotState(
+        sds((S,), jnp.int32), sds((S, 2), jnp.uint32), sds((S,), jnp.float32),
+        sds((S,), jnp.int32), sds((S,), jnp.float32), sds((S,), jnp.int32),
+        sds((S,), bool), sds((S,), bool))
+    out = engine._decode_fn(cfg, "scan", blk, 8).lower(
+        params, cache, sds((S, M), jnp.int32), state).out_info
+    assert len(out) == 4 and isinstance(out[-1], dict)
+    assert jax.tree.structure(out[0]) == jax.tree.structure(cache)
+
+    real = steps.init_params(jax.random.key(0), cfg)
+    base = dict(slots=S, max_len=32, kv_block=blk)
+    for knob, (takes, _why) in steps.REFUSED_KNOBS.items():
+        other = {"quant_kv": "int8", "decode_impl": "pallas"}.get(knob, not takes)
+        with pytest.raises(NotImplementedError, match=knob):
+            if knob == "block_handoff":     # no field: refused where called
+                Engine(real, cfg, ServeConfig(**base)).export_prefix_blocks([1] * blk)
+            else:
+                Engine(real, cfg, ServeConfig(**base, **{knob: other}))

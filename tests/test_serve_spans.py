@@ -157,7 +157,7 @@ PROGRAMS = {
     "serve_prefill": lambda cfg: engine_mod._prefill_fn(cfg, 16, 8),
     "serve_tail_prefill": lambda cfg: engine_mod._tail_fn(cfg, 16, 8),
     "serve_decode": lambda cfg: engine_mod._decode_fn(cfg, "scan", 8, 8),
-    "serve_spec_decode": lambda cfg: engine_mod._spec_decode_fn(cfg, "scan", 8, 8, 2),
+    "serve_spec_decode": lambda cfg: engine_mod._decode_fn(cfg, "scan", 8, 8, draft_k=2),
     "serve_scatter": lambda cfg: engine_mod._scatter_fn(),
     "serve_scatter[int8]": lambda cfg: engine_mod._scatter_fn("int8"),
     "serve_copy_block": lambda cfg: engine_mod._copy_block_fn(),

@@ -2,9 +2,12 @@
 
 Deliberately an under-approximation: names are resolved through explicit
 imports, ``self.``/``cls.`` method access, module-level aliases
-(``g = partial(f, ...)``), and call arguments that are function references
-(``lax.scan(block, ...)`` adds caller -> block). Dynamic dispatch through
-duck-typed attributes is NOT resolved — checkers that need it (GL004's
+(``g = partial(f, ...)``), call arguments that are function references
+(``lax.scan(block, ...)`` adds caller -> block), and ONE module idiom:
+``m = pick(...)`` where every ``return`` of ``pick`` names an imported
+module makes ``m.func(...)`` a call into each of them (how
+serve/engine.py chooses a model family's steps). Other dynamic dispatch
+through duck-typed attributes is NOT resolved — checkers that need it (GL004's
 RPC-ish calls) match attribute patterns instead. Under-approximating keeps
 the zero-findings tier-1 gate honest: every finding is explainable from
 the source, so a clean tree stays clean without blanket suppressions.
@@ -213,6 +216,15 @@ class Project:
                     if fi is not None:
                         return (fi,)
             return ()
+        # head bound to the result of a module-returning function
+        # (_scope_aliases keeps such a module as "<module>:")
+        for aliases in (local_aliases, mi.aliases):
+            mods = [q[:-1] for q in (aliases or {}).get(parts[0], ())
+                    if q.endswith(":")]
+            if mods:
+                found = (self._resolve_in_module(m, ".".join(parts[1:]))
+                         for m in mods)
+                return tuple(fi for fi in found if fi is not None)
         fi = self._resolve_dotted(mi, parts)
         return (fi,) if fi is not None else ()
 
@@ -304,6 +316,10 @@ class Project:
                     for fi in self.resolve_candidates(mi, caller, v, aliases):
                         if fi.qualname not in quals:
                             quals.append(fi.qualname)
+                    if isinstance(v, ast.Call):
+                        for fi in self.resolve_candidates(mi, caller, v.func, aliases):
+                            quals += [m + ":" for m in self._returned_modules(fi)
+                                      if m + ":" not in quals]
                 if quals:
                     name = stmt.targets[0].id
                     merged = list(aliases.get(name, ()))
@@ -312,6 +328,22 @@ class Project:
                             merged.append(q)
                     aliases[name] = tuple(merged)
         return aliases
+
+    def _returned_modules(self, fi: FuncInfo) -> list[str]:
+        """Analyzed modules that ``fi``'s own ``return <name>`` statements
+        name through its module's imports."""
+        imports = self.modules[fi.module].imports
+        out: list[str] = []
+        for node in self._own_nodes(fi.node):
+            if not isinstance(node, ast.Return) or node.value is None:
+                continue
+            imp = imports.get(dotted(node.value) or "")
+            if imp is None:
+                continue
+            mod = imp[1] if imp[0] == "mod" else f"{imp[1]}.{imp[2]}"
+            if mod in self.modules and mod not in out:
+                out.append(mod)
+        return out
 
     def _own_nodes(self, root: ast.AST):
         stack = list(ast.iter_child_nodes(root))

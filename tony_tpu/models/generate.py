@@ -74,6 +74,39 @@ def _cached_attention(q, k_cache, v_cache, q_pos, cfg: LlamaConfig):
     return jnp.einsum("bhqk,bkhd->bqhd", p, v_cache)
 
 
+def _matmul(h, lp, name):
+    return h @ lp[name]
+
+
+def layer(x, lp, cfg: LlamaConfig, attend, rope, mm=_matmul):
+    """One dense decoder layer of the serving path (the counterpart of
+    ``models/latent_moe.layer``): norm -> q/k/v -> rope -> ``attend`` ->
+    ``wo`` -> residual -> norm -> SwiGLU -> residual, on ``x [..., D]``
+    with any leading axes (a prompt ``[B, S]``, one row a slot ``[S]``, or
+    ``[S, G]`` drafted positions).
+
+    The body does not know where keys and values live: ``attend(q [...,
+    H, hd], k [..., Hkv, hd], v)`` writes the new rows into the caller's
+    state and attends it, returning ``(output [..., H, hd], state)`` — a
+    contiguous :class:`KVCache` slab in :func:`forward_with_cache`, the
+    paged pools through the block table in ``serve/dense.decode_step``.
+    ``rope(t)`` rotates ``[..., heads, hd]`` at the caller's positions;
+    ``mm(h, lp, name)`` is ``h @ lp[name]`` or the int8 weight pair.
+    Returns ``(x', state)``. The training block (``models/llama.py``
+    ``transformer_block``, with its remat names and sharding constraints)
+    is a separate spelling (ROADMAP D2)."""
+    lead, hd = x.shape[:-1], cfg.head_dim
+    h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
+    q = rope(mm(h, lp, "wq").reshape(*lead, cfg.n_heads, hd))
+    k = rope(mm(h, lp, "wk").reshape(*lead, cfg.n_kv_heads, hd))
+    v = mm(h, lp, "wv").reshape(*lead, cfg.n_kv_heads, hd)
+    attn, state = attend(q, k, v)
+    x = x + mm(attn.reshape(*lead, cfg.n_heads * hd), lp, "wo")
+    h2 = rms_norm(x, lp["ffn_norm"], cfg.norm_eps)
+    delta = mm(jax.nn.silu(mm(h2, lp, "w1")) * mm(h2, lp, "w3"), lp, "w2")
+    return x + delta, state
+
+
 def forward_with_cache(
     params: Params,
     tokens: jax.Array,
@@ -104,22 +137,15 @@ def forward_with_cache(
     angles = q_pos.astype(jnp.float32)[:, None] * freqs[None, :]
     cos, sin = jnp.cos(angles), jnp.sin(angles)
 
-    def block(x, layer):
-        lp, k_cache, v_cache = layer
-        h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-        hd = cfg.head_dim
-        q = (h @ lp["wq"]).reshape(B, S, cfg.n_heads, hd)
-        k = (h @ lp["wk"]).reshape(B, S, cfg.n_kv_heads, hd)
-        v = (h @ lp["wv"]).reshape(B, S, cfg.n_kv_heads, hd)
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
-        k_cache = lax.dynamic_update_slice(k_cache, k, (0, start_pos, 0, 0))
-        v_cache = lax.dynamic_update_slice(v_cache, v, (0, start_pos, 0, 0))
-        attn = _cached_attention(q, k_cache, v_cache, q_pos, cfg)
-        x = x + attn.reshape(B, S, cfg.n_heads * hd) @ lp["wo"]
-        h2 = rms_norm(x, lp["ffn_norm"], cfg.norm_eps)
-        x = x + (jax.nn.silu(h2 @ lp["w1"]) * (h2 @ lp["w3"])) @ lp["w2"]
-        return x, (k_cache, v_cache)
+    def block(x, xs):
+        lp, k_cache, v_cache = xs
+
+        def attend(q, k, v):
+            ks = lax.dynamic_update_slice(k_cache, k, (0, start_pos, 0, 0))
+            vs = lax.dynamic_update_slice(v_cache, v, (0, start_pos, 0, 0))
+            return _cached_attention(q, ks, vs, q_pos, cfg), (ks, vs)
+
+        return layer(x, lp, cfg, attend, lambda t: apply_rope(t, cos, sin))
 
     x, (new_k, new_v) = lax.scan(block, x, (params["layers"], cache.k, cache.v))
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
@@ -296,5 +322,5 @@ def sample_tokens(
 
 __all__ = [
     "DEFAULT_NUCLEUS_K", "KVCache", "forward_with_cache", "generate",
-    "sample_tokens",
+    "layer", "sample_tokens",
 ]

@@ -125,6 +125,13 @@ class LatentMoEConfig:
         return -(-self.latent_dim // CACHE_LANES) * CACHE_LANES
 
     @property
+    def cache_layout(self) -> tuple[int, int, int]:
+        """``(heads, width, pools)`` of what serving caches per token and
+        layer (serve/cache.py): ONE row of ``cache_width`` in one pool —
+        keys and values are expanded from the same row."""
+        return 1, self.cache_width, 1
+
+    @property
     def shared_ffn_dim(self) -> int:
         return self.n_shared_experts * self.moe_ffn_dim
 

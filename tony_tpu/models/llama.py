@@ -117,6 +117,13 @@ class LlamaConfig:
         return self.n_experts > 0
 
     @property
+    def cache_layout(self) -> tuple[int, int, int]:
+        """``(heads, width, pools)`` of what serving caches per token and
+        layer (serve/cache.py): ``n_kv_heads`` rows of ``head_dim`` in each
+        of two pools, K and V."""
+        return self.n_kv_heads, self.head_dim, 2
+
+    @property
     def n_params(self) -> int:
         """Exact parameter count (embeddings included, tied=False)."""
         d, h = self.dim, self.head_dim

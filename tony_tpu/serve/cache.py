@@ -89,7 +89,7 @@ class PagedKVCache(NamedTuple):
     ``[L, P, Hkv]`` float32 — one dequantization scale per physical block
     per kv head (None on an unquantized cache).
 
-    A **latent cache** (:func:`pool_layout`) is ONE pool: ``k`` is
+    A **latent cache** (the configuration's ``cache_layout``) is ONE pool: ``k`` is
     ``[L, P, 1, block, cache_width]`` — per token and layer the compressed
     vector every head's keys and values are expanded from, then the rope
     key all heads share, then zeros up to the lanes — and ``v`` is None (an empty pytree
@@ -120,47 +120,51 @@ class PagedKVCache(NamedTuple):
         return self.k_scale is not None
 
 
-def pool_layout(cfg) -> tuple[int, int, int]:
-    """``(heads, width, pools)`` of the rows a model caches per token and
-    layer: ``n_kv_heads`` rows of ``head_dim`` in each of two pools (K and
-    V) for a grouped-query decoder; ONE row of ``kv_lora_rank +
-    qk_rope_head_dim`` values (padded to ``cfg.cache_width``) in one pool
-    for a latent-attention decoder (models/latent_moe.py), whose values
-    are a slice of the same row."""
-    if getattr(cfg, "kv_lora_rank", 0):
-        return 1, cfg.cache_width, 1
-    return cfg.n_kv_heads, cfg.head_dim, 2
+def map_pools(fn, cache: PagedKVCache, *rest):
+    """``fn`` over the payload pools that exist — ``(k, v)``, or ``(k,
+    None)`` for a latent cache (``None`` is an empty pytree node, so the
+    second pool is simply skipped) — each with its entry of every ``rest``
+    pair (``(pk, pv)``, the scale pools ...). Returns the pair. The one
+    place that knows a cache may hold one pool; callers write the per-pool
+    operation once."""
+    return jax.tree.map(fn, (cache.k, cache.v), *rest)
+
+
+def map_scales(fn, cache: PagedKVCache):
+    """``fn`` over the scale pools ``(k_scale, v_scale)``; ``(None, None)``
+    on an unquantized cache."""
+    return jax.tree.map(fn, (cache.k_scale, cache.v_scale))
+
+
+def map_cache(fn, cache: PagedKVCache) -> PagedKVCache:
+    """``cache`` with ``fn`` applied to every pool and scale pool it holds
+    (all are ``[L, P, ...]``); the lengths as they are."""
+    return PagedKVCache(
+        *map_pools(fn, cache), cache.lengths, *map_scales(fn, cache)
+    )
 
 
 def create_cache(
     cfg, slots: int, n_blocks: int, block: int, dtype=None,
     quant_kv: str = "",
 ) -> PagedKVCache:
-    """Fresh pool of ``n_blocks`` physical blocks (block 0 = scratch).
-    With ``quant_kv`` ('int8' | 'fp8_e4m3') the pools store the quantized
-    dtype plus zeroed per-block-per-head scale pools (scale 0 = block
-    holds nothing real yet)."""
-    heads, width, pools = pool_layout(cfg)
+    """Fresh pool of ``n_blocks`` physical blocks (block 0 = scratch), laid
+    out as the configuration's ``cache_layout`` says. With ``quant_kv``
+    ('int8' | 'fp8_e4m3') the pools store the quantized dtype plus zeroed
+    per-block-per-head scale pools (scale 0 = block holds nothing real
+    yet)."""
+    heads, width, pools = cfg.cache_layout
+    if quant_kv and pools == 1:
+        raise NotImplementedError("quant_kv: a latent cache has no quantized form")
     shape = (cfg.n_layers, n_blocks, heads, block, width)
-    if pools == 1:
-        if quant_kv:
-            raise NotImplementedError("quant_kv: a latent cache has no quantized form")
-        return PagedKVCache(
-            jnp.zeros(shape, dtype or cfg.dtype), None,
-            jnp.zeros((slots,), jnp.int32),
-        )
-    if quant_kv:
-        qdt, _ = kv_quant_spec(quant_kv)
-        sc = (cfg.n_layers, n_blocks, cfg.n_kv_heads)
-        return PagedKVCache(
-            jnp.zeros(shape, qdt), jnp.zeros(shape, qdt),
-            jnp.zeros((slots,), jnp.int32),
-            jnp.zeros(sc, jnp.float32), jnp.zeros(sc, jnp.float32),
-        )
-    dt = dtype or cfg.dtype
+    dt = kv_quant_spec(quant_kv)[0] if quant_kv else dtype or cfg.dtype
+    sc = (cfg.n_layers, n_blocks, heads)
     return PagedKVCache(
-        jnp.zeros(shape, dt), jnp.zeros(shape, dt),
+        jnp.zeros(shape, dt),
+        jnp.zeros(shape, dt) if pools == 2 else None,
         jnp.zeros((slots,), jnp.int32),
+        jnp.zeros(sc, jnp.float32) if quant_kv else None,
+        jnp.zeros(sc, jnp.float32) if quant_kv else None,
     )
 
 
@@ -169,17 +173,9 @@ def grow_cache(cache: PagedKVCache, n_blocks: int) -> PagedKVCache:
     extra = n_blocks - cache.n_blocks
     if extra <= 0:
         return cache
-    pad = [(0, 0), (0, extra), (0, 0), (0, 0), (0, 0)]
-    if cache.quantized:
-        spad = pad[:3]
-        return PagedKVCache(
-            jnp.pad(cache.k, pad), jnp.pad(cache.v, pad), cache.lengths,
-            jnp.pad(cache.k_scale, spad), jnp.pad(cache.v_scale, spad),
-        )
-    if cache.v is None:
-        return PagedKVCache(jnp.pad(cache.k, pad), None, cache.lengths)
-    return PagedKVCache(
-        jnp.pad(cache.k, pad), jnp.pad(cache.v, pad), cache.lengths
+    return map_cache(
+        lambda a: jnp.pad(a, [(0, 0), (0, extra)] + [(0, 0)] * (a.ndim - 2)),
+        cache,
     )
 
 
@@ -190,16 +186,7 @@ def shrink_cache(cache: PagedKVCache, n_blocks: int) -> PagedKVCache:
     slot bounds how far the pool can shrink)."""
     if n_blocks >= cache.n_blocks:
         return cache
-    if cache.quantized:
-        return PagedKVCache(
-            cache.k[:, :n_blocks], cache.v[:, :n_blocks], cache.lengths,
-            cache.k_scale[:, :n_blocks], cache.v_scale[:, :n_blocks],
-        )
-    if cache.v is None:
-        return PagedKVCache(cache.k[:, :n_blocks], None, cache.lengths)
-    return PagedKVCache(
-        cache.k[:, :n_blocks], cache.v[:, :n_blocks], cache.lengths
-    )
+    return map_cache(lambda a: a[:, :n_blocks], cache)
 
 
 def blocks_for(length: int, block: int) -> int:
@@ -591,7 +578,7 @@ def block_bytes(cfg, block: int, dtype=None, quant_kv: str = "") -> int:
         scales = 2 * cfg.n_layers * cfg.n_kv_heads * 4
         return payload + scales
     dt = jnp.dtype(dtype or cfg.dtype)
-    heads, width, pools = pool_layout(cfg)
+    heads, width, pools = cfg.cache_layout
     return pools * cfg.n_layers * heads * block * width * dt.itemsize
 
 
@@ -700,9 +687,11 @@ __all__ = [
     "export_blocks",
     "grow_cache",
     "kv_quant_spec",
+    "map_cache",
+    "map_pools",
+    "map_scales",
     "pack_payload",
     "payload_compatible",
-    "pool_layout",
     "quant_scatter_span",
     "quantize_values",
     "scan_layers_paged",

@@ -31,18 +31,17 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from tony_tpu.models.llama import LlamaConfig, init_params
+from tony_tpu.models.llama import LlamaConfig
 from tony_tpu.obs.compiles import aot_analysis
-from tony_tpu.serve.cache import (
-    PagedKVCache, blocks_for, kv_quant_spec, pool_layout,
-)
+from tony_tpu.serve.cache import PagedKVCache, blocks_for, create_cache
 
 
 def _param_avals(cfg: LlamaConfig):
-    init = init_params
-    if pool_layout(cfg)[2] == 1:    # latent-attention family
-        from tony_tpu.models.latent_moe import init_params as init
-    return jax.eval_shape(partial(init, cfg=cfg), jax.random.key(0))
+    from tony_tpu.serve.engine import steps_for
+
+    return jax.eval_shape(
+        partial(steps_for(cfg).init_params, cfg=cfg), jax.random.key(0)
+    )
 
 
 def _tree_bytes(tree) -> int:
@@ -61,24 +60,11 @@ def _cache_avals(cfg: LlamaConfig, slots: int, capacity: int,
     per-block-per-head float32 scale pools, so the measured plan prices
     exactly what the quantized engine allocates."""
     blocks = blocks_for(capacity, kv_block)
-    n_phys = 1 + slots * blocks
-    heads, width, pools = pool_layout(cfg)
-    shape = (cfg.n_layers, n_phys, heads, kv_block, width)
-    pool_dtype = kv_quant_spec(quant_kv)[0] if quant_kv else cfg.dtype
-    scale = None
-    if quant_kv:
-        scale = jax.ShapeDtypeStruct(
-            (cfg.n_layers, n_phys, cfg.n_kv_heads), jnp.float32
-        )
-    cache = PagedKVCache(
-        k=jax.ShapeDtypeStruct(shape, pool_dtype),
-        v=jax.ShapeDtypeStruct(shape, pool_dtype) if pools == 2 else None,
-        lengths=jax.ShapeDtypeStruct((slots,), jnp.int32),
-        k_scale=scale,
-        v_scale=scale,
+    cache = jax.eval_shape(
+        partial(create_cache, cfg, slots, 1 + slots * blocks, kv_block,
+                quant_kv=quant_kv)
     )
-    table = jax.ShapeDtypeStruct((slots, blocks), jnp.int32)
-    return cache, table
+    return cache, jax.ShapeDtypeStruct((slots, blocks), jnp.int32)
 
 
 def _state_avals(slots: int):

@@ -61,25 +61,23 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from tony_tpu.models.llama import LlamaConfig, Params, rms_norm, rope_freqs
+from tony_tpu.models.latent_moe import LatentMoEConfig
+from tony_tpu.models.llama import LlamaConfig, Params
 from tony_tpu.obs import hbm, health, profile, series, slo, trace
 from tony_tpu.obs import compiles as compile_ledger
 from tony_tpu.obs.metrics import DecodeMetrics
 from tony_tpu.obs.profiler import annotate
 from tony_tpu.obs.registry import HistogramWindow, Registry, snapshot_to_app_dir
-from tony_tpu.ops.decode_attention import decode_attention
-from tony_tpu.ops.quant_mm import quant_matmul, quantize_weights
+from tony_tpu.serve import dense as dense_steps
+from tony_tpu.serve import latent as latent_steps
 from tony_tpu.serve.cache import (
     SCRATCH_BLOCK, BlockPayload, BlockPool, PagedKVCache, block_bytes,
     blocks_for, create_cache, dequantize_values, export_blocks, grow_cache,
-    kv_quant_spec, payload_compatible, pool_layout, quant_scatter_span,
-    scan_layers_paged, scatter_block_kv, shrink_cache, write_block,
+    kv_quant_spec, map_cache, map_pools, map_scales, payload_compatible,
+    quant_scatter_span, shrink_cache, write_block,
 )
-from tony_tpu.serve import latent as latent_steps
 from tony_tpu.serve.prefix import MatchResult, PrefixStore
-from tony_tpu.serve.spec import (
-    DRAFT_SOURCES, propose_drafts, verify_and_accept,
-)
+from tony_tpu.serve.spec import DRAFT_SOURCES, propose_drafts
 
 log = logging.getLogger(__name__)
 
@@ -281,28 +279,11 @@ class Engine:
         or a ``models.latent_moe.LatentMoEConfig`` (latent attention,
         sigmoid group-limited experts): same loop, pool and tables; the
         step bodies and the cache's rows are the family's
-        (:func:`_is_latent`)."""
-        self._latent = _is_latent(cfg)
-        if self._latent:
-            for knob, why in latent_steps.REFUSED_KNOBS.items():
-                if getattr(serve, knob):
-                    raise NotImplementedError(
-                        f"{knob} is not supported for a latent-attention "
-                        f"model: {why}"
-                    )
-            if serve.decode_impl != "scan":
-                raise NotImplementedError(
-                    f"decode_impl={serve.decode_impl!r} is not supported for "
-                    "a latent-attention model: the absorbed latent decode "
-                    "has a scan form only"
-                )
-        elif cfg.is_moe:
-            # forward_with_cache (the prefill path) has no expert FFN —
-            # reject loudly instead of crashing at the first admission
-            raise NotImplementedError(
-                "serving MoE configs is not supported yet (prefill has no "
-                "expert dispatch)"
-            )
+        (:func:`steps_for`)."""
+        self._steps = steps_for(cfg)
+        for knob in self._steps.REFUSED_KNOBS:
+            # block_handoff is no field: refused where it is called
+            _refuse(self._steps, knob, getattr(serve, knob, False))
         self.params = params
         self.cfg = cfg
         max_len = serve.max_len or cfg.max_seq_len
@@ -380,7 +361,8 @@ class Engine:
         # int8 weight-only decode: quantize ONCE at build; prefill keeps
         # the bf16 master params, decode/spec steps read the quantized copy
         self._qparams = (
-            _quantize_decode_params(params) if self.serve.quant_weights
+            self._steps.quantize_decode_params(params)
+            if self.serve.quant_weights
             else None
         )
         self._dec_params = self._qparams if self._qparams is not None else params
@@ -953,17 +935,17 @@ class Engine:
                 # this call journals under the prefill's name, not
                 # anonymously
                 with self._ledger.label(f"serve.prefill[{bucket}]"):
-                    tok, carry, pk, pv, *moe = self._get_prefill(bucket)(
+                    tok, carry, pk, pv, aux = self._get_prefill(bucket)(
                         self.params, jnp.asarray(padded), jnp.int32(plen - 1),
                         jnp.float32(req.temperature), jnp.int32(req.top_k),
                         jnp.float32(req.top_p), key,
                     )
                 self._scatter_prompt(slot, pk, pv, 0, plen)
             else:
-                tok, carry, *moe = self._tail_prefill(slot, prompt, matched, req, key)
+                tok, carry, aux = self._tail_prefill(slot, prompt, matched, req, key)
             # EXPLICIT sync: the sampled first token steers admission on
             # the host (transfer-guard-clean under GRAFT_SANITIZE)
-            tok = self._fetch_first_token(tok, moe)
+            tok = int(self._fetch(tok, aux, step=False)[0])
         with annotate("serve.activate"):
             self._activate_slot(slot, rid, req, prompt, tok, carry, t0)
 
@@ -981,14 +963,13 @@ class Engine:
         with trace.span("serve.prefill_chunk", rid=job.rid, slot=slot,
                         start=job.pos, end=end, final=final), \
                 annotate("serve.prefill_chunk"):
-            tok, carry, *moe = self._tail_prefill(
+            tok, carry, aux = self._tail_prefill(
                 slot, job.prompt, job.pos, job.req, job.key, end=end
             )
             if final:
-                tok = self._fetch_first_token(tok, moe)
-            elif moe:
-                self.metrics.record_moe(*jax.device_get(
-                    (moe[0]["moe_routes"], moe[0]["moe_tokens"])), step=False)
+                tok = int(self._fetch(tok, aux, step=False)[0])
+            elif aux:
+                self._fetch((), aux, step=False)
         if not final:
             job.pos = end
             return
@@ -998,15 +979,19 @@ class Engine:
                 slot, job.rid, job.req, job.prompt, tok, carry, job.t0
             )
 
-    def _fetch_first_token(self, tok, moe: list) -> int:
-        """The prefill's one sync: the sampled token, and with it what the
-        prompt's expert layers routed here (latent-attention family)."""
-        if not moe:
-            return int(jax.device_get(tok))
-        tok, routes, n = jax.device_get(
-            (tok, moe[0]["moe_routes"], moe[0]["moe_tokens"]))
-        self.metrics.record_moe(routes, n, step=False)
-        return int(tok)
+    def _fetch(self, out, aux: dict, step: bool = True):
+        """A program's one sync, and the one reading of a step's ``aux``:
+        ``out`` (the sampled tokens ...) comes to the host and with it, in
+        the SAME ``device_get``, the expert routes a latent-attention step
+        counted (a second transfer cost ~2 ms a step on the chip). Returns
+        ``(out on the host, what is left of aux)`` — the health monitors,
+        which stay device references for the sentinel's worker thread."""
+        ride = {k: aux[k] for k in _AUX_FETCHED if k in aux}
+        out, ride = jax.device_get((out, ride))
+        if ride:
+            self.metrics.record_moe(
+                ride["moe_routes"], ride["moe_tokens"], step=step)
+        return out, {k: v for k, v in aux.items() if k not in ride}
 
     def _activate_slot(self, slot: int, rid: int, req: Request,
                        prompt: np.ndarray, tok: int, carry, t0: float) -> None:
@@ -1166,7 +1151,7 @@ class Engine:
                     # the copy overwrites dst's scale row with src's — a
                     # later zeroing flush would erase it
                     self._fresh_scale.remove(dst)
-                self.cache = _copy_block_fn(self.cache.quantized)(
+                self.cache = _copy_block_fn()(
                     self.cache, jnp.int32(match.partial), jnp.int32(dst)
                 )
                 row[next_bi] = dst
@@ -1280,14 +1265,14 @@ class Engine:
         tail = np.zeros((1, tb), np.int32)
         tail[0, :tail_len] = prompt[matched:plen]
         with self._ledger.label(f"serve.prefill_tail[{tb},{C}]"):
-            tok, carry, tk, tv, *moe = self._get_tail_prefill(tb, C)(
+            tok, carry, tk, tv, aux = self._get_tail_prefill(tb, ctx_k, ctx_v)(
                 self.params, ctx_k, ctx_v, jnp.asarray(tail),
                 jnp.int32(matched), jnp.int32(tail_len - 1),
                 jnp.float32(req.temperature), jnp.int32(req.top_k),
                 jnp.float32(req.top_p), key,
             )
         self._scatter_prompt(slot, tk, tv, matched, plen)
-        return (tok, carry, *moe)
+        return tok, carry, aux
 
     def _register_prompt(self, slot: int, prompt: np.ndarray) -> None:
         """Insert the prompt's full blocks into the prefix store (each new
@@ -1393,11 +1378,7 @@ class Engine:
         return created, nb - created
 
     def _refuse_block_handoff(self) -> None:
-        if self._latent:
-            raise NotImplementedError(
-                "gang block export/adopt is not supported for a "
-                "latent-attention model: BlockPayload ships (k, v) pools"
-            )
+        _refuse(self._steps, "block_handoff", True)
 
     def _maybe_shrink_pool(self) -> None:
         """Halve the pool while the trailing half is entirely free — a
@@ -1444,10 +1425,11 @@ class Engine:
             )
         return self._prefill_fns[bucket]
 
-    def _get_tail_prefill(self, tb: int, ctx: int):
+    def _get_tail_prefill(self, tb: int, ctx_k, ctx_v):
+        ctx = ctx_k.shape[2]
         if (tb, ctx) not in self._tail_fns:
             self._tail_fns[(tb, ctx)] = _aot_tail_prefill(
-                self.cfg, tb, ctx, self.serve.max_top_k, self.params,
+                self.cfg, tb, ctx_k, ctx_v, self.serve.max_top_k, self.params,
                 self._ledger,
             )
             self.metrics.prefill_compiles = (
@@ -1455,8 +1437,15 @@ class Engine:
             )
         return self._tail_fns[(tb, ctx)]
 
-    def _get_decode(self, signature: tuple[int, int]):
-        if signature not in self._decode_fns:
+    def _get_decode(self, signature: tuple[int, int], draft_k: int = 0):
+        """The decode step at ``signature`` = (pool blocks, attended table
+        width); with ``draft_k`` the speculative (G = draft_k + 1)-position
+        verify step over the same signature space — ONE fixed G per engine
+        (``spec_max_draft``), so spec adds at most a bounded mirror of the
+        plain ledger, never a per-draft-length family (short drafts pad to G
+        with writes steered to the scratch block)."""
+        fns = self._spec_fns if draft_k else self._decode_fns
+        if signature not in fns:
             # AOT-compiled per (model, kernel, shapes, sharding), shared
             # across engines module-wide (_aot_decode's cache — every
             # pool-size/table-width signature compiles once per process,
@@ -1464,39 +1453,18 @@ class Engine:
             # ledger record the decode step's measured memory plan
             # (memory_analysis: params + temp + per-block KV bytes), which
             # the gqa_capacity slot budget is derived from. The per-engine
-            # dict only counts the distinct signatures this engine entered.
-            self._decode_fns[signature] = _aot_decode(
+            # dicts only count the distinct signatures this engine entered.
+            fns[signature] = _aot_decode(
                 self.cfg, self.serve.decode_impl, self.serve.kv_block,
                 self.serve.max_top_k, self._dec_params, self.cache,
                 self._table_dev, self.state, self._ledger,
                 monitors=self._monitors, quant_kv=self.serve.quant_kv,
-                quant_weights=self.serve.quant_weights,
+                quant_weights=self.serve.quant_weights, draft_k=draft_k,
             )
             self.metrics.decode_compiles = (
                 len(self._decode_fns) + len(self._spec_fns)
             )
-        return self._decode_fns[signature]
-
-    def _get_spec_decode(self, signature: tuple[int, int]):
-        """The speculative (G = spec_max_draft + 1)-position verify step.
-        Same signature space as the 1-wide step — (pool blocks, attended
-        table width) — at ONE fixed G per engine, so spec adds at most a
-        bounded mirror of the plain ledger, never a per-draft-length
-        signature family (short drafts pad to G with writes steered to
-        the scratch block)."""
-        if signature not in self._spec_fns:
-            self._spec_fns[signature] = _aot_spec_decode(
-                self.cfg, self.serve.decode_impl, self.serve.kv_block,
-                self.serve.max_top_k, self.serve.spec_max_draft,
-                self._dec_params, self.cache, self._table_dev, self.state,
-                self._ledger, monitors=self._monitors,
-                quant_kv=self.serve.quant_kv,
-                quant_weights=self.serve.quant_weights,
-            )
-            self.metrics.decode_compiles = (
-                len(self._decode_fns) + len(self._spec_fns)
-            )
-        return self._spec_fns[signature]
+        return fns[signature]
 
     # --- decode loop ----------------------------------------------------------
 
@@ -1565,8 +1533,8 @@ class Engine:
             with annotate("serve.dispatch"):
                 sig = (self.cache.n_blocks, self._attended)
                 if spec_step:
-                    self.cache, self.state, toks, n_emit, hmon = \
-                        self._get_spec_decode(sig)(
+                    self.cache, self.state, toks, n_emit, aux = \
+                        self._get_decode(sig, self.serve.spec_max_draft)(
                             self._dec_params, self.cache, self._table_dev,
                             self.state, jnp.asarray(drafts_np),
                             jnp.asarray(np.asarray(dlens, np.int32)),
@@ -1575,7 +1543,7 @@ class Engine:
                     # no live slot drafted: the plain 1-wide step (also the
                     # only step compiled with spec off — same signatures as
                     # the pre-spec engine)
-                    self.cache, self.state, toks, hmon = \
+                    self.cache, self.state, toks, aux = \
                         self._get_decode(sig)(
                             self._dec_params, self.cache, self._table_dev,
                             self.state,
@@ -1584,19 +1552,12 @@ class Engine:
             # tokens + done flags on host to steer admission — this is the
             # engine's one designed sync point per decode step
             with annotate("serve.sync"):
-                # a step's expert routes (latent-attention family) ride the
-                # tokens' own transfer: one device_get, no round trip of
-                # their own (a second one cost ~2 ms a step on the chip)
-                moe = ((hmon.pop("moe_routes"), hmon.pop("moe_tokens"))
-                       if "moe_routes" in hmon else ())
-                toks_np, *moe = jax.device_get((toks, *moe))
+                toks_np, hmon = self._fetch(toks, aux)
                 toks_np = np.asarray(toks_np)
                 emit_np = (
                     np.asarray(jax.device_get(n_emit)) if spec_step else None
                 )
                 done_np = jax.device_get(self.state.done)
-                if moe:
-                    self.metrics.record_moe(*moe)
             dt = time.perf_counter() - t0
         with annotate("serve.emit"):
             if spec_step:
@@ -1641,25 +1602,47 @@ class Engine:
                 elif self._slot_remaining[s] <= 0:
                     self._finish(s, "length")
 
-    def _decode_impl(self, params, cache: PagedKVCache, table, state: _SlotState):
-        """One token for every slot (test/guard hook; the hot path goes
-        through the module-level cache in :func:`_decode_fn`)."""
-        return _decode_step(
-            params, cache, table, state, cfg=self.cfg,
-            decode_impl=self.serve.decode_impl,
-            kv_block=self.serve.kv_block, max_top_k=self.serve.max_top_k,
-            monitors=self._monitors, quant_kv=self.serve.quant_kv,
-            quant_weights=self.serve.quant_weights,
+
+def steps_for(cfg):
+    """The model family, chosen ONCE by the configuration's class: the
+    module whose ``prefill_step`` / ``tail_prefill_step`` / ``decode_step``
+    the program builders below run and whose ``REFUSED_KNOBS`` the engine
+    enforces (docs/SERVE.md "Model families" has the contract). The
+    programs keep ONE set of names (``jit_serve_prefill`` ...) whichever
+    family's bodies they run; what a token's cache row is comes from the
+    configuration too (``cfg.cache_layout``).
+
+    Spelled as plain ``return <module>`` statements for graft-lint: its
+    call graph follows a name bound to this function's result into every
+    module it can return (analysis/callgraph.py), which is what keeps both
+    families' steps inside the GL001 gate (tests/test_lint.py)."""
+    if isinstance(cfg, LatentMoEConfig):
+        return latent_steps
+    if isinstance(cfg, LlamaConfig):
+        if cfg.is_moe:
+            # forward_with_cache (the prefill path) has no expert FFN —
+            # reject loudly instead of crashing at the first admission
+            raise NotImplementedError(
+                "serving MoE configs is not supported yet (prefill has no "
+                "expert dispatch)"
+            )
+        return dense_steps
+    raise TypeError(f"no serving steps for a {type(cfg).__name__}")
+
+
+def _refuse(steps, knob: str, value) -> None:
+    """Raise, by the knob's name, where the family's ``REFUSED_KNOBS``
+    takes another value than ``value``."""
+    takes, why = steps.REFUSED_KNOBS.get(knob, (value, ""))
+    if value != takes:
+        raise NotImplementedError(
+            f"{knob}={value!r} is not supported by {steps.__name__}: {why}"
         )
 
 
-def _is_latent(cfg) -> bool:
-    """The model family by what its cache holds (serve/cache.py
-    ``pool_layout``): a latent-attention decoder's step bodies are
-    serve/latent.py's, a dense grouped-query decoder's are below. The
-    programs keep ONE set of names (``jit_serve_prefill`` ...) whichever
-    bodies they run."""
-    return pool_layout(cfg)[2] == 1
+# the entries of a step's ``aux`` that Engine._fetch brings to the host with
+# the sampled tokens; whatever else rides there is a health monitor
+_AUX_FETCHED = ("moe_routes", "moe_tokens")
 
 
 @functools.lru_cache(maxsize=512)
@@ -1669,13 +1652,10 @@ def _prefill_fn(cfg: LlamaConfig, bucket: int, max_top_k: int):
     function, not a ``partial``: jit names the program after it, and a
     device trace then reads ``jit_serve_prefill`` where a partial gives
     ``jit__unknown`` (the same holds for every ``serve_*`` below)."""
+    steps = steps_for(cfg)
+
     def serve_prefill(params, prompt, last_index, temp, top_k, top_p, key):
-        if _is_latent(cfg):
-            return latent_steps.prefill_step(
-                params, prompt, last_index, temp, top_k, top_p, key,
-                cfg=cfg, bucket=bucket, max_top_k=max_top_k,
-            )
-        return _prefill_step(
+        return steps.prefill_step(
             params, prompt, last_index, temp, top_k, top_p, key,
             cfg=cfg, bucket=bucket, max_top_k=max_top_k,
         )
@@ -1687,14 +1667,11 @@ def _prefill_fn(cfg: LlamaConfig, bucket: int, max_top_k: int):
 def _tail_fn(cfg: LlamaConfig, tb: int, max_top_k: int):
     """Jitted tail prefill (prefix-matched admissions), cached per (model
     config, tail bucket); jit itself caches per context width."""
+    steps = steps_for(cfg)
+
     def serve_tail_prefill(params, ctx_k, ctx_v, tail, start, last_index,
                            temp, top_k, top_p, key):
-        if _is_latent(cfg):
-            return latent_steps.tail_prefill_step(
-                params, ctx_k, ctx_v, tail, start, last_index, temp, top_k,
-                top_p, key, cfg=cfg, tb=tb, max_top_k=max_top_k,
-            )
-        return _tail_prefill_step(
+        return steps.tail_prefill_step(
             params, ctx_k, ctx_v, tail, start, last_index, temp, top_k,
             top_p, key, cfg=cfg, tb=tb, max_top_k=max_top_k,
         )
@@ -1705,34 +1682,44 @@ def _tail_fn(cfg: LlamaConfig, tb: int, max_top_k: int):
 @functools.lru_cache(maxsize=512)
 def _decode_fn(cfg: LlamaConfig, decode_impl: str, kv_block: int,
                max_top_k: int, monitors: bool = False, quant_kv: str = "",
-               quant_weights: bool = False):
+               quant_weights: bool = False, draft_k: int = 0):
     """Jitted decode step, cached per (model config, kernel knobs) — NOT
     per pool-size/table-width: jit itself caches per argument shape, so
-    all engines with the same model reuse every compiled signature.
+    all engines with the same model reuse every compiled signature. With
+    ``draft_k`` it is the speculative verify step ``jit_serve_spec_decode``
+    (two more arguments, ``n_emit`` among its results); both run the
+    family's one ``decode_step``.
 
     Contract: the cache (arg 1) and the slot state (arg 3) are DONATED and
     the pools come back as the same buffers — carried through the layer
     scan as ``[L * P, ...]`` and written in place, ``S x Hkv x hd`` values
-    per layer per pool (:func:`scan_layers_paged`,
-    ``serve/cache.scatter_block_kv``); the compiled step's temporaries do
+    per layer per pool (``serve/cache.scan_layers_paged``,
+    ``scatter_block_kv``); the compiled step's temporaries do
     not grow with the pool (tests/test_perf_guard.py) and on the chip it
     holds no pool- or slab-shaped copy (PERF.md §5). A dead slot's write
     lands in the scratch block of the layer being written (block
     ``l * P`` of the flat view). The block table (arg 2) is NOT donated —
     it is reused across steps — and names blocks of ONE layer; the step
     adds the layer's offset itself."""
-    def serve_decode(params, cache, table, state):
-        if _is_latent(cfg):
-            return latent_steps.decode_step(
-                params, cache, table, state, cfg=cfg, kv_block=kv_block,
-                max_top_k=max_top_k, monitors=monitors,
-            )
-        return _decode_step(
-            params, cache, table, state, cfg=cfg, decode_impl=decode_impl,
-            kv_block=kv_block, max_top_k=max_top_k, monitors=monitors,
-            quant_kv=quant_kv, quant_weights=quant_weights,
-        )
+    steps = steps_for(cfg)
+    # a knob the family refuses is held at its off value by Engine.__init__
+    # and never reaches the step
+    knobs = {k: v for k, v in dict(
+        decode_impl=decode_impl, quant_kv=quant_kv,
+        quant_weights=quant_weights).items() if k not in steps.REFUSED_KNOBS}
+    knobs.update(cfg=cfg, kv_block=kv_block, max_top_k=max_top_k,
+                 monitors=monitors)
 
+    def serve_decode(params, cache, table, state):
+        return steps.decode_step(params, cache, table, state, **knobs)
+
+    def serve_spec_decode(params, cache, table, state, drafts, draft_len):
+        return steps.decode_step(
+            params, cache, table, state, drafts, draft_len, draft_k=draft_k,
+            **knobs)
+
+    if draft_k:
+        return jax.jit(serve_spec_decode, donate_argnums=(1, 3))
     return jax.jit(serve_decode, donate_argnums=(1, 3))
 
 
@@ -1764,12 +1751,12 @@ def _aot_compile(fn, avals, key, name, ledger, cache=_aot_prefill_cache):
 def _aot_decode(cfg: LlamaConfig, decode_impl: str, kv_block: int,
                 max_top_k: int, params, cache, table, state, ledger, *,
                 monitors: bool = False, quant_kv: str = "",
-                quant_weights: bool = False):
+                quant_weights: bool = False, draft_k: int = 0):
     fn = _decode_fn(cfg, decode_impl, kv_block, max_top_k, monitors,
-                    quant_kv, quant_weights)
+                    quant_kv, quant_weights, draft_k)
     try:
         shard = jax.tree.leaves(params)[0].sharding
-        key = (cfg, decode_impl, kv_block, max_top_k, monitors,
+        key = (cfg, decode_impl, kv_block, max_top_k, draft_k, monitors,
                quant_kv, quant_weights,
                cache.k.shape, str(cache.k.dtype), table.shape,
                hash(shard), shard)
@@ -1777,11 +1764,15 @@ def _aot_decode(cfg: LlamaConfig, decode_impl: str, kv_block: int,
         # params without a hashable sharding (plain numpy arrays): lazy jit
         # still works and still shares compiles process-wide
         return fn
-    name = (f"serve.decode[slots={state.last_tok.shape[0]},"
-            f"blocks={cache.k.shape[1]},attended={table.shape[1]}]")
+    S = state.last_tok.shape[0]
+    shape = f"slots={S},blocks={cache.k.shape[1]},attended={table.shape[1]}"
+    avals = (params, cache, table, state)
+    name = f"serve.decode[{shape}]"
+    if draft_k:
+        avals += (_sds((S, draft_k), jnp.int32), _sds((S,), jnp.int32))
+        name = f"serve.decode_spec[{shape},k={draft_k}]"
     return _aot_compile(
-        fn, (params, cache, table, state), key, name, ledger,
-        cache=_aot_decode_cache,
+        fn, avals, key, name, ledger, cache=_aot_decode_cache,
     )
 
 
@@ -1811,18 +1802,20 @@ def _aot_prefill(cfg: LlamaConfig, bucket: int, max_top_k: int, params,
     return _aot_compile(fn, avals, key, f"serve.prefill[{bucket}]", ledger)
 
 
-def _aot_tail_prefill(cfg: LlamaConfig, tb: int, ctx: int, max_top_k: int,
-                      params, ledger):
+def _aot_tail_prefill(cfg: LlamaConfig, tb: int, ctx_k, ctx_v,
+                      max_top_k: int, params, ledger):
+    """``ctx_k`` / ``ctx_v``: the gathered context the program will attend
+    (``ctx_v`` None where the cache is one pool); their shapes are the
+    program's."""
     fn = _tail_fn(cfg, tb, max_top_k)
+    ctx = ctx_k.shape[2]
     try:
         shard = jax.tree.leaves(params)[0].sharding
         key = ("tail", cfg, tb, ctx, max_top_k, hash(shard), shard)
     except (AttributeError, TypeError):  # params are not jax arrays
         return fn
-    heads, width, pools = pool_layout(cfg)
-    kv = _sds((cfg.n_layers, 1, ctx, heads, width), cfg.dtype)
     avals = (
-        params, kv, kv if pools == 2 else None, _sds((1, tb), jnp.int32),
+        params, ctx_k, ctx_v, _sds((1, tb), jnp.int32),
         _sds((), jnp.int32),
         _sds((), jnp.int32), _sds((), jnp.float32), _sds((), jnp.int32),
         _sds((), jnp.float32), _sds((2,), jnp.uint32),
@@ -1864,44 +1857,33 @@ def _scatter_fn(quant_kv: str = ""):
         return jax.jit(serve_scatter, donate_argnums=(0,))
 
     def serve_scatter(cache: PagedKVCache, pk, pv, pids, offs, slot, plen):
-        # pk/pv [L, Hkv, W, hd]; advanced indices (pids axis 1, offs axis
+        # rows [L, Hkv, W, hd]; advanced indices (pids axis 1, offs axis
         # 3) are non-adjacent, so the indexed result moves to the front:
         # [W, L, Hkv, hd] — match it by transposing the span
-        k = cache.k.at[:, pids, :, offs, :].set(pk.transpose(2, 0, 1, 3))
-        v = None    # a latent cache is one pool
-        if cache.v is not None:
-            v = cache.v.at[:, pids, :, offs, :].set(pv.transpose(2, 0, 1, 3))
+        k, v = map_pools(
+            lambda pool, rows: pool.at[:, pids, :, offs, :].set(
+                rows.transpose(2, 0, 1, 3)),
+            cache, (pk, pv),
+        )
         lengths = lax.dynamic_update_slice(cache.lengths, plen[None], (slot,))
         return PagedKVCache(k, v, lengths)
 
     return jax.jit(serve_scatter, donate_argnums=(0,))
 
 
-@functools.lru_cache(maxsize=2)
-def _copy_block_fn(quant: bool = False):
+@functools.lru_cache(maxsize=1)
+def _copy_block_fn():
     """Jitted copy-on-write block copy (DONATED pool): duplicate one
-    physical block (all layers, K and V) so a slot about to write into a
-    shared block writes into its private copy instead. A quantized pool
+    physical block (all layers, every pool) so a slot about to write into
+    a shared block writes into its private copy instead. A quantized pool
     copies the block's scale rows with it — the COW copy dequantizes to
     exactly what the shared source did."""
     def serve_copy_block(cache: PagedKVCache, src, dst):
-        kb = lax.dynamic_slice_in_dim(cache.k, src, 1, axis=1)
-        k = lax.dynamic_update_slice_in_dim(cache.k, kb, dst, axis=1)
-        if cache.v is None:     # a latent cache is one pool
-            return PagedKVCache(k, None, cache.lengths)
-        vb = lax.dynamic_slice_in_dim(cache.v, src, 1, axis=1)
-        v = lax.dynamic_update_slice_in_dim(cache.v, vb, dst, axis=1)
-        if quant:
-            ksb = lax.dynamic_slice_in_dim(cache.k_scale, src, 1, axis=1)
-            vsb = lax.dynamic_slice_in_dim(cache.v_scale, src, 1, axis=1)
-            ksc = lax.dynamic_update_slice_in_dim(
-                cache.k_scale, ksb, dst, axis=1
-            )
-            vsc = lax.dynamic_update_slice_in_dim(
-                cache.v_scale, vsb, dst, axis=1
-            )
-            return PagedKVCache(k, v, cache.lengths, ksc, vsc)
-        return PagedKVCache(k, v, cache.lengths)
+        def copy(pool):
+            blk = lax.dynamic_slice_in_dim(pool, src, 1, axis=1)
+            return lax.dynamic_update_slice_in_dim(pool, blk, dst, axis=1)
+
+        return map_cache(copy, cache)
 
     return jax.jit(serve_copy_block, donate_argnums=(0,))
 
@@ -1912,10 +1894,9 @@ def _zero_scales_fn():
     blocks' K and V scale rows go to zero across all layers — the
     nothing-real-stored marker the first quantized write keys off."""
     def serve_zero_scales(cache: PagedKVCache, pids):
-        return cache._replace(
-            k_scale=cache.k_scale.at[:, pids, :].set(0.0),
-            v_scale=cache.v_scale.at[:, pids, :].set(0.0),
-        )
+        k_scale, v_scale = map_scales(
+            lambda sc: sc.at[:, pids, :].set(0.0), cache)
+        return cache._replace(k_scale=k_scale, v_scale=v_scale)
 
     return jax.jit(serve_zero_scales, donate_argnums=(0,))
 
@@ -1923,12 +1904,12 @@ def _zero_scales_fn():
 @functools.lru_cache(maxsize=4)
 def _gather_fn(quant: bool = False, out_dtype=None):
     """Jitted prefix gather: pool blocks ``pids`` -> one contiguous
-    ``[L, 1, C, Hkv, hd]`` context cache for the tail prefill (read-only:
-    the pool is NOT donated — the slot keeps serving from it). Quantized
-    pools dequantize through the gathered blocks' scale rows into
+    ``[L, 1, C, Hkv, hd]`` context cache a pool for the tail prefill
+    (read-only: the pool is NOT donated — the slot keeps serving from it).
+    Quantized pools dequantize through the gathered blocks' scale rows into
     ``out_dtype`` — the tail prefill attends real-valued context."""
     def serve_gather(cache: PagedKVCache, pids):
-        def one(pool, scale):
+        def one(pool, scale=None):
             g = jnp.take(pool, pids, axis=1)           # [L, nC, Hkv, blk, hd]
             if quant:
                 sc = jnp.take(scale, pids, axis=1)     # [L, nC, Hkv]
@@ -1937,353 +1918,13 @@ def _gather_fn(quant: bool = False, out_dtype=None):
             return g.transpose(0, 1, 3, 2, 4).reshape(
                 L, nC * blk, Hkv, hd
             )[:, None]                                 # [L, 1, C, Hkv, hd]
-        if cache.v is None:     # a latent cache is one pool
-            return one(cache.k, None), None
-        return one(cache.k, cache.k_scale), one(cache.v, cache.v_scale)
+        scales = ((cache.k_scale, cache.v_scale),) if quant else ()
+        return map_pools(one, cache, *scales)
 
     return jax.jit(serve_gather)
 
 
-_QUANT_WEIGHT_NAMES = ("wq", "wk", "wv", "wo", "w1", "w2", "w3")
-
-
-def _quantize_decode_params(params: Params) -> dict:
-    """One-time int8 copy of the decode-path weights (ops/quant_mm.py):
-    every layer matmul and lm_head swap to ``<name>_q``/``<name>_s``
-    pairs; norms and the embedding stay real-valued. The bf16 master
-    params are untouched — prefill keeps using them."""
-    layers = dict(params["layers"])
-    for name in _QUANT_WEIGHT_NAMES:
-        q, s = quantize_weights(layers.pop(name))
-        layers[name + "_q"] = q
-        layers[name + "_s"] = s
-    out = {k: v for k, v in params.items() if k not in ("layers", "lm_head")}
-    q, s = quantize_weights(params["lm_head"])
-    out["layers"] = layers
-    out["lm_head_q"] = q
-    out["lm_head_s"] = s
-    return out
-
-
-def _prefill_step(params, prompt, last_index, temp, top_k, top_p, key, *,
-                  cfg: LlamaConfig, bucket: int, max_top_k: int):
-    from tony_tpu.models.generate import (
-        KVCache, forward_with_cache, sample_tokens,
-    )
-
-    cache0 = KVCache.create(cfg, 1, bucket)
-    logits, kv = forward_with_cache(
-        params, prompt, cache0, jnp.int32(0), cfg, last_index=last_index
-    )
-    use, carry = jax.random.split(key)
-    tok = sample_tokens(
-        logits[:, 0], temp[None], top_k[None], top_p[None], use[None],
-        max_k=max_top_k,
-    )[0]
-    # [L, 1, bucket, Hkv, hd] -> head-major [L, Hkv, bucket, hd]
-    pk = kv.k[:, 0].transpose(0, 2, 1, 3)
-    pv = kv.v[:, 0].transpose(0, 2, 1, 3)
-    return tok, carry, pk, pv
-
-
-def _tail_prefill_step(params, ctx_k, ctx_v, tail, start, last_index, temp,
-                       top_k, top_p, key, *, cfg: LlamaConfig, tb: int,
-                       max_top_k: int):
-    """Prefill only the unshared tail of a prefix-matched prompt: the
-    gathered prefix K/V (``[L, 1, C, Hkv, hd]``, positions ``[0, start)``
-    valid) is the attention context, the tail bucket runs from absolute
-    position ``start``, and only the prompt's true last position projects
-    through lm_head. Bitwise-identical to the full prefill's logits —
-    forward_with_cache masks by absolute position and every masked term is
-    exactly zero."""
-    from tony_tpu.models.generate import (
-        KVCache, forward_with_cache, sample_tokens,
-    )
-
-    logits, kv = forward_with_cache(
-        params, tail, KVCache(ctx_k, ctx_v), start, cfg,
-        last_index=last_index,
-    )
-    use, carry = jax.random.split(key)
-    tok = sample_tokens(
-        logits[:, 0], temp[None], top_k[None], top_p[None], use[None],
-        max_k=max_top_k,
-    )[0]
-    # the tail's K/V, head-major [L, Hkv, tb, hd], for the block scatter
-    tk = lax.dynamic_slice_in_dim(kv.k[:, 0], start, tb, axis=1)
-    tv = lax.dynamic_slice_in_dim(kv.v[:, 0], start, tb, axis=1)
-    return tok, carry, tk.transpose(0, 2, 1, 3), tv.transpose(0, 2, 1, 3)
-
-
-def _q_mm(h, lp, name, quant_weights, impl):
-    """One decode matmul: the bf16 master weight, or its int8 copy through
-    the fused dequant-matmul (ops/quant_mm.py) when quantized."""
-    if quant_weights:
-        return quant_matmul(h, lp[name + "_q"], lp[name + "_s"], impl=impl)
-    return h @ lp[name]
-
-
-def _write_kv(pools, k_new, v_new, pids, offs, qmax):
-    """This layer's K/V rows into the carried pools ``(k, v, k_scale,
-    v_scale)``; with scale pools (a quantized cache) the written amax
-    folds into the block scale. ``pids`` already carry the layer's offset."""
-    k, v, ks, vs = pools
-    if ks is None:
-        return (scatter_block_kv(k, k_new, pids, offs),
-                scatter_block_kv(v, v_new, pids, offs), None, None)
-    k, ks = scatter_block_kv(k, k_new, pids, offs, scale=ks, qmax=qmax)
-    v, vs = scatter_block_kv(v, v_new, pids, offs, scale=vs, qmax=qmax)
-    return k, v, ks, vs
-
-
-def _decode_step(params, cache: PagedKVCache, table, state: _SlotState, *,
-                 cfg: LlamaConfig, decode_impl: str, kv_block: int,
-                 max_top_k: int, monitors: bool = False, quant_kv: str = "",
-                 quant_weights: bool = False):
-    """One token for every slot: write K/V at each row's position (into
-    the physical block its table names — dead slots steer to the scratch
-    block so a freed, possibly reallocated block can never be corrupted),
-    attend over its written prefix through the table, sample with its own
-    stream. The pools ride the layer scan as its carry and are written in
-    place (:func:`scan_layers_paged`); ``table`` and the write ids name
-    blocks of one layer and take the layer's offset inside the scan, so a
-    dead slot's row lands in that layer's scratch block.
-    ``monitors`` additionally returns the fused per-slot health
-    monitors (logits nonfinite counts + sampling entropy, obs/health.py);
-    the dict is empty when disarmed so the signature stays stable.
-
-    ``quant_kv``: the pools are block-scaled quantized — writes fold into
-    the running block scale and the attention kernels dequantize inline
-    through the scale pools, which ride the layer scan next to their
-    payloads. ``quant_weights``: the seven layer matmuls + lm_head read
-    int8 weights through the fused dequant-matmul."""
-    from tony_tpu.models.generate import sample_tokens
-
-    qmax = kv_quant_spec(quant_kv)[1] if quant_kv else 0.0
-    S = state.last_tok.shape[0]
-    hd, H, Hkv = cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
-    x = params["tok_emb"][state.last_tok]                  # [S, D]
-    pos = cache.lengths                                    # [S]
-    ang = pos.astype(jnp.float32)[:, None] * rope_freqs(cfg)[None, :]
-    cos = jnp.cos(ang)[:, None, :]                         # [S, 1, half]
-    sin = jnp.sin(ang)[:, None, :]
-
-    def rope(t):  # [S, H', hd], per-row angle
-        t1, t2 = jnp.split(t.astype(jnp.float32), 2, axis=-1)
-        return jnp.concatenate(
-            [t1 * cos - t2 * sin, t2 * cos + t1 * sin], axis=-1
-        ).astype(t.dtype)
-
-    # paged write target: row s's position lands in physical block
-    # table[s, pos // block] at offset pos % block
-    bi = pos // kv_block
-    off = pos % kv_block
-    pid = jnp.where(
-        state.live,
-        jnp.take_along_axis(table, bi[:, None], axis=1)[:, 0],
-        SCRATCH_BLOCK,
-    )
-
-    def block(x, lp, pools, base):
-        h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-        mm = partial(_q_mm, quant_weights=quant_weights, impl=decode_impl)
-        q = rope(mm(h, lp, "wq").reshape(S, H, hd))
-        k_new = rope(mm(h, lp, "wk").reshape(S, Hkv, hd))
-        v_new = mm(h, lp, "wv").reshape(S, Hkv, hd)
-        # in-place row writes into the carried pool at this layer's blocks
-        # (pid is a block of ONE layer; base = l * P moves it — and the
-        # scratch block — into layer l's range)
-        pools = _write_kv(pools, k_new, v_new, pid + base, off, qmax)
-        k_pool, v_pool, k_sc, v_sc = pools
-        attn = decode_attention(
-            q, k_pool, v_pool, pos + 1, tables=table + base,
-            block=kv_block, k_scale=k_sc, v_scale=v_sc,
-        )
-        x = x + mm(attn.reshape(S, H * hd), lp, "wo")
-        h2 = rms_norm(x, lp["ffn_norm"], cfg.norm_eps)
-        delta = mm(jax.nn.silu(mm(h2, lp, "w1")) * mm(h2, lp, "w3"),
-                   lp, "w2")
-        return x + delta, pools
-
-    x, pools = scan_layers_paged(block, x, params["layers"], cache)
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    if quant_weights:
-        logits = quant_matmul(
-            x, params["lm_head_q"], params["lm_head_s"], impl=decode_impl
-        ).astype(jnp.float32)                              # [S, V]
-    else:
-        logits = (x @ params["lm_head"]).astype(jnp.float32)   # [S, V]
-
-    both = jax.vmap(jax.random.split)(state.rng)           # [S, 2, 2]
-    nxt = sample_tokens(
-        logits, state.temp, state.top_k, state.top_p, both[:, 0],
-        max_k=max_top_k,
-    )
-    has_eos = state.eos >= 0
-    nxt = jnp.where(state.done & has_eos, state.eos, nxt)
-    done = state.done | (has_eos & (nxt == state.eos))
-    lengths = cache.lengths + state.live.astype(jnp.int32)
-    new_state = state._replace(last_tok=nxt, rng=both[:, 1], done=done)
-    hmon = health.decode_monitors(logits) if monitors else {}
-    return PagedKVCache(*pools[:2], lengths, *pools[2:]), new_state, nxt, hmon
-
-
-def _spec_decode_step(params, cache: PagedKVCache, table, state: _SlotState,
-                      drafts, draft_len, *, cfg: LlamaConfig,
-                      decode_impl: str, kv_block: int, max_top_k: int,
-                      draft_k: int, monitors: bool = False,
-                      quant_kv: str = "", quant_weights: bool = False):
-    """The speculative verify step: feed every row G = draft_k + 1 tokens
-    (its last sampled token + its k drafts, short drafts padded), write
-    their K/V at positions pos..pos+k, attend all G query positions in
-    ONE widened forward (ops/decode_attention.py's multi-query form),
-    then run the rejection rule (serve/spec.py) so the emitted prefix is
-    draw-for-draw what the 1-wide step would have sampled. Rollback is
-    free: ``lengths`` advance by exactly the emitted count, so rejected
-    positions' K/V sit beyond every length mask and are overwritten by
-    later steps; padding positions past a row's draft length steer to the
-    scratch block and never touch real storage at all.
-
-    Quantization (``quant_kv``/``quant_weights``) rides exactly as in
-    :func:`_decode_step`. Rejected draft positions' amaxes stay folded
-    into their blocks' running scales — scales only ever grow, so a
-    rollback never leaves a block whose payload overflows its scale."""
-    qmax = kv_quant_spec(quant_kv)[1] if quant_kv else 0.0
-    S = state.last_tok.shape[0]
-    G = draft_k + 1
-    hd, H, Hkv = cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
-    # fed tokens: [last_tok, d_1 .. d_k] — token j conditions position
-    # pos + j and its logits score the candidate at pos + j + 1
-    tokens_in = jnp.concatenate([state.last_tok[:, None], drafts], axis=1)
-    x = params["tok_emb"][tokens_in]                       # [S, G, D]
-    pos0 = cache.lengths                                   # [S]
-    goff = jnp.arange(G, dtype=jnp.int32)
-    pos = pos0[:, None] + goff[None, :]                    # [S, G]
-    ang = pos.astype(jnp.float32)[..., None] * rope_freqs(cfg)[None, None, :]
-    cos = jnp.cos(ang)[:, :, None, :]                      # [S, G, 1, half]
-    sin = jnp.sin(ang)[:, :, None, :]
-
-    def rope(t):  # [S, G, H', hd], per-position angle
-        t1, t2 = jnp.split(t.astype(jnp.float32), 2, axis=-1)
-        return jnp.concatenate(
-            [t1 * cos - t2 * sin, t2 * cos + t1 * sin], axis=-1
-        ).astype(t.dtype)
-
-    # paged write targets: position g of row s lands in physical block
-    # table[s, (pos0+g) // block] at offset (pos0+g) % block; dead rows
-    # and padding positions past the row's draft length steer to scratch
-    bi = pos // kv_block
-    off = pos % kv_block
-    write_ok = state.live[:, None] & (goff[None, :] <= draft_len[:, None])
-    M = table.shape[1]
-    pid = jnp.where(
-        write_ok,
-        jnp.take_along_axis(table, jnp.minimum(bi, M - 1), axis=1),
-        SCRATCH_BLOCK,
-    )
-    off = jnp.where(write_ok, off, 0)
-
-    def block(x, lp, pools, base):
-        h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-        mm = partial(_q_mm, quant_weights=quant_weights, impl=decode_impl)
-        q = rope(mm(h, lp, "wq").reshape(S, G, H, hd))
-        k_new = rope(mm(h, lp, "wk").reshape(S, G, Hkv, hd))
-        v_new = mm(h, lp, "wv").reshape(S, G, Hkv, hd)
-        pools = _write_kv(pools, k_new, v_new, pid + base, off, qmax)
-        k_pool, v_pool, k_sc, v_sc = pools
-        # multi-query paged attention: query g of row s sees positions
-        # < pos0[s] + g + 1 (lengths arg = pos0 + G, kernel offsets per g)
-        attn = decode_attention(
-            q, k_pool, v_pool, pos0 + G, tables=table + base,
-            block=kv_block, k_scale=k_sc, v_scale=v_sc,
-        )
-        x = x + mm(attn.reshape(S, G, H * hd), lp, "wo")
-        h2 = rms_norm(x, lp["ffn_norm"], cfg.norm_eps)
-        delta = mm(jax.nn.silu(mm(h2, lp, "w1")) * mm(h2, lp, "w3"),
-                   lp, "w2")
-        return x + delta, pools
-
-    x, pools = scan_layers_paged(block, x, params["layers"], cache)
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    if quant_weights:
-        logits = quant_matmul(
-            x, params["lm_head_q"], params["lm_head_s"], impl=decode_impl
-        ).astype(jnp.float32)                              # [S, G, V]
-    else:
-        logits = (x @ params["lm_head"]).astype(jnp.float32)   # [S, G, V]
-
-    toks, n_emit, _n_acc, last_tok, new_rng, done = verify_and_accept(
-        logits, drafts, draft_len, state, max_top_k=max_top_k,
-    )
-    live = state.live
-    lengths = pos0 + n_emit * live.astype(jnp.int32)
-    new_state = state._replace(
-        last_tok=jnp.where(live, last_tok, state.last_tok),
-        rng=jnp.where(live[:, None], new_rng, state.rng),
-        done=jnp.where(live, done, state.done),
-    )
-    if monitors:
-        # health rules judge the step by the LAST emitted position's
-        # logits — the same autoregressive frontier the 1-wide step
-        # reports, so accepted drafts can't trip entropy/nonfinite rules
-        last_idx = jnp.maximum(n_emit - 1, 0)
-        frontier = jnp.take_along_axis(
-            logits, last_idx[:, None, None], axis=1
-        )[:, 0]
-        hmon = health.decode_monitors(frontier)
-    else:
-        hmon = {}
-    return (
-        PagedKVCache(*pools[:2], lengths, *pools[2:]),
-        new_state, toks, n_emit, hmon,
-    )
-
-
-@functools.lru_cache(maxsize=512)
-def _spec_decode_fn(cfg: LlamaConfig, decode_impl: str, kv_block: int,
-                    max_top_k: int, draft_k: int, monitors: bool = False,
-                    quant_kv: str = "", quant_weights: bool = False):
-    """Jitted speculative verify step — same cache discipline as
-    :func:`_decode_fn` (per model/kernel knobs; cache and state donated,
-    pools carried through the layer scan and written in place; table not
-    donated)."""
-    def serve_spec_decode(params, cache, table, state, drafts, draft_len):
-        return _spec_decode_step(
-            params, cache, table, state, drafts, draft_len, cfg=cfg,
-            decode_impl=decode_impl, kv_block=kv_block, max_top_k=max_top_k,
-            draft_k=draft_k, monitors=monitors, quant_kv=quant_kv,
-            quant_weights=quant_weights,
-        )
-
-    return jax.jit(serve_spec_decode, donate_argnums=(1, 3))
-
-
-def _aot_spec_decode(cfg: LlamaConfig, decode_impl: str, kv_block: int,
-                     max_top_k: int, draft_k: int, params, cache, table,
-                     state, ledger, *, monitors: bool = False,
-                     quant_kv: str = "", quant_weights: bool = False):
-    fn = _spec_decode_fn(cfg, decode_impl, kv_block, max_top_k, draft_k,
-                         monitors, quant_kv, quant_weights)
-    S = state.last_tok.shape[0]
-    try:
-        shard = jax.tree.leaves(params)[0].sharding
-        key = ("spec", cfg, decode_impl, kv_block, max_top_k, draft_k,
-               monitors, quant_kv, quant_weights,
-               cache.k.shape, str(cache.k.dtype), table.shape,
-               hash(shard), shard)
-    except (AttributeError, TypeError):  # params are not jax arrays
-        return fn
-    name = (f"serve.decode_spec[slots={S},blocks={cache.k.shape[1]},"
-            f"attended={table.shape[1]},k={draft_k}]")
-    avals = (
-        params, cache, table, state,
-        _sds((S, draft_k), jnp.int32), _sds((S,), jnp.int32),
-    )
-    return _aot_compile(
-        fn, avals, key, name, ledger, cache=_aot_decode_cache,
-    )
-
-
 __all__ = [
     "AdmissionRejected", "Completion", "Engine", "Request", "ServeConfig",
+    "steps_for",
 ]

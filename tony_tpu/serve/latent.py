@@ -1,13 +1,14 @@
 """The serving steps of the latent-attention expert decoder
 (models/latent_moe.py): what ``serve/engine.py``'s ``jit_serve_prefill``,
 ``jit_serve_tail_prefill`` and ``jit_serve_decode`` run when the engine's
-model is a :class:`LatentMoEConfig`. Same signatures, same host loop, same
-block pool and tables as the dense decoder's steps in engine.py; what
+model is a :class:`LatentMoEConfig`. Same names, same signatures, same host
+loop, same block pool and tables as the dense decoder's steps in
+serve/dense.py (docs/SERVE.md "Model families" has the contract); what
 differs is the state attention keeps:
 
-- the cache is ONE pool ``[L, P, 1, block, cache_width]`` (serve/cache.py
-  ``pool_layout``; the latent row zero-padded to the chip's lanes): prefill
-  hands back the prompt's latent rows and the
+- the cache is ONE pool ``[L, P, 1, block, cache_width]`` (the
+  configuration's ``cache_layout``; the latent row zero-padded to the
+  chip's lanes): prefill hands back the prompt's latent rows and the
   engine scatters them into the slot's blocks; the ``v`` half of every
   (k, v) pair in the engine's plumbing is None;
 - prefill attends EXPANDED (``wkv_b`` applied to the context's latents,
@@ -17,9 +18,9 @@ differs is the state attention keeps:
 - the pool rides the layer scan of BOTH stacks (leading dense layers, then
   expert layers) as its carry and is written in place, exactly as
   ``scan_layers_paged`` does for the dense decoder;
-- every program also returns what its expert layers routed here
-  (``moe_routes [n_moe_layers, n_local]``, ``moe_tokens``), which the engine
-  fetches with the sampled tokens — no sync of its own.
+- every step's last result (``aux``) holds what its expert layers routed
+  here (``moe_routes [n_moe_layers, n_local]``, ``moe_tokens``), which the
+  engine fetches with the sampled tokens — no sync of its own.
 """
 
 from __future__ import annotations
@@ -30,8 +31,8 @@ from jax import lax
 
 from tony_tpu.models.generate import sample_tokens
 from tony_tpu.models.latent_moe import (
-    LatentMoEConfig, absorb, forward_latents, layer, rope_cos_sin,
-    softmax_scale, split_experts,
+    LatentMoEConfig, absorb, forward_latents, init_params, layer,
+    rope_cos_sin, softmax_scale, split_experts,
 )
 from tony_tpu.models.llama import rms_norm
 from tony_tpu.obs import health
@@ -40,12 +41,16 @@ from tony_tpu.serve.cache import (
     SCRATCH_BLOCK, PagedKVCache, scan_layers_paged, scatter_block_kv,
 )
 
-# ServeConfig knobs this family does not take yet, each with the reason the
-# engine gives when it refuses one (Engine.__init__)
+# What this family does not take yet: ``{knob: (the one value it takes, why)}``.
+# A ServeConfig field set otherwise is refused by name at Engine.__init__ and
+# never reaches a step; ``block_handoff`` is not a field — the engine refuses
+# ``export_prefix_blocks`` / ``adopt_blocks`` when called.
 REFUSED_KNOBS = {
-    "quant_kv": "the latent pool has no block-scaled quantized form",
-    "quant_weights": "the int8 decode matmuls name the dense decoder's seven matrices",
-    "spec": "the absorbed decode attends one query position per slot",
+    "quant_kv": ("", "the latent pool has no block-scaled quantized form"),
+    "quant_weights": (False, "the int8 decode matmuls name the dense decoder's seven matrices"),
+    "spec": (False, "the absorbed decode attends one query position per slot"),
+    "decode_impl": ("scan", "the absorbed latent decode has a scan form only"),
+    "block_handoff": (False, "gang block export/adopt ships (k, v) pools in a BlockPayload"),
 }
 
 
@@ -100,11 +105,11 @@ def tail_prefill_step(params, ctx_k, ctx_v, tail, start, last_index, temp,
 def decode_step(params, cache: PagedKVCache, table, state, *,
                 cfg: LatentMoEConfig, kv_block: int, max_top_k: int,
                 monitors: bool = False):
-    """One token for every slot (engine.py ``_decode_step``'s contract): the
-    latent row of each live slot is written in place at its position — dead
-    slots steer to the layer's scratch block — then attended absorbed
-    through the table. The last result carries the step's expert routes
-    beside the health monitors."""
+    """One token for every slot (serve/dense.py ``decode_step``'s contract
+    without drafts): the latent row of each live slot is written in place
+    at its position — dead slots steer to the layer's scratch block — then
+    attended absorbed through the table. The last result carries the
+    step's expert routes beside the health monitors."""
     S = state.last_tok.shape[0]
     H, kr, vd = cfg.n_heads, cfg.kv_lora_rank, cfg.v_head_dim
     x = params["tok_emb"][state.last_tok]                      # [S, D]
@@ -164,4 +169,7 @@ def decode_step(params, cache: PagedKVCache, table, state, *,
     return PagedKVCache(cache.k, None, cache.lengths + live), new_state, nxt, aux
 
 
-__all__ = ["REFUSED_KNOBS", "decode_step", "prefill_step", "tail_prefill_step"]
+__all__ = [
+    "REFUSED_KNOBS", "decode_step", "init_params", "prefill_step",
+    "tail_prefill_step",
+]

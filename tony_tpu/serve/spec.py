@@ -40,8 +40,8 @@ Rollback is free by construction: the verify step writes position
 ``pos + j``'s K/V from fed token j of ``[last_tok, d_1..d_k]``, and the
 accepted prefix covers exactly the positions the advanced ``lengths``
 expose — rejected positions' K/V lie beyond every row's length, masked
-out of attention, and overwritten by later steps (serve/engine.py
-``_spec_decode_step``).
+out of attention, and overwritten by later steps (serve/dense.py
+``decode_step`` with drafts).
 """
 
 from __future__ import annotations
