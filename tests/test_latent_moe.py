@@ -450,3 +450,47 @@ def test_param_count_matches_the_tree(model):
     for a, ax in zip(jax.tree.leaves(params),
                      jax.tree.leaves(axes, is_leaf=lambda x: isinstance(x, tuple))):
         assert a.ndim == len(ax)
+
+
+@pytest.mark.parametrize("lead", [(5,), (5, 3), (2, 5), "vmap"],
+                         ids=["S", "S-G", "B-S", "vmap"])
+def test_attention_inputs_keep_the_plain_formula_bitwise(model, lead):
+    """``attention_inputs`` holds ``cq @ wq_b`` apart from the reshape and
+    the nope/rope split (an optimization barrier, for the chip's compiler:
+    PERF.md §6 PR 30); in float32 its three results are bit for bit the
+    formula written out here for any leading axes, jitted, and under
+    ``jax.vmap``."""
+    cfg, params = model
+    lp = jax.tree.map(lambda a: a[0], params["dense_layers"])
+    H, nope, kr = cfg.n_heads, cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    shape = (4, 5) if lead == "vmap" else lead
+    kx, ka = jax.random.split(jax.random.key(5))
+    h = jax.random.normal(kx, (*shape, cfg.dim), jnp.float32)
+    ang = jax.random.uniform(ka, (*shape, cfg.qk_rope_head_dim // 2), jnp.float32, 0.0, 6.0)
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+
+    def norm(x, w):
+        return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + cfg.norm_eps) * w
+
+    def rope(t, cos, sin):
+        half = t.shape[-1] // 2
+        t1, t2 = t[..., :half], t[..., half:]
+        return jnp.concatenate([t1 * cos - t2 * sin, t2 * cos + t1 * sin], axis=-1)
+
+    def changed(h, cos, sin):
+        return lm.attention_inputs(h, lp, cfg, cos, sin)
+
+    def plain(h, cos, sin):
+        cq = norm(h @ lp["wq_a"], lp["q_norm"])
+        q = (cq @ lp["wq_b"]).reshape(*h.shape[:-1], H, cfg.qk_head_dim)
+        kv = h @ lp["wkv_a"]
+        latent = jnp.concatenate(
+            [norm(kv[..., :kr], lp["kv_norm"]), rope(kv[..., kr:], cos, sin)], axis=-1)
+        return q[..., :nope], rope(q[..., nope:], cos[..., None, :], sin[..., None, :]), latent
+
+    fn = jax.vmap(changed) if lead == "vmap" else changed
+    got = jax.jit(fn)(h, cos, sin)
+    want = jax.jit(plain)(h, cos, sin)
+    for g, w in zip(got, want):
+        assert g.shape == w.shape
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))

@@ -598,3 +598,60 @@ def test_a_model_family_keeps_the_steps_contract(family):
                 Engine(real, cfg, ServeConfig(**base)).export_prefix_blocks([1] * blk)
             else:
                 Engine(real, cfg, ServeConfig(**base, **{knob: other}))
+
+
+# --- the layer's projections stay the plain formula ---------------------------
+
+
+def _plain_rope(t, cos, sin):
+    half = t.shape[-1] // 2
+    t1, t2 = t[..., :half], t[..., half:]
+    return jnp.concatenate([t1 * cos - t2 * sin, t2 * cos + t1 * sin], axis=-1)
+
+
+def _plain_norm(x, w, eps):
+    return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * w
+
+
+@pytest.mark.parametrize("lead", [(5,), (5, 3), (2, 5), "vmap"],
+                         ids=["S", "S-G", "B-S", "vmap"])
+def test_layer_keeps_the_plain_formula_bitwise(setup, lead):
+    """``generate.layer`` holds its q and k products apart from the reshape
+    and rope (an optimization barrier, for the chip's compiler: PERF.md §6
+    PR 30); in float32 the queries, the keys and the layer's result are
+    bit for bit the formula written out here — product, reshape to heads,
+    rope — for one row a slot ``[S]``, drafted positions ``[S, G]`` and a
+    prompt ``[B, S]``, jitted, and under ``jax.vmap``."""
+    from tony_tpu.models.generate import layer
+
+    cfg, params = setup
+    lp = jax.tree.map(lambda a: a[0], params["layers"])
+    H, Hkv, hd, rep = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, cfg.n_heads // cfg.n_kv_heads
+    shape = (4, 5) if lead == "vmap" else lead
+    kx, ka = jax.random.split(jax.random.key(3))
+    x = jax.random.normal(kx, (*shape, cfg.dim), jnp.float32)
+    ang = jax.random.uniform(ka, (*shape, 1, hd // 2), jnp.float32, 0.0, 6.0)
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+
+    def attend(q, k, v):
+        return q * jnp.repeat(k + v, rep, axis=-2), (q, k)
+
+    def changed(x, cos, sin):
+        return layer(x, lp, cfg, attend, lambda t: _plain_rope(t, cos, sin))
+
+    def plain(x, cos, sin):
+        rows = x.shape[:-1]
+        h = _plain_norm(x, lp["attn_norm"], cfg.norm_eps)
+        q = _plain_rope((h @ lp["wq"]).reshape(*rows, H, hd), cos, sin)
+        k = _plain_rope((h @ lp["wk"]).reshape(*rows, Hkv, hd), cos, sin)
+        v = (h @ lp["wv"]).reshape(*rows, Hkv, hd)
+        y = x + attend(q, k, v)[0].reshape(*rows, H * hd) @ lp["wo"]
+        h2 = _plain_norm(y, lp["ffn_norm"], cfg.norm_eps)
+        return y + (jax.nn.silu(h2 @ lp["w1"]) * (h2 @ lp["w3"])) @ lp["w2"], (q, k)
+
+    fn = jax.vmap(changed) if lead == "vmap" else changed
+    got = jax.jit(fn)(x, cos, sin)
+    want = jax.jit(plain)(x, cos, sin)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert g.shape == w.shape
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))

@@ -15,6 +15,7 @@ another worker, whose fixture would then skip in silence.
 from __future__ import annotations
 
 import os
+import re
 from dataclasses import replace
 from functools import partial
 
@@ -79,6 +80,12 @@ def _compile(fn, one_chip, *shapes):
 
 
 BF16, F32, I32, I8 = jnp.bfloat16, jnp.float32, jnp.int32, jnp.int8
+
+
+def _described(tree, one_chip):
+    """The tree's arrays (or shapes) as shapes placed on the described chip."""
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
 
 
 # --- flash attention (train path) ---------------------------------------------
@@ -339,10 +346,7 @@ def test_latent_decode_step_at_published_widths_keeps_the_pool_in_place(one_chip
     assert cfg.cache_width == (640 if lanes == 128 else 576)
     S, P = 48, 3136
 
-    def sds(tree):
-        return jax.tree.map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
-
+    sds = partial(_described, one_chip=one_chip)
     params = sds(jax.eval_shape(partial(init_params, cfg=cfg), jax.random.key(0)))
     pool = jax.ShapeDtypeStruct((3, P, 1, 64, cfg.cache_width), BF16, sharding=one_chip)
     cache = PagedKVCache(pool, None, jax.ShapeDtypeStruct((S,), I32, sharding=one_chip))
@@ -361,3 +365,81 @@ def test_latent_decode_step_at_published_widths_keeps_the_pool_in_place(one_chip
         assert mem.alias_size_in_bytes >= pool_bytes
     else:
         assert relaid and mem.temp_size_in_bytes > pool_bytes
+
+
+# --- a layer's projection weights are read where they lie in the stack ---------
+
+
+def _materialised(hlo: str):
+    """``(opcode, result type, line)`` of every instruction of the compiled
+    text that runs on its own — the entry, loop bodies and branches, not
+    the computations a ``fusion`` calls (a ``dynamic-slice`` in there is the
+    fusion reading its operand in place)."""
+    fused = set(re.findall(r"fusion\(.*?calls=%([\w.\-]+)", hlo))
+    inst = re.compile(r"\s*(?:ROOT )?%[\w.\-]+ = (.+?) ([\w\-]+)\(")
+    here = None
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) \(.*\{$", line)
+        if head:
+            here = head.group(1)
+        m = inst.match(line)
+        if m and here not in fused:
+            yield m.group(2), m.group(1), line.strip()
+
+
+@pytest.mark.parametrize("case", ["dense-decode", "dense-prefill-512",
+                                  "latent-decode", "latent-prefill-512"])
+def test_projection_weights_are_read_in_place(one_chip, case):
+    """The serving programs at the two cells' widths (Yi-1.5-6B with 4
+    layers: 16 slots, pool 529, table 32; DeepSeek-V3 as in the test above:
+    48 slots, pool 3,136): no layer's query weight — nor the dense key
+    weight — is sliced out of its ``[L, ...]`` stack by a fusion of its own
+    or copied (the transposition ``constant_dynamic-slice_fusion.4`` +
+    ``copy.156 bf16[1,4096,4096]{1,2,0}`` that fusing ``h @ wq`` with the
+    reshape and rope's split cost on every layer of every step: PERF.md §6
+    PR 30; ``models/generate.layer`` and ``latent_moe.attention_inputs``
+    keep the product apart). A same-layout ``copy-start``/``copy-done`` of
+    the one leading dense layer's ``wq_b`` is a prefetch, not a relayout.
+    What is LEFT is pinned as present, so that a compiler or a PR which
+    removes it is noticed: ``wkv_b``, sliced and transposed a layer by the
+    absorbed decode and by the expanded prefill alike
+    (``bf16[1,512,32768]{1,2,0}``; PERF.md §7)."""
+    from tony_tpu.serve.cache import create_cache
+    from tony_tpu.serve.capacity import _state_avals
+    from tony_tpu.serve.engine import _decode_fn, _prefill_fn
+
+    family, program = case.split("-", 1)
+    if family == "dense":
+        from tony_tpu.models.llama import LlamaConfig, init_params
+
+        cfg = LlamaConfig(vocab_size=64000, dim=4096, n_layers=4, n_heads=32, n_kv_heads=4,
+                          ffn_dim=11008, max_seq_len=2048, rope_theta=5e6, norm_eps=1e-6,
+                          dtype=BF16)
+        S, P, M = 16, 529, 32
+        weights = ("bf16[1,4096,4096]", "bf16[1,4096,512]")
+    else:
+        from tony_tpu.models.latent_moe import LatentMoEConfig, init_params
+
+        cfg = LatentMoEConfig(vocab_size=16160, n_layers=3, n_dense_layers=1,
+                              n_local_experts=16, max_seq_len=4096)
+        S, P, M = 48, 3136, 64
+        weights = ("bf16[1,1536,24576]",)
+
+    sds = partial(_described, one_chip=one_chip)
+    params = sds(jax.eval_shape(partial(init_params, cfg=cfg), jax.random.key(0)))
+    if program == "decode":
+        cache = sds(jax.eval_shape(partial(create_cache, cfg, S, P, 64)))
+        lowered = _decode_fn(cfg, "scan", 64, 64).lower(
+            params, cache, sds(jax.ShapeDtypeStruct((S, M), I32)), sds(_state_avals(S)))
+    else:
+        scalars = [jax.ShapeDtypeStruct(s, d) for s, d in (
+            ((1, 512), I32), ((), I32), ((), F32), ((), I32), ((), F32), ((2,), jnp.uint32))]
+        lowered = _prefill_fn(cfg, 512, 64).lower(params, *sds(scalars))
+    ops = list(_materialised(lowered.compile().as_text()))
+    assert any(op == "while" for op, _, _ in ops)          # the layer scan was walked
+    relaid = [line for op, result, line in ops
+              if result.startswith(weights) and op in ("copy", "fusion")]
+    assert not relaid, relaid[:2]
+    left = [line for op, result, line in ops
+            if op == "copy" and result.startswith("bf16[1,512,32768]{1,2,0")]
+    assert bool(left) == (family == "latent"), left[:2]

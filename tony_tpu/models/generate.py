@@ -97,8 +97,13 @@ def layer(x, lp, cfg: LlamaConfig, attend, rope, mm=_matmul):
     is a separate spelling (ROADMAP D2)."""
     lead, hd = x.shape[:-1], cfg.head_dim
     h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-    q = rope(mm(h, lp, "wq").reshape(*lead, cfg.n_heads, hd))
-    k = rope(mm(h, lp, "wk").reshape(*lead, cfg.n_kv_heads, hd))
+    # the products stay as stated: fused with the reshape and rope's split the
+    # chip's compiler wants the whole weight sliced out of its stack and
+    # transposed first (constant_dynamic-slice_fusion.4 + copy.156
+    # bf16[1,4096,4096]: PERF.md §6 PR 30, tests/test_tpu_compile.py)
+    q, k = lax.optimization_barrier((mm(h, lp, "wq"), mm(h, lp, "wk")))
+    q = rope(q.reshape(*lead, cfg.n_heads, hd))
+    k = rope(k.reshape(*lead, cfg.n_kv_heads, hd))
     v = mm(h, lp, "wv").reshape(*lead, cfg.n_kv_heads, hd)
     attn, state = attend(q, k, v)
     x = x + mm(attn.reshape(*lead, cfg.n_heads * hd), lp, "wo")

@@ -304,7 +304,11 @@ def attention_inputs(h: jax.Array, lp: Params, cfg: LatentMoEConfig,
     rope key. cos/sin ``[..., rope/2]`` at each row's position."""
     H, nope = cfg.n_heads, cfg.qk_nope_head_dim
     cq = rms_norm(h @ lp["wq_a"], lp["q_norm"], cfg.norm_eps)
-    q = (cq @ lp["wq_b"]).reshape(*h.shape[:-1], H, cfg.qk_head_dim)
+    # the product stays as stated, as in models/generate.layer: fused with the
+    # reshape and the nope/rope split it costs wq_b sliced out of its stack and
+    # transposed (constant_dynamic-slice_fusion.9 + copy.766 bf16[1,1536,24576])
+    q = lax.optimization_barrier(cq @ lp["wq_b"])
+    q = q.reshape(*h.shape[:-1], H, cfg.qk_head_dim)
     q_nope, q_rope = q[..., :nope], q[..., nope:]
     q_rope = rotate(q_rope, cos[..., None, :], sin[..., None, :])
     kv = h @ lp["wkv_a"]

@@ -1696,11 +1696,15 @@ def _decode_fn(cfg: LlamaConfig, decode_impl: str, kv_block: int,
     per layer per pool (``serve/cache.scan_layers_paged``,
     ``scatter_block_kv``); the compiled step's temporaries do
     not grow with the pool (tests/test_perf_guard.py) and on the chip it
-    holds no pool- or slab-shaped copy (PERF.md §5). A dead slot's write
-    lands in the scratch block of the layer being written (block
-    ``l * P`` of the flat view). The block table (arg 2) is NOT donated —
-    it is reused across steps — and names blocks of ONE layer; the step
-    adds the layer's offset itself."""
+    holds no pool- or slab-shaped copy (PERF.md §5), and no layer's query
+    (or dense key) weight sliced out of its ``[L, ...]`` stack and relaid:
+    the product reads it where it lies, as every other weight product of
+    the step does (``test_projection_weights_are_read_in_place`` in
+    tests/test_tpu_compile.py; what is left, ``wkv_b``, is pinned there).
+    A dead slot's write lands in the scratch block of the layer being
+    written (block ``l * P`` of the flat view). The block table (arg 2) is
+    NOT donated — it is reused across steps — and names blocks of ONE
+    layer; the step adds the layer's offset itself."""
     steps = steps_for(cfg)
     # a knob the family refuses is held at its off value by Engine.__init__
     # and never reaches the step
