@@ -135,8 +135,8 @@ class TestGL001:
         assert [os.path.basename(f.path) for f in fs] == ["fam_b.py"], fs
 
     def test_real_engine_decode_path_is_traced(self):
-        """The live tree's jitted hot paths are reachable: both model
-        families' three serving steps (chosen through
+        """The live tree's jitted hot paths are reachable: every model
+        family's three serving steps (chosen through
         ``serve.engine.steps_for`` — the module-returning idiom
         analysis/callgraph.py follows) and the decode step's transitive
         callees (the layer body, sampling, kernels) are in the traced
@@ -149,8 +149,12 @@ class TestGL001:
             "tony_tpu.serve.latent:prefill_step",
             "tony_tpu.serve.latent:tail_prefill_step",
             "tony_tpu.serve.latent:decode_step",
+            "tony_tpu.serve.shortconv:prefill_step",
+            "tony_tpu.serve.shortconv:tail_prefill_step",
+            "tony_tpu.serve.shortconv:decode_step",
             "tony_tpu.models.generate:layer",
             "tony_tpu.models.latent_moe:layer",
+            "tony_tpu.models.shortconv_moe:layer",
             "tony_tpu.serve.spec:verify_and_accept",
             "tony_tpu.models.generate:sample_tokens",
             "tony_tpu.ops.decode_attention:decode_attention",

@@ -536,25 +536,30 @@ def test_engine_shutdown_summary(setup, tmp_path, monkeypatch, caplog):
 # --- the seam between the engine and a model family's steps -------------------
 
 
-@pytest.mark.parametrize("family", ["dense", "latent"])
+@pytest.mark.parametrize("family", ["dense", "latent", "shortconv"])
 def test_a_model_family_keeps_the_steps_contract(family):
     """What ``Engine`` asks of a family (docs/SERVE.md "Model families"):
     ``steps_for`` maps the configuration's class to ONE module with the
     four names and ``REFUSED_KNOBS``; the configuration says what a cache
-    row is (``cache_layout``); each of the three programs lowers through
-    the engine's builder for either family and its LAST result is a dict
+    row is (``cache_layout``), how many layers keep one (``cache_layers``)
+    and what fixed-size state a slot keeps beside them (``slot_state``,
+    None for two of the three); each of the three programs lowers through
+    the engine's builder for every family and its LAST result is a dict
     (prefill: 5 results, the V rows None where the cache is one pool;
-    decode: 4); and the engine refuses every key of the table by name."""
+    decode: 4, the cache back with the structure it came with); and the
+    engine refuses every key of the table by name."""
     from dataclasses import fields
     from functools import partial
 
     from tony_tpu.models.latent_moe import LatentMoEConfig
-    from tony_tpu.serve import dense, engine, latent
+    from tony_tpu.models.shortconv_moe import ShortConvMoEConfig
+    from tony_tpu.serve import dense, engine, latent, shortconv
     from tony_tpu.serve.cache import create_cache
 
     cfg, steps = {
         "dense": (llama.LlamaConfig.tiny(), dense),
         "latent": (LatentMoEConfig.tiny(), latent),
+        "shortconv": (ShortConvMoEConfig.tiny(), shortconv),
     }[family]
     assert engine.steps_for(cfg) is steps
     for name in ("prefill_step", "tail_prefill_step", "decode_step", "init_params"):
@@ -571,15 +576,23 @@ def test_a_model_family_keeps_the_steps_contract(family):
     out = engine._prefill_fn(cfg, bucket, 8).lower(
         params, sds((1, bucket), jnp.int32), *sample).out_info
     assert len(out) == 5 and isinstance(out[-1], dict)
-    assert out[2].shape[0] == cfg.n_layers and (out[3] is None) == (pools == 1)
-    ctx = sds((cfg.n_layers, 1, 2 * bucket, heads, width), cfg.dtype)
+    assert out[2].shape[0] == cfg.cache_layers and (out[3] is None) == (pools == 1)
+    assert (cfg.slot_state is None) == (family != "shortconv")
+    assert ("slot_state" in out[-1]) == (cfg.slot_state is not None)
+    ctx = sds((cfg.cache_layers, 1, 2 * bucket, heads, width), cfg.dtype)
+    slot_state = None
+    if cfg.slot_state is not None:
+        layers, row, dtype = cfg.slot_state
+        slot_state = sds((layers, *row), dtype)
+        assert out[-1]["slot_state"].shape == slot_state.shape
     out = engine._tail_fn(cfg, bucket, 8).lower(
         params, ctx, ctx if pools == 2 else None, sds((1, bucket), jnp.int32),
-        sds((), jnp.int32), *sample).out_info
+        sds((), jnp.int32), *sample, slot_state).out_info
     assert len(out) == 5 and isinstance(out[-1], dict)
     assert (out[3] is None) == (pools == 1)
     cache = jax.eval_shape(partial(create_cache, cfg, S, P, blk))
     assert (cache.v is None) == (pools == 1)
+    assert (cache.slot_state is None) == (cfg.slot_state is None)
     state = engine._SlotState(
         sds((S,), jnp.int32), sds((S, 2), jnp.uint32), sds((S,), jnp.float32),
         sds((S,), jnp.int32), sds((S,), jnp.float32), sds((S,), jnp.int32),
@@ -591,13 +604,15 @@ def test_a_model_family_keeps_the_steps_contract(family):
 
     real = steps.init_params(jax.random.key(0), cfg)
     base = dict(slots=S, max_len=32, kv_block=blk)
+    if "prefix" in steps.REFUSED_KNOBS:     # the default ServeConfig is refused for it
+        base["prefix"] = False
     for knob, (takes, _why) in steps.REFUSED_KNOBS.items():
         other = {"quant_kv": "int8", "decode_impl": "pallas"}.get(knob, not takes)
         with pytest.raises(NotImplementedError, match=knob):
             if knob == "block_handoff":     # no field: refused where called
                 Engine(real, cfg, ServeConfig(**base)).export_prefix_blocks([1] * blk)
             else:
-                Engine(real, cfg, ServeConfig(**base, **{knob: other}))
+                Engine(real, cfg, ServeConfig(**{**base, knob: other}))
 
 
 # --- the layer's projections stay the plain formula ---------------------------

@@ -443,3 +443,54 @@ def test_projection_weights_are_read_in_place(one_chip, case):
     left = [line for op, result, line in ops
             if op == "copy" and result.startswith("bf16[1,512,32768]{1,2,0")]
     assert bool(left) == (family == "latent"), left[:2]
+
+
+# --- the short-convolution family's decode step keeps both kinds of state in place ----
+
+
+@pytest.mark.parametrize("lanes", [128, 1], ids=["rows-128", "rows-64"])
+def test_shortconv_decode_step_at_published_widths_keeps_pool_and_state_in_place(
+        one_chip, lanes, monkeypatch):
+    """The short-convolution / attention hybrid's decode step at the serving
+    cell's widths (the first 14 published layers: 11 convolution, 3 attention,
+    12 expert layers of 32; 64 slots, a pool of 2,049 blocks of 64, a table of
+    32): compiles for one chip with the paged kernel in it, and with two
+    64-wide K/V heads side by side in a 128-lane row the pool is one buffer —
+    no pool-shaped copy, temporaries far under a pool, pool AND per-slot
+    state aliased from the donated argument to the result. With rows of 64
+    the compiler moves the pool's block dimension minor-most and relays the
+    whole pool in and out, four pool-sized copies a step, which is why the
+    heads are packed (models/shortconv_moe.py ``kv_pack``; the unpacked case
+    is made here by patching the lanes); that case is pinned so that a
+    compiler which stops doing it is noticed."""
+    from tony_tpu.models import shortconv_moe as sm
+    from tony_tpu.serve.cache import create_cache, slot_state_bytes
+    from tony_tpu.serve.capacity import _state_avals
+    from tony_tpu.serve.engine import _decode_fn
+
+    monkeypatch.setattr(sm, "CACHE_LANES", lanes)
+    cfg = sm.ShortConvMoEConfig(layer_types=sm.PUBLISHED_LAYER_TYPES[:14], max_seq_len=2048)
+    assert cfg.cache_layout == ((4, 128, 2) if lanes == 128 else (8, 64, 2))
+    S, M = 64, 32
+    P = 1 + S * M
+    sds = partial(_described, one_chip=one_chip)
+    params = sds(jax.eval_shape(partial(sm.init_params, cfg=cfg), jax.random.key(0)))
+    cache = sds(jax.eval_shape(partial(create_cache, cfg, S, P, 64)))
+    assert cache.k.shape[0] == 3 and cache.slot_state.shape == (11, S, 4096)
+    lowered = _decode_fn(cfg, "scan", 64, 64).lower(
+        params, cache, sds(jax.ShapeDtypeStruct((S, M), I32)), sds(_state_avals(S)))
+    assert "paged_decode_attention" in lowered.as_text()
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    pool_bytes = 2 * 3 * P * 8 * 64 * 64 * 2
+    heads, width, _ = cfg.cache_layout
+    relaid = [l for l in compiled.as_text().splitlines()
+              if f"bf16[3,{P},{heads},64,{width}]" in l and " copy(" in l]
+    if lanes == 128:
+        assert not relaid, relaid[:2]
+        assert mem.temp_size_in_bytes < pool_bytes // 8, mem
+        assert mem.alias_size_in_bytes >= pool_bytes + slot_state_bytes(cfg, S)
+        # 4.667 B parameters beside the pool: the program fits the chip
+        assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
+    else:
+        assert relaid and mem.temp_size_in_bytes > pool_bytes

@@ -132,6 +132,17 @@ class LatentMoEConfig:
         return 1, self.cache_width, 1
 
     @property
+    def cache_layers(self) -> int:
+        """Layers the block pool holds rows for: every layer attends."""
+        return self.n_layers
+
+    @property
+    def slot_state(self) -> None:
+        """No fixed-size per-slot state beside the blocks (docs/SERVE.md
+        "What a new family must provide")."""
+        return None
+
+    @property
     def shared_ffn_dim(self) -> int:
         return self.n_shared_experts * self.moe_ffn_dim
 

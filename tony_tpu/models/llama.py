@@ -124,6 +124,17 @@ class LlamaConfig:
         return self.n_kv_heads, self.head_dim, 2
 
     @property
+    def cache_layers(self) -> int:
+        """Layers the block pool holds rows for: every layer attends."""
+        return self.n_layers
+
+    @property
+    def slot_state(self) -> None:
+        """No fixed-size per-slot state beside the blocks (docs/SERVE.md
+        "What a new family must provide")."""
+        return None
+
+    @property
     def n_params(self) -> int:
         """Exact parameter count (embeddings included, tied=False)."""
         d, h = self.dim, self.head_dim

@@ -167,6 +167,11 @@ class DecodeMetrics:
     # table names for all slots (what a gather of every entry reads)
     attn_blocks_live: int = 0
     attn_blocks_table: int = 0
+    # the second kind of state (a family's ``slot_state``, docs/SERVE.md):
+    # bytes resident for all slots, and prefill or chunk results written into
+    # a slot's (admissions + chunk boundaries); both 0 for a family without
+    slot_state_bytes: int = 0
+    state_handoffs: int = 0
 
     def record_prompt(self, plen: int, hit_tokens: int = 0) -> None:
         self.prompt_tokens += plen

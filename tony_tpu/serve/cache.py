@@ -93,13 +93,21 @@ class PagedKVCache(NamedTuple):
     ``[L, P, 1, block, cache_width]`` — per token and layer the compressed
     vector every head's keys and values are expanded from, then the rope
     key all heads share, then zeros up to the lanes — and ``v`` is None (an empty pytree
-    node: every program that takes the cache simply has no second pool)."""
+    node: every program that takes the cache simply has no second pool).
+
+    ``L`` is the configuration's ``cache_layers``: the layers that keep
+    rows per token (all of them, or a hybrid's attention layers only).
+    ``slot_state`` is the second kind of state a family may declare (its
+    configuration's ``slot_state``): ``[layers, S, *shape]``, a fixed-size
+    row per slot and layer that is not paged — None where the family
+    declares none, and then no program has it."""
 
     k: jax.Array
     v: jax.Array
     lengths: jax.Array
     k_scale: jax.Array | None = None
     v_scale: jax.Array | None = None
+    slot_state: jax.Array | None = None
 
     @property
     def n_blocks(self) -> int:
@@ -138,9 +146,11 @@ def map_scales(fn, cache: PagedKVCache):
 
 def map_cache(fn, cache: PagedKVCache) -> PagedKVCache:
     """``cache`` with ``fn`` applied to every pool and scale pool it holds
-    (all are ``[L, P, ...]``); the lengths as they are."""
+    (all are ``[L, P, ...]``); the lengths and the per-slot state as they
+    are."""
     return PagedKVCache(
-        *map_pools(fn, cache), cache.lengths, *map_scales(fn, cache)
+        *map_pools(fn, cache), cache.lengths, *map_scales(fn, cache),
+        cache.slot_state,
     )
 
 
@@ -152,20 +162,35 @@ def create_cache(
     out as the configuration's ``cache_layout`` says. With ``quant_kv``
     ('int8' | 'fp8_e4m3') the pools store the quantized dtype plus zeroed
     per-block-per-head scale pools (scale 0 = block holds nothing real
-    yet)."""
+    yet). A configuration that declares a per-slot state gets it zeroed,
+    ``[layers, slots, *shape]``."""
     heads, width, pools = cfg.cache_layout
     if quant_kv and pools == 1:
         raise NotImplementedError("quant_kv: a latent cache has no quantized form")
-    shape = (cfg.n_layers, n_blocks, heads, block, width)
+    shape = (cfg.cache_layers, n_blocks, heads, block, width)
     dt = kv_quant_spec(quant_kv)[0] if quant_kv else dtype or cfg.dtype
-    sc = (cfg.n_layers, n_blocks, heads)
+    sc = (cfg.cache_layers, n_blocks, heads)
+    state = None
+    if cfg.slot_state is not None:
+        layers, row, state_dtype = cfg.slot_state
+        state = jnp.zeros((layers, slots, *row), state_dtype)
     return PagedKVCache(
         jnp.zeros(shape, dt),
         jnp.zeros(shape, dt) if pools == 2 else None,
         jnp.zeros((slots,), jnp.int32),
         jnp.zeros(sc, jnp.float32) if quant_kv else None,
         jnp.zeros(sc, jnp.float32) if quant_kv else None,
+        state,
     )
+
+
+def slot_state_bytes(cfg, slots: int) -> int:
+    """HBM bytes of the per-slot state ``slots`` slots keep (0 where the
+    configuration declares none)."""
+    if cfg.slot_state is None:
+        return 0
+    layers, row, dtype = cfg.slot_state
+    return layers * slots * math.prod(row) * jnp.dtype(dtype).itemsize
 
 
 def grow_cache(cache: PagedKVCache, n_blocks: int) -> PagedKVCache:
@@ -568,18 +593,19 @@ def scan_layers_paged(layer_fn, x, layers, cache: PagedKVCache,
 
 
 def block_bytes(cfg, block: int, dtype=None, quant_kv: str = "") -> int:
-    """HBM bytes one physical block costs (K + V across all layers).
-    With ``quant_kv`` the payload is priced at the quantized dtype plus
-    the block's two scale rows (K and V, float32 per layer per head)."""
+    """HBM bytes one physical block costs (K + V across the layers the pool
+    holds, ``cfg.cache_layers``). With ``quant_kv`` the payload is priced at
+    the quantized dtype plus the block's two scale rows (K and V, float32
+    per layer per head)."""
     if quant_kv:
         qdt, _ = kv_quant_spec(quant_kv)
-        payload = (2 * cfg.n_layers * cfg.n_kv_heads * block * cfg.head_dim
+        payload = (2 * cfg.cache_layers * cfg.n_kv_heads * block * cfg.head_dim
                    * qdt.itemsize)
-        scales = 2 * cfg.n_layers * cfg.n_kv_heads * 4
+        scales = 2 * cfg.cache_layers * cfg.n_kv_heads * 4
         return payload + scales
     dt = jnp.dtype(dtype or cfg.dtype)
     heads, width, pools = cfg.cache_layout
-    return pools * cfg.n_layers * heads * block * width * dt.itemsize
+    return pools * cfg.cache_layers * heads * block * width * dt.itemsize
 
 
 class BlockPool:
@@ -694,7 +720,7 @@ __all__ = [
     "payload_compatible",
     "quant_scatter_span",
     "quantize_values",
-    "scan_layers_paged",
+    "scan_layers_paged", "slot_state_bytes",
     "scatter_block_kv",
     "shrink_cache",
     "unpack_payload",

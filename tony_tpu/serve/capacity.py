@@ -7,7 +7,8 @@ XLA's own numbers: the decode step is AOT-lowered from shape avals (no
 array is ever allocated) at two slot counts, and ``memory_analysis()``
 splits the footprint into
 
-- **param/argument bytes** — resident weights + cache + slot state,
+- **param/argument bytes** — resident weights + cache (the block pools
+  and a family's fixed-size per-slot state) + sampling state,
 - **fixed temp** — per-step scratch independent of the slot count,
 - **per-slot temp** — the marginal scratch one more slot costs (measured
   as the slot-count difference, so fused/fused-out buffers price
@@ -115,6 +116,8 @@ def decode_step_analysis(cfg: LlamaConfig, *, slots: int, capacity: int,
         "capacity": capacity,
         "param_bytes": _tree_bytes(params),
         "cache_bytes": _tree_bytes(pool_leaves),
+        # the second kind of state a family may declare (0 without one)
+        "slot_state_bytes": _tree_bytes([cache.slot_state]),
         "table_bytes": _tree_bytes([table]),
         "kv_bytes_per_slot": blocks * _bb(cfg, kv_block, quant_kv=quant_kv),
         **aot_analysis(compiled),
@@ -173,13 +176,17 @@ def derive_slot_budget(cfg: LlamaConfig, *, max_len: int,
     # per-slot KV bytes are exact from the block math (one slot's private
     # blocks; the shared scratch block sits in cache_bytes, not here)
     per_slot_kv = one["kv_bytes_per_slot"]
+    # a family's fixed-size per-slot state is one more marginal cost of a
+    # slot (0 without one, as for every family that takes quant_kv)
+    per_slot_state = one["slot_state_bytes"]
     # the hypothetical repeat-expanded layout keeps K/V at n_heads width —
     # the capacity the native-GQA decode kernel exists to avoid paying
     per_slot_kv_repeat = per_slot_kv * cfg.n_heads // cfg.n_kv_heads
     budget = hbm_bytes - param_bytes - fixed_temp - code
-    native = max(budget // (per_slot_kv + per_slot_temp), 0) if budget > 0 else 0
+    per_slot_rest = per_slot_temp + per_slot_state
+    native = max(budget // (per_slot_kv + per_slot_rest), 0) if budget > 0 else 0
     repeat = (
-        max(budget // (per_slot_kv_repeat + per_slot_temp), 0)
+        max(budget // (per_slot_kv_repeat + per_slot_rest), 0)
         if budget > 0 else 0
     )
     out = {
@@ -188,6 +195,7 @@ def derive_slot_budget(cfg: LlamaConfig, *, max_len: int,
         "fixed_temp_bytes": int(fixed_temp),
         "per_slot_temp_bytes": int(per_slot_temp),
         "generated_code_bytes": code,
+        "slot_state_bytes_per_slot": int(per_slot_state),
         "kv_bytes_per_slot_native": int(per_slot_kv),
         "kv_bytes_per_slot_repeat": int(per_slot_kv_repeat),
         "max_slots_native": int(native),
@@ -198,7 +206,7 @@ def derive_slot_budget(cfg: LlamaConfig, *, max_len: int,
         # shared-block accounting: the prefix's blocks exist once in the
         # pool (refcounted), each slot pays only its unshared tail
         shared_bytes, per_slot_private, slots_shared = _shared_budget(
-            per_slot_kv, per_slot_temp, budget,
+            per_slot_kv, per_slot_rest, budget,
             shared_prefix_tokens, max_len, kv_block,
         )
         out["shared_prefix_tokens"] = int(shared_prefix_tokens)

@@ -63,6 +63,7 @@ from jax import lax
 
 from tony_tpu.models.latent_moe import LatentMoEConfig
 from tony_tpu.models.llama import LlamaConfig, Params
+from tony_tpu.models.shortconv_moe import ShortConvMoEConfig
 from tony_tpu.obs import hbm, health, profile, series, slo, trace
 from tony_tpu.obs import compiles as compile_ledger
 from tony_tpu.obs.metrics import DecodeMetrics
@@ -70,11 +71,12 @@ from tony_tpu.obs.profiler import annotate
 from tony_tpu.obs.registry import HistogramWindow, Registry, snapshot_to_app_dir
 from tony_tpu.serve import dense as dense_steps
 from tony_tpu.serve import latent as latent_steps
+from tony_tpu.serve import shortconv as shortconv_steps
 from tony_tpu.serve.cache import (
     SCRATCH_BLOCK, BlockPayload, BlockPool, PagedKVCache, block_bytes,
     blocks_for, create_cache, dequantize_values, export_blocks, grow_cache,
     kv_quant_spec, map_cache, map_pools, map_scales, payload_compatible,
-    quant_scatter_span, shrink_cache, write_block,
+    quant_scatter_span, shrink_cache, slot_state_bytes, write_block,
 )
 from tony_tpu.serve.prefix import MatchResult, PrefixStore
 from tony_tpu.serve.spec import DRAFT_SOURCES, propose_drafts
@@ -275,10 +277,12 @@ class Engine:
     """
 
     def __init__(self, params: Params, cfg: LlamaConfig, serve: ServeConfig):
-        """``cfg`` is a :class:`LlamaConfig` (dense grouped-query decoder)
-        or a ``models.latent_moe.LatentMoEConfig`` (latent attention,
-        sigmoid group-limited experts): same loop, pool and tables; the
-        step bodies and the cache's rows are the family's
+        """``cfg`` is a :class:`LlamaConfig` (dense grouped-query decoder),
+        a ``models.latent_moe.LatentMoEConfig`` (latent attention, sigmoid
+        group-limited experts) or a ``models.shortconv_moe.
+        ShortConvMoEConfig`` (short convolutions beside attention layers,
+        sigmoid-routed experts): same loop, pool and tables; the step
+        bodies, the cache's rows and the per-slot state are the family's
         (:func:`steps_for`)."""
         self._steps = steps_for(cfg)
         for knob in self._steps.REFUSED_KNOBS:
@@ -339,6 +343,7 @@ class Engine:
         blk_bytes = block_bytes(cfg, B, quant_kv=self.serve.quant_kv)
         self._blk_bytes = blk_bytes
         self.metrics.kv_bytes_per_token = blk_bytes / B
+        self.metrics.slot_state_bytes = slot_state_bytes(cfg, S)
         budget_bytes = int(self.serve.prefix_budget_mb * 2**20)
         budget_blocks = (
             max(1, -(-budget_bytes // blk_bytes)) if budget_bytes
@@ -567,6 +572,11 @@ class Engine:
             # HBM per cached token (block bytes / block positions): the
             # quantized-serving capacity win, live (`tony top`'s kvB/t)
             "kv_bytes_per_token": round(self.metrics.kv_bytes_per_token, 2),
+            # the second kind of state (a family's ``slot_state``): bytes
+            # resident for all slots, and prefill or chunk results written
+            # into a slot's (admissions + chunk boundaries); 0 without one
+            "slot_state_bytes": float(self.metrics.slot_state_bytes),
+            "state_handoffs": float(self.metrics.state_handoffs),
             # pool label (disaggregated gangs): a string, so it rides the
             # series journal but the numeric AM metrics push drops it —
             # AM-rollup consumers derive the pool from the task type instead
@@ -702,6 +712,7 @@ class Engine:
             prefill_compiles=len(self._prefill_fns) + len(self._tail_fns),
             decode_compiles=len(self._decode_fns) + len(self._spec_fns),
             kv_bytes_per_token=self.metrics.kv_bytes_per_token,
+            slot_state_bytes=self.metrics.slot_state_bytes,
         )
         self._init_registry()
         # windowed-snapshot baselines re-base with the counters: a stale
@@ -908,6 +919,11 @@ class Engine:
                 self._c_prefix_hit.inc(matched)
         self.metrics.record_prompt(plen, matched)
         key = _as_raw_key(req.rng, rid)
+        if self.cache.slot_state is not None:
+            # the slot's fixed-size state starts from zero, whatever its
+            # last tenant left
+            self.cache = self.cache._replace(slot_state=_zero_slot_state_fn()(
+                self.cache.slot_state, jnp.int32(slot)))
         if chunked:
             # chunked prefill: plan every prompt block now, then advance
             # one chunk per engine step (docs/SERVE.md "Disaggregated
@@ -940,7 +956,7 @@ class Engine:
                         jnp.float32(req.temperature), jnp.int32(req.top_k),
                         jnp.float32(req.top_p), key,
                     )
-                self._scatter_prompt(slot, pk, pv, 0, plen)
+                self._scatter_prompt(slot, pk, pv, 0, plen, aux)
             else:
                 tok, carry, aux = self._tail_prefill(slot, prompt, matched, req, key)
             # EXPLICIT sync: the sampled first token steers admission on
@@ -1191,11 +1207,18 @@ class Engine:
         padded[:len(pids)] = pids
         self.cache = _zero_scales_fn()(self.cache, jnp.asarray(padded))
 
-    def _scatter_prompt(self, slot: int, pk, pv, start: int, plen: int) -> None:
+    def _scatter_prompt(self, slot: int, pk, pv, start: int, plen: int,
+                        aux: dict) -> None:
         """Write prefilled K/V (``[L, Hkv, W, hd]``, positions ``start +
         i``) into the slot's blocks; padded rows beyond ``plen`` steer to
         the scratch block. Quantized pools quantize the span in the same
-        fused step (per-touched-block running-scale update)."""
+        fused step (per-touched-block running-scale update). The handoff of
+        the second kind of state rides the same program: what the prefill
+        left under ``aux['slot_state']`` (a family that declares one)
+        becomes the slot's."""
+        handed = aux.get(_AUX_SLOT_STATE)
+        if handed is not None:
+            self.metrics.state_handoffs += 1
         B = self.serve.kv_block
         row = self._table[slot]
         W = pk.shape[2]
@@ -1219,7 +1242,7 @@ class Engine:
             return
         self.cache = _scatter_fn()(
             self.cache, pk, pv, jnp.asarray(pids), jnp.asarray(offs),
-            jnp.int32(slot), jnp.int32(plen),
+            jnp.int32(slot), jnp.int32(plen), handed,
         )
 
     def _tail_prefill(self, slot: int, prompt: np.ndarray, matched: int,
@@ -1262,16 +1285,22 @@ class Engine:
         ctx_k, ctx_v = _gather_fn(self.cache.quantized, self.cfg.dtype)(
             self.cache, jnp.asarray(gather)
         )
+        # the slot's fixed-size state as its predecessor left it (None, an
+        # empty argument, for a family without one)
+        state = None
+        if self.cache.slot_state is not None:
+            state = _slot_state_fn()(self.cache.slot_state, jnp.int32(slot))
         tail = np.zeros((1, tb), np.int32)
         tail[0, :tail_len] = prompt[matched:plen]
         with self._ledger.label(f"serve.prefill_tail[{tb},{C}]"):
-            tok, carry, tk, tv, aux = self._get_tail_prefill(tb, ctx_k, ctx_v)(
+            tok, carry, tk, tv, aux = self._get_tail_prefill(
+                tb, ctx_k, ctx_v, state)(
                 self.params, ctx_k, ctx_v, jnp.asarray(tail),
                 jnp.int32(matched), jnp.int32(tail_len - 1),
                 jnp.float32(req.temperature), jnp.int32(req.top_k),
-                jnp.float32(req.top_p), key,
+                jnp.float32(req.top_p), key, state,
             )
-        self._scatter_prompt(slot, tk, tv, matched, plen)
+        self._scatter_prompt(slot, tk, tv, matched, plen, aux)
         return tok, carry, aux
 
     def _register_prompt(self, slot: int, prompt: np.ndarray) -> None:
@@ -1425,12 +1454,12 @@ class Engine:
             )
         return self._prefill_fns[bucket]
 
-    def _get_tail_prefill(self, tb: int, ctx_k, ctx_v):
+    def _get_tail_prefill(self, tb: int, ctx_k, ctx_v, state):
         ctx = ctx_k.shape[2]
         if (tb, ctx) not in self._tail_fns:
             self._tail_fns[(tb, ctx)] = _aot_tail_prefill(
-                self.cfg, tb, ctx_k, ctx_v, self.serve.max_top_k, self.params,
-                self._ledger,
+                self.cfg, tb, ctx_k, ctx_v, state, self.serve.max_top_k,
+                self.params, self._ledger,
             )
             self.metrics.prefill_compiles = (
                 len(self._prefill_fns) + len(self._tail_fns)
@@ -1614,10 +1643,12 @@ def steps_for(cfg):
 
     Spelled as plain ``return <module>`` statements for graft-lint: its
     call graph follows a name bound to this function's result into every
-    module it can return (analysis/callgraph.py), which is what keeps both
-    families' steps inside the GL001 gate (tests/test_lint.py)."""
+    module it can return (analysis/callgraph.py), which is what keeps every
+    family's steps inside the GL001 gate (tests/test_lint.py)."""
     if isinstance(cfg, LatentMoEConfig):
         return latent_steps
+    if isinstance(cfg, ShortConvMoEConfig):
+        return shortconv_steps
     if isinstance(cfg, LlamaConfig):
         if cfg.is_moe:
             # forward_with_cache (the prefill path) has no expert FFN —
@@ -1641,8 +1672,10 @@ def _refuse(steps, knob: str, value) -> None:
 
 
 # the entries of a step's ``aux`` that Engine._fetch brings to the host with
-# the sampled tokens; whatever else rides there is a health monitor
+# the sampled tokens; whatever else rides there is a health monitor, but for
+# the per-slot state a prefill hands to the scatter (it stays on the device)
 _AUX_FETCHED = ("moe_routes", "moe_tokens")
+_AUX_SLOT_STATE = "slot_state"
 
 
 @functools.lru_cache(maxsize=512)
@@ -1670,10 +1703,13 @@ def _tail_fn(cfg: LlamaConfig, tb: int, max_top_k: int):
     steps = steps_for(cfg)
 
     def serve_tail_prefill(params, ctx_k, ctx_v, tail, start, last_index,
-                           temp, top_k, top_p, key):
+                           temp, top_k, top_p, key, slot_state=None):
+        # ``slot_state``: None for a family that declares none, and then
+        # neither an argument of the program nor of its step
+        state = {} if slot_state is None else {"slot_state": slot_state}
         return steps.tail_prefill_step(
             params, ctx_k, ctx_v, tail, start, last_index, temp, top_k,
-            top_p, key, cfg=cfg, tb=tb, max_top_k=max_top_k,
+            top_p, key, cfg=cfg, tb=tb, max_top_k=max_top_k, **state,
         )
 
     return jax.jit(serve_tail_prefill)
@@ -1806,11 +1842,12 @@ def _aot_prefill(cfg: LlamaConfig, bucket: int, max_top_k: int, params,
     return _aot_compile(fn, avals, key, f"serve.prefill[{bucket}]", ledger)
 
 
-def _aot_tail_prefill(cfg: LlamaConfig, tb: int, ctx_k, ctx_v,
+def _aot_tail_prefill(cfg: LlamaConfig, tb: int, ctx_k, ctx_v, slot_state,
                       max_top_k: int, params, ledger):
     """``ctx_k`` / ``ctx_v``: the gathered context the program will attend
-    (``ctx_v`` None where the cache is one pool); their shapes are the
-    program's."""
+    (``ctx_v`` None where the cache is one pool); ``slot_state``: the
+    slot's fixed-size state (None where the family declares none); their
+    shapes are the program's."""
     fn = _tail_fn(cfg, tb, max_top_k)
     ctx = ctx_k.shape[2]
     try:
@@ -1822,7 +1859,7 @@ def _aot_tail_prefill(cfg: LlamaConfig, tb: int, ctx_k, ctx_v,
         params, ctx_k, ctx_v, _sds((1, tb), jnp.int32),
         _sds((), jnp.int32),
         _sds((), jnp.int32), _sds((), jnp.float32), _sds((), jnp.int32),
-        _sds((), jnp.float32), _sds((2,), jnp.uint32),
+        _sds((), jnp.float32), _sds((2,), jnp.uint32), slot_state,
     )
     return _aot_compile(
         fn, avals, key, f"serve.prefill_tail[{tb},{ctx}]", ledger
@@ -1860,7 +1897,8 @@ def _scatter_fn(quant_kv: str = ""):
 
         return jax.jit(serve_scatter, donate_argnums=(0,))
 
-    def serve_scatter(cache: PagedKVCache, pk, pv, pids, offs, slot, plen):
+    def serve_scatter(cache: PagedKVCache, pk, pv, pids, offs, slot, plen,
+                      slot_state=None):
         # rows [L, Hkv, W, hd]; advanced indices (pids axis 1, offs axis
         # 3) are non-adjacent, so the indexed result moves to the front:
         # [W, L, Hkv, hd] — match it by transposing the span
@@ -1870,9 +1908,37 @@ def _scatter_fn(quant_kv: str = ""):
             cache, (pk, pv),
         )
         lengths = lax.dynamic_update_slice(cache.lengths, plen[None], (slot,))
-        return PagedKVCache(k, v, lengths)
+        # the handoff of the second kind of state: ``slot_state [layers,
+        # *shape]`` becomes slot ``slot``'s row (None — no argument and no
+        # write — for a family that declares none)
+        state = cache.slot_state
+        if slot_state is not None:
+            state = lax.dynamic_update_slice_in_dim(
+                state, slot_state[:, None].astype(state.dtype), slot, axis=1)
+        return PagedKVCache(k, v, lengths, slot_state=state)
 
     return jax.jit(serve_scatter, donate_argnums=(0,))
+
+
+@functools.lru_cache(maxsize=1)
+def _zero_slot_state_fn():
+    """Jitted reset of one slot's fixed-size state (DONATED): what the
+    engine runs at admission for a family that declares one."""
+    def serve_zero_slot_state(state, slot):
+        return lax.dynamic_update_slice_in_dim(
+            state, jnp.zeros_like(state[:, :1]), slot, axis=1)
+
+    return jax.jit(serve_zero_slot_state, donate_argnums=(0,))
+
+
+@functools.lru_cache(maxsize=1)
+def _slot_state_fn():
+    """Jitted read of one slot's fixed-size state ``[layers, *shape]`` for
+    the tail prefill that continues from it."""
+    def serve_slot_state(state, slot):
+        return lax.dynamic_index_in_dim(state, slot, axis=1, keepdims=False)
+
+    return jax.jit(serve_slot_state)
 
 
 @functools.lru_cache(maxsize=1)
