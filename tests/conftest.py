@@ -63,6 +63,32 @@ def paged_kernel(monkeypatch):
     drop_steps()
 
 
+@pytest.fixture(scope="session")
+def host_trace_events():
+    """``host_trace_events(log_dir)``: the profiler's host events of the ONE
+    trace under ``log_dir`` that tell what the serving engine did, as
+    ``(name, start_ns, end_ns)`` in start order: its ``serve.*`` phases
+    (obs/profiler.annotate) and every jitted call, ``PjitFunction(<name>)``
+    for a jitted function, ``PjitFunction(jit(<name>))`` for a compiled
+    executable — an eager op is a jitted call of its primitive's name here.
+    The profiler writes a call twice (the call and its fast path)."""
+    import glob
+
+    def read(log_dir):
+        (path,) = glob.glob(os.path.join(str(log_dir), "**", "*.xplane.pb"), recursive=True)
+        events = []
+        for plane in jax.profiler.ProfileData.from_file(path).planes:
+            if not plane.name.startswith("/host:"):
+                continue
+            for line in plane.lines:
+                events += [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+                           for e in line.events
+                           if e.name.startswith(("serve.", "PjitFunction("))]
+        return sorted(events, key=lambda e: e[1])
+
+    return read
+
+
 def pytest_terminal_summary(terminalreporter, exitstatus, config):
     """scripts/lint.py-style budget line: tier-1 runs close to its 870s
     timeout, so every run prints the top-10 slowest tests — future PRs see

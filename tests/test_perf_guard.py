@@ -232,6 +232,44 @@ def test_decode_step_keeps_the_pool_in_one_buffer(step):
     )
 
 
+def test_a_slot_transition_is_one_device_program(tmp_path, host_trace_events):
+    """Serving-host guard, by counts and not by wall time: a warmed engine
+    that serves a one-token request hands the slot over and takes it back
+    inside ONE ``serve.activate`` phase with exactly two programs, one
+    ``serve_activate`` and one ``serve_release``, and reads the device once
+    (the first token). Eleven eager ``.at[slot].set`` there were some forty
+    dispatches with the chip idle (PERF.md §6, PR 32); a field updated on
+    its own again shows here as a third program."""
+    from benchmark import tracing
+    from tony_tpu.models import llama
+    from tony_tpu.serve import Engine, Request, ServeConfig
+
+    cfg = LlamaConfig.tiny()
+    params = llama.init_params(jax.random.key(0), cfg)
+    eng = Engine(params, cfg, ServeConfig(slots=2, max_len=32, kv_block=8))
+    prompt = np.arange(5, dtype=np.int32)
+
+    def serve_one():
+        (comp,) = eng.run([Request(prompt=prompt, max_new_tokens=1)]).values()
+        return comp
+
+    serve_one(), serve_one()  # every program built, the prefix in the store
+    eng.reset_metrics()
+    tracing.start(str(tmp_path))
+    try:
+        comp = serve_one()
+    finally:
+        tracing.stop()
+    assert comp.finish_reason == "length" and len(comp.tokens) == 1
+    assert eng.metrics.slot_programs == 2
+    assert eng.metrics.device_fetches == 1 and eng.metrics.decode_steps == 0
+    events = host_trace_events(tmp_path)
+    ((_, lo, hi),) = [e for e in events if e[0] == "serve.activate"]
+    inside = sorted({name for name, start, _ in events
+                     if name.startswith("PjitFunction(") and lo <= start <= hi})
+    assert inside == ["PjitFunction(serve_activate)", "PjitFunction(serve_release)"]
+
+
 def test_disarmed_trace_span_is_within_noise_of_noop():
     """The trace spine's no-op contract: a span call on a DISARMED tracer
     is one global load + None compare returning a shared no-op object —

@@ -533,6 +533,208 @@ def test_engine_shutdown_summary(setup, tmp_path, monkeypatch, caplog):
             "tony_requests_finished_total"} <= names
 
 
+# --- the host touches the device once per event -------------------------------
+
+
+def _eager_activate(st, slot, tok, carry, temp, top_k, top_p, eos):
+    """The eight eager updates ``Engine._activate_slot`` made before one
+    program did: kept here as the plain reference."""
+    from tony_tpu.serve.engine import _SlotState
+
+    return _SlotState(
+        last_tok=st.last_tok.at[slot].set(tok),
+        rng=st.rng.at[slot].set(carry),
+        temp=st.temp.at[slot].set(temp),
+        top_k=st.top_k.at[slot].set(top_k),
+        top_p=st.top_p.at[slot].set(top_p),
+        eos=st.eos.at[slot].set(eos),
+        done=st.done.at[slot].set(False),
+        live=st.live.at[slot].set(True),
+    )
+
+
+def _eager_release(st, lengths, slot):
+    """The three eager updates of ``Engine._finish``."""
+    st = st._replace(live=st.live.at[slot].set(False),
+                     done=st.done.at[slot].set(False))
+    return st, lengths.at[slot].set(0)
+
+
+def _transition_requests(cfg, params, case):
+    """Five requests through three slots, so a transition always has live
+    neighbours; the case shapes the first two."""
+    prompts = _prompts(cfg, [4, 9, 6, 3, 11], seed=11)
+    reqs = [Request(prompt=p, max_new_tokens=m) for p, m in zip(prompts, [5, 3, 4, 6, 2])]
+    if case == "sampled":
+        reqs[0] = Request(prompt=prompts[0], max_new_tokens=5, temperature=0.8,
+                          top_k=7, rng=jax.random.key(3))
+        reqs[1] = Request(prompt=prompts[1], max_new_tokens=3, temperature=1.2,
+                          top_p=0.9, eos_id=5, rng=17)
+    elif case == "eos_first":
+        first = int(generate(params, jnp.asarray(prompts[0])[None], cfg,
+                             max_new_tokens=1)[0, -1])
+        reqs[0] = Request(prompt=prompts[0], max_new_tokens=5, eos_id=first)
+    elif case == "one_token":
+        reqs[1] = Request(prompt=prompts[1], max_new_tokens=1)
+    return reqs
+
+
+@pytest.mark.parametrize("case", ["greedy", "sampled", "eos_first", "one_token"])
+def test_slot_transitions_equal_the_eager_updates(setup, monkeypatch, case):
+    """Every ``serve_activate`` and ``serve_release`` of a run leaves the
+    slot state — all eight fields, ALL slots, and the cache's lengths —
+    bit for bit what the eleven eager ``.at[slot].set`` of the plain
+    reference above make of the state the program was given; there is one
+    program per activation and one per finish, and no other."""
+    from tony_tpu.serve import engine as engine_mod
+    from tony_tpu.serve.engine import _SlotState
+
+    cfg, params = setup
+    reqs = _transition_requests(cfg, params, case)  # generate() runs an engine too
+    real = {"activate": engine_mod._activate_fn(), "release": engine_mod._release_fn()}
+    calls = []
+
+    def recording(kind):
+        def call(*args):
+            # the arguments are donated: copy them to the host first
+            before = jax.tree.map(np.asarray, args)
+            out = real[kind](*args)
+            calls.append((kind, before, jax.tree.map(np.asarray, out)))
+            return out
+        return lambda: call
+
+    monkeypatch.setattr(engine_mod, "_activate_fn", recording("activate"))
+    monkeypatch.setattr(engine_mod, "_release_fn", recording("release"))
+    eng = Engine(params, cfg, ServeConfig(slots=3, max_len=32, kv_block=8))
+    got = eng.run(reqs)
+    kinds = [kind for kind, _, _ in calls]
+    assert len(got) == kinds.count("activate") == kinds.count("release") == len(reqs)
+    assert eng.metrics.slot_programs == len(calls)
+    for kind, before, out in calls:
+        before = jax.tree.map(jnp.asarray, before)
+        if kind == "activate":
+            want = _eager_activate(_SlotState(*before[0]), *before[1:])
+        else:
+            want = _eager_release(_SlotState(*before[0]), *before[1:])
+        want, out = jax.tree.leaves(want), jax.tree.leaves(out)
+        assert len(want) == len(out) == (8 if kind == "activate" else 9)
+        for w, o in zip(want, out):
+            assert w.dtype == o.dtype and w.shape == o.shape
+            np.testing.assert_array_equal(np.asarray(w), o)
+    if case == "eos_first":
+        assert got[0].finish_reason == "eos" and len(got[0].tokens) == 1
+    if case == "one_token":
+        assert got[1].finish_reason == "length" and len(got[1].tokens) == 1
+    # a drained engine: nobody live, nothing done, no length left behind
+    assert not np.asarray(eng.state.live).any() and not np.asarray(eng.state.done).any()
+    assert not np.asarray(eng.cache.lengths).any()
+
+
+_MIXED_ENGINES = {
+    "plain": dict(slots=2, max_len=48, kv_block=8),
+    "chunked": dict(slots=2, max_len=48, kv_block=8, chunk_tokens=8),
+    "spec": dict(slots=2, max_len=48, kv_block=8, spec=True, spec_max_draft=3),
+}
+
+
+def _mixed_requests(cfg, params):
+    """Greedy, sampled (top-k and top-p, a typed key each), an eos hit
+    on the first token, an eos hit later, ``max_new_tokens=1`` and a prompt
+    long enough to prefill in three chunks of 8: ``(request, solo)`` pairs,
+    ``solo`` what ``generate()`` gives that request alone."""
+    prompts = _prompts(cfg, [4, 9, 6, 20, 5, 7], seed=12)
+    key = jax.random.key(41)
+    row_key = jax.random.split(key, 1)[0]     # what generate() gives its one row
+
+    def solo(p, n, **kw):
+        out = generate(params, jnp.asarray(p)[None], cfg, max_new_tokens=n, **kw)
+        return list(np.asarray(out[0, len(p):]))
+
+    greedy = [solo(p, 6) for p in prompts]
+    sampled = dict(temperature=0.8, top_k=7)
+    nucleus = dict(temperature=1.2, top_p=0.9)
+    pairs = [
+        (Request(prompt=prompts[0], max_new_tokens=6), greedy[0]),
+        (Request(prompt=prompts[1], max_new_tokens=5, rng=row_key, **sampled),
+         solo(prompts[1], 5, rng=key, **sampled)),
+        (Request(prompt=prompts[2], max_new_tokens=6, eos_id=greedy[2][0]),
+         greedy[2][:1]),
+        (Request(prompt=prompts[3], max_new_tokens=6), greedy[3]),
+        (Request(prompt=prompts[4], max_new_tokens=1), greedy[4][:1]),
+        (Request(prompt=prompts[5], max_new_tokens=6, eos_id=greedy[5][3]),
+         greedy[5][:greedy[5].index(greedy[5][3]) + 1]),
+        (Request(prompt=prompts[0], max_new_tokens=4, rng=row_key, **nucleus),
+         solo(prompts[0], 4, rng=key, **nucleus)),
+    ]
+    return pairs, prompts
+
+
+@pytest.fixture(scope="module")
+def mixed(setup):
+    cfg, params = setup
+    return _mixed_requests(cfg, params)
+
+
+@pytest.mark.parametrize("kind", sorted(_MIXED_ENGINES))
+def test_mixed_run_serves_the_same_tokens(setup, mixed, kind):
+    """One program per slot transition and one fetch per step change no
+    token: a mixed run through a plain, a chunked-prefill and a speculative
+    engine returns, request by request, what ``generate()`` returns for
+    the request alone (the values the parity tests above hold), the greedy
+    ones also what the full forward's argmax chain gives (no engine in
+    that), with the finish reasons the requests ask for."""
+    cfg, params = setup
+    pairs, prompts = mixed
+    eng = Engine(params, cfg, ServeConfig(**_MIXED_ENGINES[kind]))
+    for _ in range(2):  # the second round drafts from what the first served
+        rids = [eng.submit(r) for r, _ in pairs]
+        got = eng.run()
+        for rid, (req, solo) in zip(rids, pairs):
+            assert got[rid].tokens == solo, (kind, rid)
+            assert got[rid].finish_reason == (
+                "eos" if req.eos_id is not None else "length"), (kind, rid)
+    assert (eng.metrics.draft_accepted > 0) == (kind == "spec")
+    # the first request's greedy chain by the plain full forward
+    seq = list(prompts[0])
+    for _ in range(6):
+        logits = llama.forward(params, jnp.asarray(seq, jnp.int32)[None], cfg)
+        seq.append(int(jnp.argmax(logits[0, -1])))
+    assert got[rids[0]].tokens == seq[len(prompts[0]):]
+
+
+@pytest.mark.parametrize("kind", sorted(_MIXED_ENGINES))
+def test_device_touches_are_counted_once_per_event(setup, mixed, kind):
+    """``device_fetches`` = decode steps + first tokens (a prefill's or a
+    final chunk's: the dense family's other chunks hand the host nothing),
+    ``slot_programs`` = activations + finishes, over the mixed run; both
+    are in ``stats_snapshot`` and start again with ``reset_metrics``."""
+    cfg, params = setup
+    pairs, _ = mixed
+    eng = Engine(params, cfg, ServeConfig(**_MIXED_ENGINES[kind]))
+    eng.run([r for r, _ in pairs])
+    m, snap = eng.metrics, eng.stats_snapshot()
+    assert m.requests_started == m.requests_finished == len(pairs)
+    assert m.decode_steps > 0
+    assert m.device_fetches == m.decode_steps + m.requests_started
+    assert m.slot_programs == m.requests_started + m.requests_finished
+    assert snap["device_fetches"] == m.device_fetches
+    assert snap["slot_programs"] == m.slot_programs
+    eng.reset_metrics()
+    assert eng.metrics.device_fetches == eng.metrics.slot_programs == 0
+
+
+@pytest.mark.parametrize("seed", [0, 7, 2**31, 2**40 + 9, -1])
+def test_a_seeds_raw_key_is_jax_random_keys(seed):
+    """An integer seed's raw key comes from one jitted program at admission
+    and is ``jax.random.key``'s own, past 32 bits and below zero too."""
+    from tony_tpu.serve.engine import _as_raw_key
+
+    want = jax.random.key_data(jax.random.key(seed))
+    for got in (_as_raw_key(seed, 3), _as_raw_key(None, seed)):
+        assert got.dtype == jnp.uint32 and got.shape == (2,)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
 # --- the seam between the engine and a model family's steps -------------------
 
 

@@ -10,9 +10,6 @@ them to, there is one ``serve.step`` per counted decode step, and the tokens
 are what an untraced engine serves.
 """
 
-import glob
-import os
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -55,7 +52,7 @@ def _serve(model, serve_cfg, sizes=SIZES):
 
 
 @pytest.fixture(scope="module")
-def traced(model, tmp_path_factory):
+def traced(model, tmp_path_factory, host_trace_events):
     """{"events": [(name, start_ns, end_ns)] of the ``serve.*`` host events,
     "programs": names of the jitted calls, "tokens", "chunk_tokens",
     "decode_steps"} of one plain and one chunked-prefill engine, both run
@@ -71,20 +68,16 @@ def traced(model, tmp_path_factory):
         ceng, chunk_tokens = _serve(model, chunked, [(20, 3)])
     finally:
         tracing.stop()
-    (path,) = glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"), recursive=True)
-    events, programs = [], set()
-    for plane in jax.profiler.ProfileData.from_file(path).planes:
-        if not plane.name.startswith("/host:"):
-            continue
-        for line in plane.lines:
-            for e in line.events:
-                if e.name.startswith("serve."):
-                    events.append((e.name, e.start_ns, e.start_ns + e.duration_ns))
-                elif e.name.startswith("PjitFunction("):
-                    programs.add(e.name)
-    return {"events": events, "programs": programs, "tokens": tokens,
-            "chunk_tokens": chunk_tokens,
+    found = host_trace_events(log_dir)
+    return {"events": [e for e in found if e[0].startswith("serve.")],
+            "programs": {e[0] for e in found if e[0].startswith("PjitFunction(")},
+            "tokens": tokens, "chunk_tokens": chunk_tokens,
             "decode_steps": eng.metrics.decode_steps + ceng.metrics.decode_steps}
+
+
+def _program(event_name):
+    """``serve_decode`` of ``PjitFunction(jit(serve_decode))``."""
+    return event_name[len("PjitFunction("):-1].removeprefix("jit(").rstrip(")")
 
 
 def _within(event, events, parent):
@@ -132,11 +125,56 @@ def test_one_step_annotation_for_each_counted_decode_step(traced):
 def test_traced_calls_carry_the_programs_names(traced):
     # a call through a compiled executable reads PjitFunction(jit(<name>)),
     # one through a jitted function PjitFunction(<name>)
-    called = {p[len("PjitFunction("):-1].removeprefix("jit(").rstrip(")")
-              for p in traced["programs"]}
+    called = {_program(p) for p in traced["programs"]}
     assert {"serve_decode", "serve_prefill", "serve_tail_prefill", "serve_gather",
-            "serve_scatter"} <= called, called
+            "serve_scatter", "serve_activate", "serve_release",
+            "serve_seed_key"} <= called, called
     assert not any("unknown" in p for p in called)
+
+
+WARMED = {
+    "plain": (dict(), SIZES),
+    # three of the prompts prefill in chunks, and slots churn between them
+    "chunked": (dict(chunk_tokens=8), [(20, 3), (18, 3), (5, 2), (20, 2)]),
+    "spec": (dict(spec=True, spec_max_draft=3), SIZES),
+}
+
+
+@pytest.mark.parametrize("kind", sorted(WARMED))
+def test_a_warmed_engine_runs_only_its_named_programs(model, tmp_path, host_trace_events,
+                                                      kind):
+    """Between the first ``serve.step`` and the last of a run on a WARMED
+    engine (the same requests served twice before: pool and table at their
+    sizes, prefixes in the store, so tail prefills, copy-on-write blocks and
+    drafts are in it) every program the host dispatches is a ``serve_*`` one:
+    no eager ``.at[slot].set`` (``scatter``, ``convert_element_type``,
+    ``broadcast_in_dim`` ... were eleven of them a request), no scalar put
+    that runs a conversion, no seed derivation op by op."""
+    cfg, params = model
+    knobs, sizes = WARMED[kind]
+    eng = Engine(params, cfg, ServeConfig(slots=2, max_len=32, kv_block=8,
+                                          shrink=False, **knobs))
+
+    def serve():
+        rids = [eng.submit(r) for r in _requests(cfg, sizes)]
+        done = eng.run()
+        return [done[rid].tokens for rid in rids]
+
+    first = serve()
+    assert serve() == first
+    tracing.start(str(tmp_path))
+    try:
+        assert serve() == first
+    finally:
+        tracing.stop()
+    events = host_trace_events(tmp_path)
+    steps = [e for e in events if e[0] == "serve.step"]
+    assert len(steps) > 2
+    lo, hi = steps[0][1], max(end for _, _, end in steps)
+    inside = [_program(name) for name, start, _ in events
+              if name.startswith("PjitFunction(") and lo <= start <= hi]
+    assert {"serve_activate", "serve_release"} <= set(inside)
+    assert [n for n in inside if not n.startswith("serve_")] == []
 
 
 def test_outputs_are_the_same_with_no_profiler_and_no_tracer(model, traced):
@@ -163,6 +201,9 @@ PROGRAMS = {
     "serve_copy_block": lambda cfg: engine_mod._copy_block_fn(),
     "serve_zero_scales": lambda cfg: engine_mod._zero_scales_fn(),
     "serve_gather": lambda cfg: engine_mod._gather_fn(),
+    "serve_activate": lambda cfg: engine_mod._activate_fn(),
+    "serve_release": lambda cfg: engine_mod._release_fn(),
+    "serve_seed_key": lambda cfg: engine_mod._seed_key_fn(),
 }
 
 

@@ -494,3 +494,31 @@ def test_shortconv_decode_step_at_published_widths_keeps_pool_and_state_in_place
         assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
     else:
         assert relaid and mem.temp_size_in_bytes > pool_bytes
+
+
+@pytest.mark.parametrize("program", ["serve_activate", "serve_release"])
+def test_slot_transition_programs_update_the_state_in_place(one_chip, program):
+    """The two programs the serving host runs at a slot's hand-over and
+    return (64 slots, the widest cell's): each compiles for one chip and
+    returns the donated slot state (and the cache's ``lengths``) in the
+    buffers it came in — aliased, every byte of them (the chip pads a
+    buffer to its tile, so the aliased bytes are at least the arrays')."""
+    from tony_tpu.serve.capacity import _state_avals
+    from tony_tpu.serve.engine import _activate_fn, _release_fn
+
+    S = 64
+    sds = partial(_described, one_chip=one_chip)
+    scalar = lambda dtype: sds(jax.ShapeDtypeStruct((), dtype))  # noqa: E731
+    state = sds(_state_avals(S))
+    donated = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(state))
+    if program == "serve_activate":
+        lowered = _activate_fn().lower(
+            state, scalar(I32), scalar(I32), sds(jax.ShapeDtypeStruct((2,), jnp.uint32)),
+            scalar(F32), scalar(I32), scalar(F32), scalar(I32))
+    else:
+        lowered = _release_fn().lower(
+            state, sds(jax.ShapeDtypeStruct((S,), I32)), scalar(I32))
+        donated += S * 4
+    assert f"module @jit_{program}" in lowered.as_text()
+    mem = lowered.compile().memory_analysis()
+    assert mem.alias_size_in_bytes >= donated, mem

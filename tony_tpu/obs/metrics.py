@@ -172,6 +172,12 @@ class DecodeMetrics:
     # a slot's (admissions + chunk boundaries); both 0 for a family without
     slot_state_bytes: int = 0
     state_handoffs: int = 0
+    # how often the host touched the device between model programs: reads
+    # (Engine._fetch: one a decode step, one a prefill's first token, one a
+    # chunk that counted routes) and slot transitions (one serve_activate an
+    # activation, one serve_release a finish)
+    device_fetches: int = 0
+    slot_programs: int = 0
 
     def record_prompt(self, plen: int, hit_tokens: int = 0) -> None:
         self.prompt_tokens += plen

@@ -228,7 +228,9 @@ def _as_raw_key(rng: Any, rid: int) -> jnp.ndarray:
     if rng is None:
         rng = rid
     if isinstance(rng, int):
-        return jax.random.key_data(jax.random.key(rng)).astype(jnp.uint32)
+        # the common case (a seed, or the request id) as ONE program at
+        # admission; the value is ``jax.random.key``'s own for every int
+        return _seed_key_fn()(np.int64(rng))
     arr = jnp.asarray(rng)
     if jnp.issubdtype(arr.dtype, jax.dtypes.prng_key):
         return jax.random.key_data(arr).astype(jnp.uint32)
@@ -577,6 +579,12 @@ class Engine:
             # into a slot's (admissions + chunk boundaries); 0 without one
             "slot_state_bytes": float(self.metrics.slot_state_bytes),
             "state_handoffs": float(self.metrics.state_handoffs),
+            # the host's touches of the device between model programs: a
+            # sound run reads device_fetches = decode steps + first tokens
+            # (+ chunks that counted routes), slot_programs = activations
+            # + finishes
+            "device_fetches": float(self.metrics.device_fetches),
+            "slot_programs": float(self.metrics.slot_programs),
             # pool label (disaggregated gangs): a string, so it rides the
             # series journal but the numeric AM metrics push drops it —
             # AM-rollup consumers derive the pool from the task type instead
@@ -923,7 +931,7 @@ class Engine:
             # the slot's fixed-size state starts from zero, whatever its
             # last tenant left
             self.cache = self.cache._replace(slot_state=_zero_slot_state_fn()(
-                self.cache.slot_state, jnp.int32(slot)))
+                self.cache.slot_state, np.int32(slot)))
         if chunked:
             # chunked prefill: plan every prompt block now, then advance
             # one chunk per engine step (docs/SERVE.md "Disaggregated
@@ -952,9 +960,9 @@ class Engine:
                 # anonymously
                 with self._ledger.label(f"serve.prefill[{bucket}]"):
                     tok, carry, pk, pv, aux = self._get_prefill(bucket)(
-                        self.params, jnp.asarray(padded), jnp.int32(plen - 1),
-                        jnp.float32(req.temperature), jnp.int32(req.top_k),
-                        jnp.float32(req.top_p), key,
+                        self.params, padded, np.int32(plen - 1),
+                        np.float32(req.temperature), np.int32(req.top_k),
+                        np.float32(req.top_p), key,
                     )
                 self._scatter_prompt(slot, pk, pv, 0, plen, aux)
             else:
@@ -996,14 +1004,22 @@ class Engine:
             )
 
     def _fetch(self, out, aux: dict, step: bool = True):
-        """A program's one sync, and the one reading of a step's ``aux``:
-        ``out`` (the sampled tokens ...) comes to the host and with it, in
-        the SAME ``device_get``, the expert routes a latent-attention step
-        counted (a second transfer cost ~2 ms a step on the chip). Returns
-        ``(out on the host, what is left of aux)`` — the health monitors,
-        which stay device references for the sentinel's worker thread."""
+        """A program's one sync, the ONLY place the step loop reads the
+        device (``metrics.device_fetches`` counts the calls: one a decode
+        step, one a prefill's first token, one a chunk that counted routes),
+        and the one reading of a step's ``aux``: ``out`` comes to the host —
+        a decode step's sampled tokens with its ``done`` flags and, on a
+        speculative step, ``n_emit`` — and with it, in the SAME
+        ``device_get``, the expert routes a latent-attention step counted.
+        Whatever the host needs of a program rides here: a second blocking
+        transfer, with nothing queued on the chip, cost ~2 ms a step for the
+        routes and 0.5 ms for the flags (PERF.md §6, PRs 27 and 32).
+        Returns ``(out on the host, what is left of aux)`` — the health
+        monitors, which stay device references for the sentinel's worker
+        thread."""
         ride = {k: aux[k] for k in _AUX_FETCHED if k in aux}
         out, ride = jax.device_get((out, ride))
+        self.metrics.device_fetches += 1
         if ride:
             self.metrics.record_moe(
                 ride["moe_routes"], ride["moe_tokens"], step=step)
@@ -1030,18 +1046,15 @@ class Engine:
             # draft context: prompt + every emitted token (input token
             # last) — what the host-side draft sources extend
             self._slot_ctx[slot] = [int(t) for t in prompt] + [tok]
-        st = self.state
         eos = -1 if req.eos_id is None else int(req.eos_id)
-        self.state = _SlotState(
-            last_tok=st.last_tok.at[slot].set(tok),
-            rng=st.rng.at[slot].set(carry),
-            temp=st.temp.at[slot].set(req.temperature),
-            top_k=st.top_k.at[slot].set(req.top_k),
-            top_p=st.top_p.at[slot].set(req.top_p),
-            eos=st.eos.at[slot].set(eos),
-            done=st.done.at[slot].set(False),
-            live=st.live.at[slot].set(True),
+        # the slot's eight fields in ONE program, the scalars as numpy
+        # arguments of that one call (no put, no scatter of its own a field)
+        self.state = _activate_fn()(
+            self.state, np.int32(slot), np.int32(tok), carry,
+            np.float32(req.temperature), np.int32(req.top_k),
+            np.float32(req.top_p), np.int32(eos),
         )
+        self.metrics.slot_programs += 1
         self._slot_rid[slot] = rid
         self._slot_remaining[slot] = req.max_new_tokens
         comp = Completion(
@@ -1091,14 +1104,11 @@ class Engine:
         self._slot_rid[slot] = None
         self._slot_remaining[slot] = 0
         self._slot_len[slot] = 0
-        st = self.state
-        self.state = st._replace(
-            live=st.live.at[slot].set(False),
-            done=st.done.at[slot].set(False),
-        )
-        self.cache = self.cache._replace(
-            lengths=self.cache.lengths.at[slot].set(0)
-        )
+        # ``lengths`` alone, not the cache: the pools are no argument of it
+        self.state, lengths = _release_fn()(
+            self.state, self.cache.lengths, np.int32(slot))
+        self.cache = self.cache._replace(lengths=lengths)
+        self.metrics.slot_programs += 1
         # a freed slot returns only the blocks whose refcount hits zero —
         # blocks the prefix store (or another slot's table) still
         # references stay resident
@@ -1168,7 +1178,7 @@ class Engine:
                     # later zeroing flush would erase it
                     self._fresh_scale.remove(dst)
                 self.cache = _copy_block_fn()(
-                    self.cache, jnp.int32(match.partial), jnp.int32(dst)
+                    self.cache, np.int32(match.partial), np.int32(dst)
                 )
                 row[next_bi] = dst
                 next_bi += 1
@@ -1205,7 +1215,7 @@ class Engine:
             n *= 2
         padded = np.full(n, SCRATCH_BLOCK, np.int32)
         padded[:len(pids)] = pids
-        self.cache = _zero_scales_fn()(self.cache, jnp.asarray(padded))
+        self.cache = _zero_scales_fn()(self.cache, padded)
 
     def _scatter_prompt(self, slot: int, pk, pv, start: int, plen: int,
                         aux: dict) -> None:
@@ -1236,13 +1246,13 @@ class Engine:
             uniq = np.unique(pids)
             ub[:len(uniq)] = uniq
             self.cache = _scatter_fn(self.serve.quant_kv)(
-                self.cache, pk, pv, jnp.asarray(pids), jnp.asarray(offs),
-                jnp.asarray(ub), jnp.int32(slot), jnp.int32(plen),
+                self.cache, pk, pv, pids, offs, ub, np.int32(slot),
+                np.int32(plen),
             )
             return
         self.cache = _scatter_fn()(
-            self.cache, pk, pv, jnp.asarray(pids), jnp.asarray(offs),
-            jnp.int32(slot), jnp.int32(plen), handed,
+            self.cache, pk, pv, pids, offs, np.int32(slot), np.int32(plen),
+            handed,
         )
 
     def _tail_prefill(self, slot: int, prompt: np.ndarray, matched: int,
@@ -1283,22 +1293,20 @@ class Engine:
         gather = np.full(nC, SCRATCH_BLOCK, np.int32)
         gather[:min(n_have, nC)] = row[:min(n_have, nC)]
         ctx_k, ctx_v = _gather_fn(self.cache.quantized, self.cfg.dtype)(
-            self.cache, jnp.asarray(gather)
-        )
+            self.cache, gather)
         # the slot's fixed-size state as its predecessor left it (None, an
         # empty argument, for a family without one)
         state = None
         if self.cache.slot_state is not None:
-            state = _slot_state_fn()(self.cache.slot_state, jnp.int32(slot))
+            state = _slot_state_fn()(self.cache.slot_state, np.int32(slot))
         tail = np.zeros((1, tb), np.int32)
         tail[0, :tail_len] = prompt[matched:plen]
         with self._ledger.label(f"serve.prefill_tail[{tb},{C}]"):
             tok, carry, tk, tv, aux = self._get_tail_prefill(
                 tb, ctx_k, ctx_v, state)(
-                self.params, ctx_k, ctx_v, jnp.asarray(tail),
-                jnp.int32(matched), jnp.int32(tail_len - 1),
-                jnp.float32(req.temperature), jnp.int32(req.top_k),
-                jnp.float32(req.top_p), key, state,
+                self.params, ctx_k, ctx_v, tail, np.int32(matched),
+                np.int32(tail_len - 1), np.float32(req.temperature),
+                np.int32(req.top_k), np.float32(req.top_p), key, state,
             )
         self._scatter_prompt(slot, tk, tv, matched, plen, aux)
         return tok, carry, aux
@@ -1565,8 +1573,7 @@ class Engine:
                     self.cache, self.state, toks, n_emit, aux = \
                         self._get_decode(sig, self.serve.spec_max_draft)(
                             self._dec_params, self.cache, self._table_dev,
-                            self.state, jnp.asarray(drafts_np),
-                            jnp.asarray(np.asarray(dlens, np.int32)),
+                            self.state, drafts_np, np.asarray(dlens, np.int32),
                         )
                 else:
                     # no live slot drafted: the plain 1-wide step (also the
@@ -1579,14 +1586,13 @@ class Engine:
                         )
             # EXPLICIT per-step sync: continuous batching needs the sampled
             # tokens + done flags on host to steer admission — this is the
-            # engine's one designed sync point per decode step
+            # engine's one designed sync point per decode step, and its one
+            # transfer: the flags (and a speculative step's emit counts)
+            # ride the tokens' fetch
             with annotate("serve.sync"):
-                toks_np, hmon = self._fetch(toks, aux)
-                toks_np = np.asarray(toks_np)
-                emit_np = (
-                    np.asarray(jax.device_get(n_emit)) if spec_step else None
-                )
-                done_np = jax.device_get(self.state.done)
+                (toks_np, emit_np, done_np), hmon = self._fetch(
+                    (toks, n_emit if spec_step else None, self.state.done),
+                    aux)
             dt = time.perf_counter() - t0
         with annotate("serve.emit"):
             if spec_step:
@@ -1724,7 +1730,9 @@ def _decode_fn(cfg: LlamaConfig, decode_impl: str, kv_block: int,
     all engines with the same model reuse every compiled signature. With
     ``draft_k`` it is the speculative verify step ``jit_serve_spec_decode``
     (two more arguments, ``n_emit`` among its results); both run the
-    family's one ``decode_step``.
+    family's one ``decode_step``. What the host needs of a step — the
+    tokens, the new state's ``done`` flags, ``n_emit``, the routes ``aux``
+    counts — it reads in the ONE ``device_get`` of ``Engine._fetch``.
 
     Contract: the cache (arg 1) and the slot state (arg 3) are DONATED and
     the pools come back as the same buffers — carried through the layer
@@ -1918,6 +1926,55 @@ def _scatter_fn(quant_kv: str = ""):
         return PagedKVCache(k, v, lengths, slot_state=state)
 
     return jax.jit(serve_scatter, donate_argnums=(0,))
+
+
+@functools.lru_cache(maxsize=1)
+def _seed_key_fn():
+    """Jitted raw key ``uint32[2]`` of an integer seed."""
+    def serve_seed_key(seed):
+        return jax.random.key_data(jax.random.key(seed)).astype(jnp.uint32)
+
+    return jax.jit(serve_seed_key)
+
+
+@functools.lru_cache(maxsize=1)
+def _activate_fn():
+    """Jitted hand-over of a slot to a request (``state`` DONATED): the
+    eight fields of :class:`_SlotState` for ONE slot in one program, where
+    eight eager ``.at[slot].set`` were eight dispatches with the device
+    idle between a prefill's token and the next decode step (PERF.md §6,
+    PR 32). ``carry`` is the prefill's key carry; the other values are the
+    request's, given as numpy scalars in the call itself."""
+    def serve_activate(state, slot, tok, carry, temp, top_k, top_p, eos):
+        return _SlotState(
+            last_tok=state.last_tok.at[slot].set(tok),
+            rng=state.rng.at[slot].set(carry),
+            temp=state.temp.at[slot].set(temp),
+            top_k=state.top_k.at[slot].set(top_k),
+            top_p=state.top_p.at[slot].set(top_p),
+            eos=state.eos.at[slot].set(eos),
+            done=state.done.at[slot].set(False),
+            live=state.live.at[slot].set(True),
+        )
+
+    return jax.jit(serve_activate, donate_argnums=(0,))
+
+
+@functools.lru_cache(maxsize=1)
+def _release_fn():
+    """Jitted return of a finished request's slot (``state`` and the
+    cache's ``lengths`` DONATED): ``live`` and ``done`` off, so the decode
+    step steers the slot's writes to the scratch block, and its length 0.
+    It takes ``lengths`` alone — the pools stay arguments of the model's
+    programs and of the scatter only."""
+    def serve_release(state, lengths, slot):
+        state = state._replace(
+            live=state.live.at[slot].set(False),
+            done=state.done.at[slot].set(False),
+        )
+        return state, lengths.at[slot].set(0)
+
+    return jax.jit(serve_release, donate_argnums=(0, 1))
 
 
 @functools.lru_cache(maxsize=1)
