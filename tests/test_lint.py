@@ -152,9 +152,14 @@ class TestGL001:
             "tony_tpu.serve.shortconv:prefill_step",
             "tony_tpu.serve.shortconv:tail_prefill_step",
             "tony_tpu.serve.shortconv:decode_step",
+            "tony_tpu.serve.ssm_hybrid:prefill_step",
+            "tony_tpu.serve.ssm_hybrid:tail_prefill_step",
+            "tony_tpu.serve.ssm_hybrid:decode_step",
             "tony_tpu.models.generate:layer",
             "tony_tpu.models.latent_moe:layer",
             "tony_tpu.models.shortconv_moe:layer",
+            "tony_tpu.models.ssm_hybrid:layer",
+            "tony_tpu.models.layer_walk:walk_layers",
             "tony_tpu.serve.spec:verify_and_accept",
             "tony_tpu.models.generate:sample_tokens",
             "tony_tpu.ops.decode_attention:decode_attention",

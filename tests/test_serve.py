@@ -742,7 +742,7 @@ def test_a_seeds_raw_key_is_jax_random_keys(seed):
 def test_a_model_family_keeps_the_steps_contract(family):
     """What ``Engine`` asks of a family (docs/SERVE.md "Model families"):
     ``steps_for`` maps the configuration's class to ONE module with the
-    four names and ``REFUSED_KNOBS``; the configuration says what a cache
+    four names, ``REFUSED_KNOBS`` and ``SCAN_STATE``; the configuration says what a cache
     row is (``cache_layout``), how many layers keep one (``cache_layers``)
     and what fixed-size state a slot keeps beside them (``slot_state``,
     None for two of the three); each of the three programs lowers through
@@ -768,6 +768,7 @@ def test_a_model_family_keeps_the_steps_contract(family):
         assert callable(getattr(steps, name)), name
     knobs = {f.name for f in fields(ServeConfig)} | {"block_handoff"}
     assert set(steps.REFUSED_KNOBS) <= knobs
+    assert steps.SCAN_STATE is False     # none of the three scans its prompt into a state
     heads, width, pools = cfg.cache_layout
 
     S, blk, P, bucket, M = 2, 8, 5, 16, 2

@@ -61,10 +61,10 @@ def _compiled_kernels(monkeypatch):
     which is the CPU here; each reads ``_use_interpret`` from its own
     namespace, so steer it there (no option in the program)."""
     from tony_tpu.ops import (
-        attention, decode_attention, fused_ce, grouped_mm, quant_mm,
+        attention, decode_attention, fused_ce, grouped_mm, quant_mm, selective_scan,
     )
 
-    for mod in (attention, decode_attention, fused_ce, grouped_mm, quant_mm):
+    for mod in (attention, decode_attention, fused_ce, grouped_mm, quant_mm, selective_scan):
         monkeypatch.setattr(mod, "_use_interpret", lambda: False)
 
 
@@ -494,6 +494,181 @@ def test_shortconv_decode_step_at_published_widths_keeps_pool_and_state_in_place
         assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
     else:
         assert relaid and mem.temp_size_in_bytes > pool_bytes
+
+
+# --- the other families' serving programs are the programs they were ----------------------
+
+# (flops, bytes accessed, temporaries, instructions of the compiled text) at the parent of
+# PR 33 (commit 69de200), which moved the layer walk into models/layer_walk.py and added
+# a family to ``steps_for``: PERF.md section 6's method (PR 29)
+_PROGRAMS_OF_PR_32 = {
+    "dense.decode": (14149430272, 912558080, 2257920, 1823),
+    "dense.prefill512": (183018209280, 1227667456, 612864, 1313),
+    "latent.decode": (94019731456, 3437949952, 8242176, 3861),
+    "latent.prefill512": (853507637248, 8985379840, 169394176, 3501),
+    "shortconv.decode": (44899729408, 1304465920, 7180800, 7940),
+    "shortconv.prefill512": (171656413184, 5460690944, 35565056, 12451),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_PROGRAMS_OF_PR_32))
+def test_the_other_families_serving_programs_are_unchanged(one_chip, case):
+    """The decode step and a 512 prefill of the dense (Yi-1.5-6B widths, 4
+    layers), latent (DeepSeek-V3 widths, 3 layers) and short-convolution
+    (LFM2's 14 layers) families, compiled for the described chip: the same
+    operations, bytes, temporaries and instruction count as before a fourth
+    family was added beside them."""
+    from tony_tpu.serve.cache import create_cache
+    from tony_tpu.serve.capacity import _state_avals
+    from tony_tpu.serve.engine import _decode_fn, _prefill_fn
+
+    family, program = case.split(".")
+    if family == "dense":
+        from tony_tpu.models.llama import LlamaConfig, init_params
+
+        cfg = LlamaConfig(vocab_size=64000, dim=4096, n_layers=4, n_heads=32, n_kv_heads=4,
+                          ffn_dim=11008, max_seq_len=2048, rope_theta=5e6, norm_eps=1e-6,
+                          dtype=BF16)
+        S, P, M = 16, 529, 32
+    elif family == "latent":
+        from tony_tpu.models.latent_moe import LatentMoEConfig, init_params
+
+        cfg = LatentMoEConfig(vocab_size=16160, n_layers=3, n_dense_layers=1,
+                              n_local_experts=16, max_seq_len=4096)
+        S, P, M = 48, 3136, 64
+    else:
+        from tony_tpu.models import shortconv_moe as sm
+        from tony_tpu.models.shortconv_moe import init_params
+
+        cfg = sm.ShortConvMoEConfig(layer_types=sm.PUBLISHED_LAYER_TYPES[:14], max_seq_len=2048)
+        S, P, M = 64, 2049, 32
+    sds = partial(_described, one_chip=one_chip)
+    params = sds(jax.eval_shape(partial(init_params, cfg=cfg), jax.random.key(0)))
+    if program == "decode":
+        cache = sds(jax.eval_shape(partial(create_cache, cfg, S, P, 64)))
+        lowered = _decode_fn(cfg, "scan", 64, 64).lower(
+            params, cache, sds(jax.ShapeDtypeStruct((S, M), I32)), sds(_state_avals(S)))
+    else:
+        scalars = [jax.ShapeDtypeStruct(s, d) for s, d in (
+            ((1, 512), I32), ((), I32), ((), F32), ((), I32), ((), F32), ((2,), jnp.uint32))]
+        lowered = _prefill_fn(cfg, 512, 64).lower(params, *sds(scalars))
+    compiled = lowered.compile()
+    cost, mem = compiled.cost_analysis(), compiled.memory_analysis()
+    instructions = len(re.findall(r" = \S+ ([a-z][\w\-]*)\(", compiled.as_text()))
+    assert (int(cost["flops"]), int(cost["bytes accessed"]), mem.temp_size_in_bytes,
+            instructions) == _PROGRAMS_OF_PR_32[case]
+
+
+# --- the state-space family: a 1.3 GB per-slot state touched in place ------------------
+
+_SSM_SLOTS, _SSM_TABLE = 128, 80          # the serving cell: 128 slots, max_len 5120 / 64
+
+
+def _ssm_cell(one_chip):
+    """The state-space / attention hybrid at the serving cell's shapes, as
+    shapes on the described chip: ``(cfg, params, cache)`` — all 28 published
+    layers, 128 slots, a pool of 10,241 blocks of 64 for the 2 attention
+    layers, the recurrent state ``[26 x 19, 128, 5120]`` float32 (1.295 GB)."""
+    from tony_tpu.models.ssm_hybrid import SSMHybridConfig, init_params
+    from tony_tpu.serve.cache import create_cache
+
+    cfg = SSMHybridConfig(max_seq_len=5120)
+    sds = partial(_described, one_chip=one_chip)
+    params = sds(jax.eval_shape(partial(init_params, cfg=cfg), jax.random.key(0)))
+    pool = 1 + _SSM_SLOTS * _SSM_TABLE
+    cache = sds(jax.eval_shape(partial(create_cache, cfg, _SSM_SLOTS, pool, 64)))
+    assert cache.k.shape == (2, pool, 1, 64, 128)
+    assert cache.slot_state.shape == (26 * 19, _SSM_SLOTS, 5120)
+    return cfg, params, cache
+
+
+def _state_copies(hlo: str) -> list[str]:
+    """Instructions of the compiled text that copy or relay a buffer of the
+    whole recurrent state's shape."""
+    pattern = re.compile(r"= \(?f32\[494,128,5120\]\S* (copy|copy-start|transpose)\(")
+    return [l.strip()[:160] for l in hlo.splitlines() if pattern.search(l)]
+
+
+@pytest.mark.parametrize("program", ["decode", "scatter", "zero_slot_state", "slot_state"])
+def test_ssm_hybrid_state_programs_hold_no_copy_of_the_state(one_chip, program):
+    """The four programs that touch the state-space family's per-slot state
+    at the serving cell's 128 slots (1.295 GB, a sixth of what the chip
+    holds beside 6.06 GB of weights): ``jit_serve_decode`` carries it
+    through the layer walk and rewrites a layer's rows where they lie (ONE
+    dynamic-update-slice a Mamba layer: a second update that read the buffer
+    after the first made the compiler copy the whole state every layer);
+    ``jit_serve_scatter`` writes a prefill's state into one slot,
+    ``jit_serve_zero_slot_state`` resets one slot, ``jit_serve_slot_state``
+    reads one — none holds a copy, a relayout or a temporary of the state's
+    size, the donated state comes back in the buffer it came in, and the
+    paged kernel is in the decode step at 20 query rows to ONE K/V head. The
+    state's leading index is (layer, state row) and its rows are ``[slots,
+    5120]``: as ``[layers, slots, 19, 5120]`` the compiler relaid it
+    slot-minor in and out of every scanned run (six state-sized copies a
+    step, 1.3 GB of temporaries: PERF.md §6, PR 33)."""
+    from tony_tpu.serve.cache import slot_state_bytes
+    from tony_tpu.serve.capacity import _state_avals
+    from tony_tpu.serve.engine import (
+        _decode_fn, _scatter_fn, _slot_state_fn, _zero_slot_state_fn,
+    )
+
+    cfg, params, cache = _ssm_cell(one_chip)
+    sds = partial(_described, one_chip=one_chip)
+    state_bytes = slot_state_bytes(cfg, _SSM_SLOTS)
+    assert state_bytes == 1_294_991_360
+    i32 = lambda *shape: sds(jax.ShapeDtypeStruct(shape, I32))  # noqa: E731
+    if program == "decode":
+        lowered = _decode_fn(cfg, "scan", 64, 64).lower(
+            params, cache, i32(_SSM_SLOTS, _SSM_TABLE), sds(_state_avals(_SSM_SLOTS)))
+        assert "paged_decode_attention" in lowered.as_text()
+    elif program == "scatter":
+        rows = sds(jax.ShapeDtypeStruct((2, 1, 512, 128), BF16))
+        handed = sds(jax.ShapeDtypeStruct((26 * 19, 5120), F32))
+        lowered = _scatter_fn().lower(cache, rows, rows, i32(512), i32(512), i32(), i32(), handed)
+    elif program == "zero_slot_state":
+        lowered = _zero_slot_state_fn().lower(cache.slot_state, i32())
+    else:
+        lowered = _slot_state_fn().lower(cache.slot_state, i32())
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    assert not _state_copies(compiled.as_text())
+    # what is left of temporaries is a pool's (the scatter's known pool copies, PERF.md §7)
+    pool_bytes = cache.k.size * 2
+    assert mem.temp_size_in_bytes < max(state_bytes // 8, pool_bytes + pool_bytes // 8), mem
+    if program != "slot_state":
+        assert mem.alias_size_in_bytes >= state_bytes, mem
+    else:
+        assert mem.output_size_in_bytes < state_bytes // _SSM_SLOTS * 2
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
+
+
+@pytest.mark.parametrize("bucket", [512, 1280])
+def test_ssm_hybrid_prefill_holds_the_selective_scan_kernel(one_chip, bucket):
+    """A prefill of the state-space family at the cell's widths compiles for
+    one chip with the ``selective_scan`` kernel in its layer scans (grid: 10
+    channel blocks of 512 x chunks of 256 positions), and fits."""
+    from tony_tpu.serve.engine import _prefill_fn
+
+    cfg, params, _ = _ssm_cell(one_chip)
+    scalars = [jax.ShapeDtypeStruct(s, d) for s, d in (
+        ((1, bucket), I32), ((), I32), ((), F32), ((), I32), ((), F32), ((2,), jnp.uint32))]
+    lowered = _prefill_fn(cfg, bucket, 64).lower(params, *_described(scalars, one_chip))
+    text = lowered.as_text()
+    assert "selective_scan" in text and "tpu_custom_call" in text
+    mem = lowered.compile().memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
+    assert mem.temp_size_in_bytes < 2**30, mem
+
+
+@pytest.mark.parametrize("T,dtype", [(64, BF16), (640, BF16), (2048, BF16), (256, F32)])
+def test_selective_scan_kernel(one_chip, T, dtype):
+    """The kernel alone at the published widths (5120 channels, 16 state
+    rows) for the shortest, an odd and the longest bucket."""
+    from tony_tpu.ops.selective_scan import selective_scan
+
+    E, N = 5120, 16
+    _compile(selective_scan, one_chip, ((T, E), dtype), ((T, E), F32), ((T, N), dtype),
+             ((T, N), dtype), ((T, E), dtype), ((N, E), F32), ((E,), F32), ((N, E), F32))
 
 
 @pytest.mark.parametrize("program", ["serve_activate", "serve_release"])

@@ -23,7 +23,8 @@ or the one-token form (:func:`conv_token`); for an attention layer it is
 (serve/shortconv.py) hand in their own.
 
 Weights are stacked BY KIND (``conv_layers``, ``attn_layers``,
-``dense_ffns``, ``moe_ffns``) and :func:`walk_layers` follows the list: runs
+``dense_ffns``, ``moe_ffns``) and models/layer_walk.py's ``walk_layers``
+(shared with models/ssm_hybrid.py) follows the list: runs
 of equal layers are scanned with the stacks indexed where they lie, single
 layers are called with a static index. The published list is not periodic,
 so nothing here assumes a period. Expert layers hold ``n_local_experts`` of
@@ -34,35 +35,23 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass
-from typing import Any, Callable, NamedTuple
+from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
-from tony_tpu.models.latent_moe import (
-    CACHE_LANES, EXPERT_LEAVES, rotate, split_experts, swiglu,
+from tony_tpu.models.latent_moe import CACHE_LANES, EXPERT_LEAVES, rotate, swiglu
+from tony_tpu.models.layer_walk import (
+    ATTENTION, CONV, Run, layer_of, put, runs_of, walk_layers,
 )
 from tony_tpu.models.llama import rms_norm, rope_freqs
 
 Params = dict[str, Any]
 
-CONV, ATTENTION = "conv", "full_attention"
 # the published list: attention at 2, 6, 10, 14, 18, 21
 PUBLISHED_LAYER_TYPES = tuple(
     ATTENTION if i in (2, 6, 10, 14, 18, 21) else CONV for i in range(24))
-_OP_STACK = {CONV: "conv_layers", ATTENTION: "attn_layers"}
-
-
-class Run(NamedTuple):
-    """``n`` consecutive layers of one operator kind and one feed-forward
-    kind; ``op0`` / ``ff0`` index the first of them in its kind's stack."""
-
-    op: str
-    moe: bool
-    op0: int
-    ff0: int
-    n: int
 
 
 @dataclass(frozen=True)
@@ -160,17 +149,7 @@ class ShortConvMoEConfig:
     @property
     def runs(self) -> tuple[Run, ...]:
         """The declared list as runs of equal layers, in order."""
-        out: list[Run] = []
-        seen = {CONV: 0, ATTENTION: 0, False: 0, True: 0}
-        for i, op in enumerate(self.layer_types):
-            moe = i >= self.n_dense_layers
-            if out and (out[-1].op, out[-1].moe) == (op, moe):
-                out[-1] = out[-1]._replace(n=out[-1].n + 1)
-            else:
-                out.append(Run(op, moe, seen[op], seen[moe], 1))
-            seen[op] += 1
-            seen[moe] += 1
-        return tuple(out)
+        return runs_of(self.layer_types, self.n_dense_layers)
 
     @property
     def n_params(self) -> int:
@@ -403,47 +382,6 @@ def layer(x: jax.Array, op: Params, ff: Params, cfg: ShortConvMoEConfig, keep: K
     else:
         delta, routes = swiglu(h2, ff["w1"], ff["w3"], ff["w2"]), None
     return x + delta, state, routes
-
-
-def layer_of(tree: Params, i):
-    """Layer ``i`` of a stack, read where it lies: a static slice for a
-    Python index, a dynamic one inside a scanned run."""
-    if isinstance(i, int):
-        return jax.tree.map(lambda a: a[i], tree)
-    return jax.tree.map(lambda a: lax.dynamic_index_in_dim(a, i, 0, keepdims=False), tree)
-
-
-def walk_layers(step, carry, params: Params, cfg: ShortConvMoEConfig):
-    """Run ``step(carry, op, ff, oi, fi, experts) -> carry`` over the layers
-    in the declared order. ``op`` / ``ff`` are the layer's operator and
-    feed-forward weights, ``oi`` / ``fi`` its index among the layers of its
-    operator kind and of its feed-forward kind (what per-kind state — a
-    convolution state, a pool's layer, a routes row — is indexed by), and
-    ``experts = (the expert stacks whole, fi)`` for an expert layer, else
-    None. A run of equal layers is ONE scanned body with traced indices; a
-    single layer is called with Python ones."""
-    rest, stacked = split_experts(params["moe_ffns"])
-    for run in cfg.runs:
-        ops = params[_OP_STACK[run.op]]
-        ffs = rest if run.moe else params["dense_ffns"]
-
-        def one(carry, i, run=run, ops=ops, ffs=ffs):
-            oi, fi = run.op0 + i, run.ff0 + i
-            return step(carry, layer_of(ops, oi), layer_of(ffs, fi), oi, fi,
-                        (stacked, fi) if run.moe else None)
-
-        if run.n == 1:
-            carry = one(carry, 0)
-        else:
-            carry, _ = lax.scan(lambda c, i, one=one: (one(c, i), None), carry,
-                                jnp.arange(run.n, dtype=jnp.int32))
-    return carry
-
-
-def put(stack: jax.Array, row: jax.Array, i) -> jax.Array:
-    """``stack`` with ``row`` written at leading index ``i`` (in place on a
-    carried buffer)."""
-    return lax.dynamic_update_index_in_dim(stack, row.astype(stack.dtype), i, 0)
 
 
 def forward_states(params: Params, tokens: jax.Array, ctx_k: jax.Array | None,

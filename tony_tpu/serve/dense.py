@@ -46,6 +46,8 @@ from tony_tpu.serve.spec import verify_and_accept
 # this family takes every ServeConfig knob (serve/latent.py's table says
 # what a refusal looks like)
 REFUSED_KNOBS: dict[str, tuple] = {}
+# no per-slot state that prefill scans (docs/SERVE.md item 5)
+SCAN_STATE = False
 
 _QUANT_WEIGHT_NAMES = ("wq", "wk", "wv", "wo", "w1", "w2", "w3")
 
@@ -269,6 +271,6 @@ def decode_step(params, cache: PagedKVCache, table, state, drafts=None,
 
 
 __all__ = [
-    "REFUSED_KNOBS", "decode_step", "init_params", "prefill_step",
+    "REFUSED_KNOBS", "SCAN_STATE", "decode_step", "init_params", "prefill_step",
     "quantize_decode_params", "tail_prefill_step",
 ]

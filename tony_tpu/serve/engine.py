@@ -64,6 +64,7 @@ from jax import lax
 from tony_tpu.models.latent_moe import LatentMoEConfig
 from tony_tpu.models.llama import LlamaConfig, Params
 from tony_tpu.models.shortconv_moe import ShortConvMoEConfig
+from tony_tpu.models.ssm_hybrid import SSMHybridConfig
 from tony_tpu.obs import hbm, health, profile, series, slo, trace
 from tony_tpu.obs import compiles as compile_ledger
 from tony_tpu.obs.metrics import DecodeMetrics
@@ -72,6 +73,7 @@ from tony_tpu.obs.registry import HistogramWindow, Registry, snapshot_to_app_dir
 from tony_tpu.serve import dense as dense_steps
 from tony_tpu.serve import latent as latent_steps
 from tony_tpu.serve import shortconv as shortconv_steps
+from tony_tpu.serve import ssm_hybrid as ssm_hybrid_steps
 from tony_tpu.serve.cache import (
     SCRATCH_BLOCK, BlockPayload, BlockPool, PagedKVCache, block_bytes,
     blocks_for, create_cache, dequantize_values, export_blocks, grow_cache,
@@ -281,11 +283,12 @@ class Engine:
     def __init__(self, params: Params, cfg: LlamaConfig, serve: ServeConfig):
         """``cfg`` is a :class:`LlamaConfig` (dense grouped-query decoder),
         a ``models.latent_moe.LatentMoEConfig`` (latent attention, sigmoid
-        group-limited experts) or a ``models.shortconv_moe.
+        group-limited experts), a ``models.shortconv_moe.
         ShortConvMoEConfig`` (short convolutions beside attention layers,
-        sigmoid-routed experts): same loop, pool and tables; the step
-        bodies, the cache's rows and the per-slot state are the family's
-        (:func:`steps_for`)."""
+        sigmoid-routed experts) or a ``models.ssm_hybrid.SSMHybridConfig``
+        (selective state-space layers beside position-free attention
+        layers): same loop, pool and tables; the step bodies, the cache's
+        rows and the per-slot state are the family's (:func:`steps_for`)."""
         self._steps = steps_for(cfg)
         for knob in self._steps.REFUSED_KNOBS:
             # block_handoff is no field: refused where it is called
@@ -560,6 +563,7 @@ class Engine:
         with warmup); the default reports run-cumulative quantiles (the
         RPC/stats view). The windowed state is single-consumer by design
         — only the engine's own series source uses it."""
+        scanned = int(self._steps.SCAN_STATE)
         snap: dict[str, float] = {
             "queue_depth": float(len(self._queue)),
             "live_slots": float(self.n_live),
@@ -579,6 +583,15 @@ class Engine:
             # into a slot's (admissions + chunk boundaries); 0 without one
             "slot_state_bytes": float(self.metrics.slot_state_bytes),
             "state_handoffs": float(self.metrics.state_handoffs),
+            # a state that prefill scans and every decode step reads and
+            # rewrites (the steps module's SCAN_STATE): the prompt tokens put
+            # through the scan, and the bytes of slot state the decode steps
+            # streamed (live slots x a slot's bytes x 2, a step); both are
+            # arithmetic on the counters above, 0 for the other families
+            "scan_tokens": float(scanned * (
+                self.metrics.prompt_tokens - self.metrics.prefix_hit_tokens)),
+            "state_stream_bytes": float(
+                scanned * 2 * slot_state_bytes(self.cfg, 1) * self.metrics.decode_live_sum),
             # the host's touches of the device between model programs: a
             # sound run reads device_fetches = decode steps + first tokens
             # (+ chunks that counted routes), slot_programs = activations
@@ -1655,6 +1668,8 @@ def steps_for(cfg):
         return latent_steps
     if isinstance(cfg, ShortConvMoEConfig):
         return shortconv_steps
+    if isinstance(cfg, SSMHybridConfig):
+        return ssm_hybrid_steps
     if isinstance(cfg, LlamaConfig):
         if cfg.is_moe:
             # forward_with_cache (the prefill path) has no expert FFN —

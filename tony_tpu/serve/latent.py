@@ -53,6 +53,9 @@ REFUSED_KNOBS = {
     "block_handoff": (False, "gang block export/adopt ships (k, v) pools in a BlockPayload"),
 }
 
+# no per-slot state that prefill scans (docs/SERVE.md item 5)
+SCAN_STATE = False
+
 
 def _cache_rows(latents, cfg: LatentMoEConfig):
     """Latent rows as the cache holds them: zero lanes up to ``cache_width``."""
@@ -170,6 +173,6 @@ def decode_step(params, cache: PagedKVCache, table, state, *,
 
 
 __all__ = [
-    "REFUSED_KNOBS", "decode_step", "init_params", "prefill_step",
+    "REFUSED_KNOBS", "SCAN_STATE", "decode_step", "init_params", "prefill_step",
     "tail_prefill_step",
 ]

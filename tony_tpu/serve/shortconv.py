@@ -58,6 +58,10 @@ REFUSED_KNOBS = {
                              "slot's convolution state"),
 }
 
+# the per-slot state is a window of fixed length, not a scan over the prompt
+# (docs/SERVE.md item 5)
+SCAN_STATE = False
+
 
 def _pack_rows(rows, cfg: ShortConvMoEConfig):
     """K or V rows ``[..., Hkv, hd]`` as the cache holds them: ``[..., Hkv /
@@ -205,6 +209,6 @@ def decode_step(params, cache: PagedKVCache, table, state, *,
 
 
 __all__ = [
-    "REFUSED_KNOBS", "decode_step", "init_params", "prefill_step",
+    "REFUSED_KNOBS", "SCAN_STATE", "decode_step", "init_params", "prefill_step",
     "tail_prefill_step",
 ]
