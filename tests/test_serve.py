@@ -707,7 +707,8 @@ def test_device_touches_are_counted_once_per_event(setup, mixed, kind):
     """``device_fetches`` = decode steps + first tokens (a prefill's or a
     final chunk's: the dense family's other chunks hand the host nothing),
     ``slot_programs`` = activations + finishes, over the mixed run; both
-    are in ``stats_snapshot`` and start again with ``reset_metrics``."""
+    are in ``stats_snapshot`` and start again with ``reset_metrics``.
+    ``steps_ahead`` is 0 with ``spec`` on and not otherwise."""
     cfg, params = setup
     pairs, _ = mixed
     eng = Engine(params, cfg, ServeConfig(**_MIXED_ENGINES[kind]))
@@ -717,10 +718,297 @@ def test_device_touches_are_counted_once_per_event(setup, mixed, kind):
     assert m.decode_steps > 0
     assert m.device_fetches == m.decode_steps + m.requests_started
     assert m.slot_programs == m.requests_started + m.requests_finished
+    # drafts are proposed from the tokens just read: a speculating engine
+    # never dispatches a step before the one before it was read
+    assert (m.steps_ahead == 0) == (kind == "spec")
     assert snap["device_fetches"] == m.device_fetches
     assert snap["slot_programs"] == m.slot_programs
     eng.reset_metrics()
     assert eng.metrics.device_fetches == eng.metrics.slot_programs == 0
+
+
+# --- a pipeline of depth one between host and device ---------------------------
+
+_PIPELINE_FAMILIES = ("dense", "latent", "shortconv", "ssm_hybrid")
+
+
+def _family_model(family):
+    from tony_tpu.models.latent_moe import LatentMoEConfig
+    from tony_tpu.models.shortconv_moe import ShortConvMoEConfig
+    from tony_tpu.models.ssm_hybrid import SSMHybridConfig
+    from tony_tpu.serve import engine as engine_mod
+
+    cfg = {"dense": llama.LlamaConfig, "latent": LatentMoEConfig,
+           "shortconv": ShortConvMoEConfig, "ssm_hybrid": SSMHybridConfig}[family].tiny()
+    return cfg, engine_mod.steps_for(cfg).init_params(jax.random.key(0), cfg)
+
+
+def _pipeline_engine(model, **knobs):
+    cfg, params = model
+    base = dict(slots=3, max_len=64, kv_block=8, prefill_buckets=(16, 32), prefix=False)
+    return Engine(params, cfg, ServeConfig(**{**base, **knobs}))
+
+
+def _hold_off(monkeypatch):
+    """The engine with running ahead held off: the private predicate
+    patched, in the test alone; there is no public switch."""
+    monkeypatch.setattr(Engine, "_may_run_ahead", lambda self, step: False)
+
+
+def _drive(eng, reqs):
+    """Submit everything, step to the end: what a caller can see after
+    EVERY ``step()`` call — each request's tokens and finish reason, so
+    also the call at which each request was admitted."""
+    rids = [eng.submit(r) for r in reqs]
+    seen = []
+    while eng.queue_depth or eng.n_live:
+        eng.step()
+        comps = [(rid, eng.completion_of(rid)) for rid in rids]
+        seen.append(tuple((rid, tuple(c.tokens), c.finish_reason)
+                          for rid, c in comps if c is not None))
+    return seen
+
+
+def _watch(eng, monkeypatch):
+    """Log the step loop of ``eng``: (event, step record) for every
+    dispatch, sync and emit, the pool growths and the admissions made
+    while a step was in flight, the latter with whether the step's row in
+    that slot was another request's (a stale row)."""
+    from tony_tpu.serve import engine as engine_mod
+
+    log = {"events": [], "grown_in_flight": 0, "admitted_over_stale": 0}
+    for name in ("_dispatch", "_sync", "_emit"):
+        def wrapped(step, _real=getattr(eng, name), _name=name):
+            if _name != "_sync" or step.out is not None:
+                log["events"].append((_name, step))
+            return _real(step)
+        monkeypatch.setattr(eng, name, wrapped)
+    real_grow, real_admit = engine_mod.grow_cache, eng._admit_one
+
+    def grow(cache, n):
+        log["grown_in_flight"] += eng._inflight is not None
+        return real_grow(cache, n)
+
+    def admit_one(slot, rid, req):
+        step = eng._inflight
+        if step is not None and any(s == slot and r != rid for s, r in step.rows):
+            log["admitted_over_stale"] += 1
+        return real_admit(slot, rid, req)
+
+    monkeypatch.setattr(engine_mod, "grow_cache", grow)
+    monkeypatch.setattr(eng, "_admit_one", admit_one)
+    return log
+
+
+def _steps_ahead_of(events):
+    """From the log alone: (emitted steps that were dispatched before the
+    step dispatched before them had been read, emitted steps in today's
+    order)."""
+    unread, ahead = None, []
+    for name, step in events:
+        if name == "_dispatch":
+            if unread is not None:
+                ahead.append(step)
+            unread = step
+        elif name == "_sync" and step is unread:
+            unread = None
+    emitted = [step for name, step in events if name == "_emit"]
+    n_ahead = sum(1 for step in emitted if any(step is a for a in ahead))
+    return n_ahead, len(emitted) - n_ahead
+
+
+def _pipeline_requests(cfg, greedy):
+    """The mixed run, from what each prompt gives greedily (``greedy``,
+    None on the first pass): finishes by length the host can foresee, an
+    eos on the first token, eos hits mid-run while the queue is not empty
+    (so a slot is admitted again while a stale step is in flight),
+    ``max_new_tokens`` 1 and 2, a sampled request, and rows long enough to
+    grow the pool."""
+    prompts = _prompts(cfg, [5, 9, 12, 4, 7, 20, 6, 11, 3], seed=21)
+    budgets = [40, 12, 1, 2, 6, 12, 12, 9, 30]
+    reqs = [Request(prompt=p, max_new_tokens=m) for p, m in zip(prompts, budgets)]
+    if greedy is None:
+        return reqs
+    for i, k in ((1, 3), (5, 0), (6, 5)):       # request -> index of the token made its eos
+        reqs[i] = Request(prompt=prompts[i], max_new_tokens=budgets[i], eos_id=greedy[i][k])
+    reqs[4] = Request(prompt=prompts[4], max_new_tokens=6, temperature=0.8, top_k=7, rng=5)
+    return reqs
+
+
+@pytest.fixture(scope="module", params=_PIPELINE_FAMILIES)
+def pipelined(request):
+    """Two runs a family, each through an engine that runs ahead and
+    through the same engine held off. ``steady``: every finish is one the
+    host can foresee (by length; one- and two-token requests among them),
+    as in a closed loop of callers. ``mixed``: eos finishes too. Of each:
+    {"ahead", "held": what the callers saw call by call, "log", "engine"
+    of the one that ran ahead, "held_engine", "requests"}."""
+    model = _family_model(request.param)
+    cfg = model[0]
+    runs = {}
+    greedy = None
+    for kind in ("steady", "mixed"):
+        reqs = _pipeline_requests(cfg, greedy)
+        with pytest.MonkeyPatch.context() as mp:
+            _hold_off(mp)
+            held_eng = _pipeline_engine(model)
+            held = _drive(held_eng, reqs)
+        greedy = greedy or [list(toks) for _, toks, _ in held[-1]]
+        with pytest.MonkeyPatch.context() as mp:
+            eng = _pipeline_engine(model)
+            log = _watch(eng, mp)
+            ahead = _drive(eng, reqs)
+        runs[kind] = {"ahead": ahead, "held": held, "log": log, "engine": eng,
+                      "held_engine": held_eng, "requests": reqs}
+    return runs
+
+
+def test_running_ahead_is_invisible_where_every_finish_is_foreseen(pipelined):
+    """Token for token, finish for finish and admission step for admission
+    step, after EVERY ``step()`` call: where each finish is by length, every
+    admission meets an empty pipeline, and dispatching step N+1 before step
+    N is read changes nothing a caller can see — through slots that churn,
+    a pool grown mid-flight and requests of one and two tokens."""
+    run = pipelined["steady"]
+    assert run["ahead"] == run["held"]
+    final = {rid: (toks, why) for rid, toks, why in run["ahead"][-1]}
+    assert [len(final[i][0]) for i in (2, 3)] == [1, 2]
+    assert all(why == "length" for _, why in final.values())
+    assert run["log"]["grown_in_flight"] >= 1 and run["log"]["admitted_over_stale"] == 0
+    assert run["engine"].metrics.steps_ahead > 0
+
+
+def test_running_ahead_serves_the_held_off_engines_tokens(pipelined):
+    """Every request gets, token for token and with the same finish
+    reason, what the engine held off gives it, through eos finishes found
+    one device step late and a slot admitted again while its last tenant's
+    stale step is in flight. (Such a request starts behind that step and
+    trails the held-off engine's calls by one: only what it is given is
+    compared, not when.)"""
+    run = pipelined["mixed"]
+    final = {rid: (toks, why) for rid, toks, why in run["ahead"][-1]}
+    assert final == {rid: (toks, why) for rid, toks, why in run["held"][-1]}
+    reqs = run["requests"]
+    assert len(final) == len(reqs) and all(why for _, why in final.values())
+    assert final[5] == ((reqs[5].eos_id,), "eos")           # on its first token
+    for i in (1, 6):                                        # found at a decode step
+        toks, why = final[i]
+        assert why == "eos" and 1 < len(toks) < reqs[i].max_new_tokens
+        assert toks[-1] == reqs[i].eos_id and reqs[i].eos_id not in toks[:-1]
+    assert run["log"]["admitted_over_stale"] >= 1
+    # no call made more than one step's tokens visible
+    for before, after in zip(run["ahead"], run["ahead"][1:]):
+        seen = {rid: len(toks) for rid, toks, _ in before}
+        assert all(len(toks) - seen.get(rid, len(toks) - 1) <= 1 for rid, toks, _ in after)
+
+
+def test_steps_ahead_counts_the_steps_that_ran_ahead(pipelined):
+    """``steps_ahead`` + the steps in today's order = ``decode_steps``,
+    both counted here from the order of dispatches and fetches alone; the
+    mechanism engaged, and never when held off; one fetch a counted step
+    and one a first token, as before steps overlapped."""
+    for kind in ("steady", "mixed"):
+        run = pipelined[kind]
+        eng = run["engine"]
+        m, held = eng.metrics, run["held_engine"].metrics
+        n_ahead, n_in_order = _steps_ahead_of(run["log"]["events"])
+        assert n_ahead == m.steps_ahead > 0 and n_in_order > 0
+        assert n_ahead + n_in_order == m.decode_steps
+        assert held.steps_ahead == 0
+        for metrics in (m, held):
+            assert metrics.device_fetches == metrics.decode_steps + metrics.requests_started
+            assert metrics.slot_programs == 2 * len(run["requests"])
+        assert m.decode_tokens == held.decode_tokens
+        assert m.decode_live_sum == held.decode_live_sum
+        snap = eng.stats_snapshot()
+        assert snap["steps_ahead"] == m.steps_ahead and snap["decode_steps"] == m.decode_steps
+        assert m.summary()["steps_ahead"] == m.steps_ahead
+        # a step whose row is on its last token by length is never run past
+        assert eng._inflight is None
+    steady = pipelined["steady"]
+    assert steady["engine"].metrics.decode_steps == steady["held_engine"].metrics.decode_steps
+
+
+def test_a_step_in_flight_keeps_the_table_it_was_dispatched_with(setup):
+    """The next step's plan writes the host's block table while the step
+    before it runs: what was uploaded for that step is a copy of its own,
+    also at full width, where the slice is the whole contiguous array and
+    an upload that aliases its source (the CPU backend does, alignment
+    permitting) would show the device the later writes."""
+    cfg, params = setup
+    for _ in range(12):     # an alias needs the host array aligned by chance
+        eng = Engine(params, cfg, ServeConfig(slots=3, max_len=16, kv_block=8))
+        eng._table[:] = 5
+        eng._table_dirty = True
+        eng._set_attended(eng._m_total)
+        assert eng._table_dev.shape == eng._table.shape
+        eng._table[:] = 7
+        assert (np.asarray(eng._table_dev) == 5).all()
+
+
+def _two_long_requests(cfg, eos=None):
+    return [Request(prompt=p, max_new_tokens=10, eos_id=eos)
+            for p in _prompts(cfg, [6, 9], seed=22)]
+
+
+def test_one_step_call_makes_one_steps_tokens_visible(setup):
+    """What the drivers' pre-rolls count on: every ``step()`` call adds
+    exactly one token to every decoding request and one to ``decode_steps``,
+    whether or not the step after it is already in flight."""
+    cfg, params = setup
+    eng = Engine(params, cfg, ServeConfig(slots=2, max_len=32, kv_block=8))
+    rids = [eng.submit(r) for r in _two_long_requests(cfg)]
+    in_flight = []
+    for call in range(1, 10):
+        eng.step()
+        assert eng.metrics.decode_steps == call
+        assert [len(eng.completion_of(rid).tokens) for rid in rids] == [call + 1] * 2
+        in_flight.append(eng._inflight is not None)
+    assert all(in_flight[:8]) and not in_flight[8]   # the ninth is the last token's step
+    assert eng.metrics.steps_ahead == 8
+
+
+@pytest.mark.parametrize("how", ["run", "run_to_an_eos", "close", "reset_metrics"])
+def test_a_step_in_flight_is_coped_with(setup, monkeypatch, how):
+    """``run()``, ``close()`` and ``reset_metrics()`` with a step in flight
+    leave no buffer unread and no request unfinished: ``run()`` ends with
+    nothing in flight, also when the last finish is an eos that was found
+    with the next step already dispatched; ``close()`` waits for the step
+    and drops it; ``reset_metrics()`` leaves it in flight and counts it,
+    whole, where it is emitted."""
+    cfg, params = setup
+    with pytest.MonkeyPatch.context() as mp:
+        _hold_off(mp)
+        want = Engine(params, cfg, ServeConfig(slots=2, max_len=32, kv_block=8)).run(
+            _two_long_requests(cfg))
+    eos = want[0].tokens[5] if how == "run_to_an_eos" else None
+    eng = Engine(params, cfg, ServeConfig(slots=2, max_len=32, kv_block=8, prefix=False))
+    rids = [eng.submit(r) for r in _two_long_requests(cfg, eos)]
+    eng.step()
+    eng.step()
+    step = eng._inflight
+    assert step is not None and step.out is not None
+    if how == "close":
+        eng.close()
+        assert eng._inflight is None
+        assert all(a.is_ready() for a in jax.tree.leaves((eng.cache, eng.state)))
+        return
+    if how == "reset_metrics":
+        eng.reset_metrics()
+        assert eng._inflight is step
+    got = eng.run()
+    assert eng._inflight is None and eng.n_live == 0 and eng._pool.n_used == 0
+    assert not np.asarray(eng.state.live).any() and not np.asarray(eng.cache.lengths).any()
+    for rid in rids:
+        toks = want[rid].tokens
+        if eos in toks:
+            toks = toks[:toks.index(eos) + 1]
+        assert got[rid].tokens == toks and got[rid].finish_reason
+    m = eng.metrics
+    started = 0 if how == "reset_metrics" else 2
+    assert m.device_fetches == m.decode_steps + started
+    if how == "run_to_an_eos":
+        assert "eos" in {c.finish_reason for c in got.values()}
 
 
 @pytest.mark.parametrize("seed", [0, 7, 2**31, 2**40 + 9, -1])

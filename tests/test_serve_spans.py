@@ -55,8 +55,8 @@ def _serve(model, serve_cfg, sizes=SIZES):
 def traced(model, tmp_path_factory, host_trace_events):
     """{"events": [(name, start_ns, end_ns)] of the ``serve.*`` host events,
     "programs": names of the jitted calls, "tokens", "chunk_tokens",
-    "decode_steps"} of one plain and one chunked-prefill engine, both run
-    with the profiler on."""
+    "decode_steps", "steps_ahead"} of one plain and one chunked-prefill
+    engine, both run with the profiler on."""
     plain = ServeConfig(slots=2, max_len=32, kv_block=8)
     chunked = ServeConfig(slots=2, max_len=32, kv_block=8, chunk_tokens=8)
     _serve(model, plain)  # every program built before the trace starts
@@ -72,7 +72,8 @@ def traced(model, tmp_path_factory, host_trace_events):
     return {"events": [e for e in found if e[0].startswith("serve.")],
             "programs": {e[0] for e in found if e[0].startswith("PjitFunction(")},
             "tokens": tokens, "chunk_tokens": chunk_tokens,
-            "decode_steps": eng.metrics.decode_steps + ceng.metrics.decode_steps}
+            "decode_steps": eng.metrics.decode_steps + ceng.metrics.decode_steps,
+            "steps_ahead": eng.metrics.steps_ahead + ceng.metrics.steps_ahead}
 
 
 def _program(event_name):
@@ -101,11 +102,24 @@ def test_phases_nest_as_the_trace_reader_takes_them_to(traced):
     assert _inside(ev, "serve.dispatch", "serve.step")
     assert _inside(ev, "serve.sync", "serve.step")
     assert _inside(ev, "serve.prefill", "serve.admit")
-    # plan, step and emit tile a decode step in that order, side by side
-    order = [n for n, _, _ in sorted(ev, key=lambda e: e[1])
-             if n in ("serve.plan", "serve.step", "serve.emit")]
-    assert order == ["serve.plan", "serve.step", "serve.emit"] * (len(order) // 3)
+    # step and emit tile a ``step()`` call's decode part side by side, after
+    # the plan of the step the call dispatches (none where the step it reads
+    # was already in flight and no other may follow it yet); where the
+    # pipeline fills (nothing was in flight) the NEXT step's plan lies inside
+    # the step, between its two dispatches
+    nested = [e for e in ev if e[0] == "serve.plan" and _within(e, ev, "serve.step")]
+    order = [e[0] for e in sorted(ev, key=lambda e: e[1])
+             if e[0] in ("serve.plan", "serve.step", "serve.emit") and e not in nested]
+    assert [n for n in order if n != "serve.plan"] == \
+        ["serve.step", "serve.emit"] * traced["decode_steps"]
+    assert all(b == "serve.step" for a, b in zip(order, order[1:]) if a == "serve.plan")
     assert not _inside(ev, "serve.step", "serve.plan")
+    dispatches = sorted((e for e in ev if e[0] == "serve.dispatch"), key=lambda e: e[1])
+    for _, s, e in nested:
+        step = next(x for x in ev if x[0] == "serve.step" and x[1] <= s and e <= x[2])
+        first, second = [d for d in dispatches if step[1] <= d[1] and d[2] <= step[2]]
+        assert first[2] <= s and e <= second[1]
+    assert 0 < len(nested) < traced["steps_ahead"]
     # a slot is activated from an admission, or after a chunked prompt's last
     # chunk, which runs from Engine.step itself
     activations = [e for e in ev if e[0] == "serve.activate"]
@@ -120,6 +134,37 @@ def test_one_step_annotation_for_each_counted_decode_step(traced):
         == count["serve.emit"] == count["serve.step"]
     assert count["serve.prefill"] == len(SIZES) + 1  # the chunked prompt's plan too
     assert count["serve.prefill_chunk"] == 3         # 20 tokens in chunks of 8
+
+
+def test_the_trace_reader_tiles_a_run_ahead_engines_steps(traced):
+    """``benchmark.host_spans.phase_segments``, the reader of the traced
+    runs, as it is: an engine that dispatches step N+1 before it reads step
+    N still gives it one ``serve.step`` a counted decode step, which it
+    tiles into ``dispatch`` and ``sync`` with nothing left over, beside the
+    ``admit``, ``plan`` and ``emit`` of the same call (``caller`` is what
+    lies between the segments)."""
+    from benchmark import host_spans
+
+    assert traced["steps_ahead"] > 0
+    ev = traced["events"]
+    segments = host_spans.phase_segments([(n, s, e - s) for n, s, e in ev])
+    assert all(a[1] <= b[0] for a, b in zip(segments, segments[1:]))
+    assert {phase for _, _, phase in segments} == set(host_spans.PHASES) - {"caller"}
+    steps = [e for e in ev if e[0] == "serve.step"]
+    assert len(steps) == traced["decode_steps"]
+    dispatched = 0
+    for _, s, e in steps:
+        inside = [seg for seg in segments if s <= seg[0] and seg[1] <= e]
+        assert sum(b - a for a, b, _ in inside) == e - s
+        phases = [phase for _, _, phase in inside]
+        assert set(phases) <= {"dispatch", "sync"} and phases[-1] == "sync"
+        dispatched += phases.count("dispatch")
+    # a step's dispatch lies in its own serve.step or, run ahead, in the one before
+    assert dispatched == sum(1 for e in ev if e[0] == "serve.dispatch") == len(steps)
+    held = {"dispatch": "serve.dispatch", "plan": "serve.plan", "emit": "serve.emit"}
+    for phase, name in held.items():
+        assert sum(b - a for a, b, p in segments if p == phase) <= \
+            sum(e - s for n, s, e in ev if n == name)
 
 
 def test_traced_calls_carry_the_programs_names(traced):

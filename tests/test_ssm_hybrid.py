@@ -158,7 +158,8 @@ def test_engine_prefill_then_decode_matches_the_reference_logits(model, monkeypa
                                                                  scan_kernel, paged, scanned):
     """Through the ``Engine``: request A (11 tokens, bucket 16) is prefilled
     and decodes three steps alone, then request B (27 tokens, bucket 32) is
-    admitted while A decodes; 11 and 9 decode steps. Every logit row the
+    admitted while A decodes — behind A's fourth step, which is in flight by
+    then, so B decodes from the fifth; 11 and 9 decode steps. Every logit row the
     programs sampled from — the padded-bucket prefill's, then each decode
     step's through both kinds of state — against the reference's full forward
     of prompt + served tokens. Both forms of the paged attention and of the
@@ -179,9 +180,9 @@ def test_engine_prefill_then_decode_matches_the_reference_logits(model, monkeypa
     jax.effects_barrier()
     prefills = [x for x in seen if x.shape[0] == 1]
     decodes = [x for x in seen if x.shape[0] == 2]
-    assert len(prefills) == 2 and len(decodes) == 12
+    assert len(prefills) == 2 and len(decodes) == 13
     s = sizes(cfg)
-    for slot, (rid, first_step) in enumerate([(rid_a, 0), (rid_b, 3)]):
+    for slot, (rid, first_step) in enumerate([(rid_a, 0), (rid_b, 4)]):
         p, toks = prompts[slot], done[rid].tokens
         assert len(toks) == budget[slot]
         seq = np.concatenate([p, np.asarray(toks[:-1], np.int32)])

@@ -178,6 +178,10 @@ class DecodeMetrics:
     # activation, one serve_release a finish)
     device_fetches: int = 0
     slot_programs: int = 0
+    # decode steps dispatched before the step before them was read (the
+    # host's wait for step N under the device's work on N+1); the rest of
+    # decode_steps ran in dispatch-then-read order
+    steps_ahead: int = 0
 
     def record_prompt(self, plen: int, hit_tokens: int = 0) -> None:
         self.prompt_tokens += plen
@@ -211,11 +215,15 @@ class DecodeMetrics:
         self.generated_tokens += 1  # prefill samples the first token
 
     def record_decode(self, dt_s: float, new_tokens: int, live: int,
-                      slots: int, attn_blocks: tuple[int, int] = (0, 0)) -> None:
+                      slots: int, attn_blocks: tuple[int, int] = (0, 0),
+                      ahead: bool = False) -> None:
         """``attn_blocks``: (blocks the live rows reach, slots x table width)
-        of this step, from the host's own bookkeeping."""
+        of this step, from the host's own bookkeeping; ``ahead``: the step
+        was dispatched before the one before it was read, and ``dt_s`` then
+        starts where that one's fetch returned."""
         self.decode_s += dt_s
         self.decode_steps += 1
+        self.steps_ahead += ahead
         self.generated_tokens += new_tokens
         self.decode_tokens += new_tokens
         self.decode_live_sum += live
@@ -278,6 +286,7 @@ class DecodeMetrics:
             "ttft_max_s": round(self.ttft_max_s, 4),
             "slot_occupancy": round(self.slot_occupancy, 3),
             "decode_steps": self.decode_steps,
+            "steps_ahead": self.steps_ahead,
             "requests_finished": self.requests_finished,
             "prefill_compiles": self.prefill_compiles,
             "decode_compiles": self.decode_compiles,

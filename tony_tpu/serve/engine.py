@@ -212,6 +212,30 @@ class _ChunkedPrefill:
     t0: float         # admission start (TTFT spans the whole chunked prefill)
 
 
+@dataclass
+class _InFlight:
+    """One decode step between its plan and its emit — what the host keeps
+    of it while the device runs it, and while the step AFTER it is already
+    dispatched. It holds only results no later program consumes: the cache
+    and the slot state a step returns are donated to the next program, so
+    whether a row hit its eos is read off the fetched tokens (``tok ==
+    eos``; a row that finishes leaves the batch at once, so the host has no
+    use for the device's sticky ``done`` flag)."""
+
+    rows: list[tuple[int, int]]   # (slot, rid) in the decode batch at dispatch
+    drafts: Any                   # [S, k] draft batch of a speculative step, else None
+    dlens: list[int]              # draft tokens per slot (all 0 on a plain step)
+    reached: int                  # table blocks the live rows attend (record_decode)
+    width: int                    # table width the step was dispatched at
+    left: list[int] = field(default_factory=list)  # per row: tokens still owed after this step
+    ahead: bool = False           # dispatched before the step before it was read
+    t0: float = 0.0               # dispatch time
+    out: Any = None               # device results the host reads: (tokens, n_emit | None)
+    aux: dict = field(default_factory=dict)  # rides the fetch; then the health monitors
+    host: Any = None              # ``out`` on the host, once fetched
+    dt: float = 0.0               # wall time charged to the step (Engine._sync)
+
+
 class _SlotState(NamedTuple):
     """Per-slot device state threaded through the jitted decode step."""
 
@@ -405,6 +429,11 @@ class Engine:
         self._slot_rid: list[int | None] = [None] * S
         self._slot_remaining: list[int] = [0] * S
         self._slot_len: list[int] = [0] * S       # host mirror of lengths
+        self._slot_eos: list[int] = [-1] * S      # the request's eos id, -1 = none
+        # the decode step dispatched and not yet emitted (docs/SERVE.md "The
+        # step loop"): None between steps that ran in today's order
+        self._inflight: _InFlight | None = None
+        self._synced_t = 0.0                      # when the last decode fetch returned
         self._submit_t: dict[int, float] = {}
         self._next_rid = 0
         self._prefill_fns: dict[int, Any] = {}
@@ -598,6 +627,11 @@ class Engine:
             # + finishes
             "device_fetches": float(self.metrics.device_fetches),
             "slot_programs": float(self.metrics.slot_programs),
+            # decode steps dispatched before the step before them was read
+            # (over decode_steps: the share of steps whose host wait lay
+            # under the device's work)
+            "decode_steps": float(self.metrics.decode_steps),
+            "steps_ahead": float(self.metrics.steps_ahead),
             # pool label (disaggregated gangs): a string, so it rides the
             # series journal but the numeric AM metrics push drops it —
             # AM-rollup consumers derive the pool from the task type instead
@@ -709,6 +743,10 @@ class Engine:
             "tony_serve_quant_pool_resident_bytes",
             "HBM resident in the quantized KV pool (payload + scale rows)",
         )
+        self._c_steps_ahead = reg.counter(
+            "tony_serve_steps_ahead_total",
+            "decode steps dispatched before the step before them was read",
+        )
         self._c_handoff_shipped = reg.counter(
             "tony_serve_handoff_shipped_blocks_total",
             "physical blocks exported for a blockwise KV handoff",
@@ -727,7 +765,8 @@ class Engine:
         that paid the compiles); compile counts persist — they describe
         the engine, not the trace. The registry histograms reset too, or
         close()'s TTFT/TPOT quantiles and the job-history snapshot would
-        blend warmup compile time into the measured trace."""
+        blend warmup compile time into the measured trace. A step in
+        flight stays in flight: it is counted, whole, where it is emitted."""
         self.metrics = DecodeMetrics(
             n_chips=self.metrics.n_chips,
             prefill_compiles=len(self._prefill_fns) + len(self._tail_fns),
@@ -755,6 +794,11 @@ class Engine:
                 sp.end(reason="shutdown")
             spans.clear()
         self._first_tok_t.clear()
+        if self._inflight is not None:
+            # a step still in flight: wait for it, so the device is quiet
+            # when close() returns, and drop what it sampled
+            jax.block_until_ready(self._inflight.out)
+            self._inflight = None
         # a profile window still open at shutdown finalises (partial trace
         # + manifest land) instead of dying with the engine
         profile.finish_capture()
@@ -822,7 +866,12 @@ class Engine:
         return s
 
     def step(self) -> int:
-        """Admit what fits, run one decode step; returns live-slot count."""
+        """Admit what fits, make ONE decode step's tokens visible; returns
+        live-slot count. The device may be one step further: when ``step()``
+        returns, the step after the one just emitted can already be in
+        flight (:meth:`_decode_once`), and ``self.cache`` / ``self.state``
+        are then that step's results. Callers read tokens and finishes from
+        completions, never from device state."""
         # coordinated-profiling seam (one global load + None compare
         # disarmed): a broadcast window brackets decode steps exactly like
         # train steps, so `tony profile` anatomises serving hosts too
@@ -993,6 +1042,7 @@ class Engine:
         points mid-prompt); the final chunk's sample IS the request's
         first token — same logits, same key as an unchunked prefill, so
         chunking is draw-for-draw invisible in the output."""
+        self._drain()
         job = self._chunking[slot]
         plen = len(job.prompt)
         end = min(job.pos + self.serve.chunk_tokens, plen)
@@ -1021,12 +1071,17 @@ class Engine:
         device (``metrics.device_fetches`` counts the calls: one a decode
         step, one a prefill's first token, one a chunk that counted routes),
         and the one reading of a step's ``aux``: ``out`` comes to the host —
-        a decode step's sampled tokens with its ``done`` flags and, on a
-        speculative step, ``n_emit`` — and with it, in the SAME
-        ``device_get``, the expert routes a latent-attention step counted.
-        Whatever the host needs of a program rides here: a second blocking
-        transfer, with nothing queued on the chip, cost ~2 ms a step for the
-        routes and 0.5 ms for the flags (PERF.md §6, PRs 27 and 32).
+        a decode step's sampled tokens and, on a speculative step,
+        ``n_emit`` — and with it, in the SAME ``device_get``, the expert
+        routes a latent-attention step counted. Whatever the host needs of
+        a program rides here: a second blocking transfer, with nothing
+        queued on the chip, cost ~2 ms a step for the routes and 0.5 ms for
+        the ``done`` flags (PERF.md §6, PRs 27 and 32). The flags are not
+        fetched at all since steps overlap: they live in the slot state,
+        which the NEXT step's dispatch donates before this one is read, so
+        the host reads a finish off the tokens (``tok == eos``). ``out`` is
+        a result of its own of the program (``nxt`` beside ``state.last_tok``)
+        and outlives that donation.
         Returns ``(out on the host, what is left of aux)`` — the health
         monitors, which stay device references for the sentinel's worker
         thread."""
@@ -1069,6 +1124,7 @@ class Engine:
         )
         self.metrics.slot_programs += 1
         self._slot_rid[slot] = rid
+        self._slot_eos[slot] = eos
         self._slot_remaining[slot] = req.max_new_tokens
         comp = Completion(
             rid=rid, tokens=[tok], prompt_len=plen,
@@ -1115,8 +1171,16 @@ class Engine:
         if dspan is not None:
             dspan.end(tokens=len(comp.tokens), reason=reason)
         self._slot_rid[slot] = None
+        self._slot_eos[slot] = -1
         self._slot_remaining[slot] = 0
         self._slot_len[slot] = 0
+        ran_on = self._inflight
+        if ran_on is not None and not any(
+                self._slot_rid[s] == r for s, r in ran_on.rows):
+            # every row of the step in flight has finished since its
+            # dispatch: nobody is left to read it, and device order keeps
+            # whatever follows behind it
+            self._inflight = None
         # ``lengths`` alone, not the cache: the pools are no argument of it
         self.state, lengths = _release_fn()(
             self.state, self.cache.lengths, np.int32(slot))
@@ -1356,6 +1420,7 @@ class Engine:
         self._refuse_block_handoff()
         if self._store is None:
             return None
+        self._drain()
         B = self.serve.kv_block
         toks = [int(t) for t in tokens]
         n_full = len(toks) // B
@@ -1390,6 +1455,7 @@ class Engine:
         raises ValueError on an incompatible payload (the gang worker
         maps it to an error response, never a corrupted pool)."""
         self._refuse_block_handoff()
+        self._drain()
         B = self.serve.kv_block
         nb = payload.n_blocks
         if len(tokens) != nb * B:
@@ -1441,6 +1507,7 @@ class Engine:
         while new // 2 >= target and new // 2 >= self._p0:
             new //= 2
         if new < self._pool.n_blocks:
+            self._drain()
             self.cache = shrink_cache(self.cache, new)
             self._pool.shrink(new)
 
@@ -1455,7 +1522,10 @@ class Engine:
             cur = max(need, 1)
         if cur != self._attended or self._table_dirty:
             self._attended = cur
-            self._table_dev = jnp.asarray(self._table[:, :cur])
+            # a private copy: the upload may alias the host array (the CPU
+            # backend) or still be on its way when the NEXT step's plan
+            # writes ``_table``, while the step dispatched with it runs
+            self._table_dev = jnp.asarray(self._table[:, :cur].copy())
             self._table_dirty = False
 
     # --- jitted steps ---------------------------------------------------------
@@ -1544,26 +1614,89 @@ class Engine:
         return drafts, dlens
 
     def _decode_once(self) -> None:
-        # device-timeline bridge: the host phases of one decode step as
-        # profiler annotations (plan / step{dispatch, sync} / emit), so a
-        # capture names every idle gap; request identity stays in the
-        # journal spans, the profiler side carries phase names only
+        """One decode step's tokens become visible — the OLDEST unread
+        step's. A pipeline of depth one between host and device: where the
+        next step's plan needs nothing these tokens decide
+        (:meth:`_may_run_ahead`), it is dispatched BEFORE they are read, so
+        the device runs step N+1 while the host reads, emits and hands to
+        its caller step N. Same programs in the same order on the device,
+        the same tokens to the same requests; only the host's wait moves
+        under the device's work (docs/SERVE.md "The step loop").
+
+        Device-timeline bridge: the host phases of one decode step as
+        profiler annotations (plan / step{dispatch, sync} / emit), so a
+        capture names every idle gap; request identity stays in the journal
+        spans, the profiler side carries phase names only. In the steady
+        state a call is plan(N+1) / step{dispatch(N+1), sync(N)} / emit(N);
+        with nothing in flight it is today's plan(N) / step{dispatch(N),
+        sync(N)} / emit(N), with N+1's plan and dispatch inside the step,
+        before the sync, where the pipeline fills."""
+        head, ahead = self._inflight, None
+        fresh = head is None        # nothing in flight: today's order
+        if fresh:
+            head = self._plan()
+        elif self._may_run_ahead(head):
+            ahead = self._plan()
+        tracer = trace.active_tracer()
+        sp = trace.NOOP_SPAN
+        if tracer is not None:
+            sp = tracer.sampled_span("serve.step", live=len(head.rows))
+        with sp, annotate("serve.step"):
+            if fresh:
+                self._dispatch(head)
+                if self._may_run_ahead(head):
+                    ahead = self._plan()
+            if ahead is not None:
+                ahead.ahead = True
+                self._dispatch(ahead)
+            self._sync(head)
+        self._inflight = ahead
+        self._emit(head)
+
+    def _may_run_ahead(self, step: _InFlight) -> bool:
+        """May the step after ``step`` be dispatched before ``step``'s
+        tokens are read? Only if its plan needs nothing they decide:
+
+        - no row of ``step`` is on its LAST token by length — a finish the
+          host can foresee ends at a boundary with nothing queued, so the
+          caller's next request is admitted, and its prefill starts, on an
+          idle device exactly as without a pipeline (a queued decode step
+          would sit in front of every such first token);
+        - no request waits for a slot that is already free: the next call
+          admits it, and the step after ``step`` is the first it decodes in;
+        - the engine does not speculate: drafts are proposed on the host
+          from the tokens just read;
+        - no prompt is prefilling in chunks: a chunk, and the activation
+          after the last one, run between steps on a drained device.
+
+        Decided from what the engine observes at each step; no knob. An eos
+        cannot be foreseen: it is found one device step late (:meth:`_emit`),
+        and the request admitted into its slot — like one that arrives from
+        outside between two calls — starts behind the step in flight and
+        decodes from the step after it."""
+        last = any(n <= 0 for n in step.left)
+        admits = bool(self._queue) and self.n_live < self.serve.slots
+        return not (last or admits or self.serve.spec or self._chunking)
+
+    def _plan(self) -> _InFlight:
+        """Per-step block planning: a live row allocates blocks NOW to
+        cover every position this step may write (host-side, before
+        dispatch) — position pos autoregressively, pos..pos+draft_len
+        speculatively; the attended table width tracks the live maximum.
+        Reads the host bookkeeping as the step before it left it at ITS
+        dispatch, so it is right whether or not that step has been read."""
         with annotate("serve.plan"):
-            # per-step block planning: a live row allocates blocks NOW to
-            # cover every position this step may write (host-side, before
-            # dispatch) — position pos autoregressively, pos..pos+draft_len
-            # speculatively; the attended table width tracks the live
-            # maximum
             B = self.serve.kv_block
-            live_before = [
-                s for s, r in enumerate(self._slot_rid)
+            rows = [
+                (s, r) for s, r in enumerate(self._slot_rid)
                 if r is not None and s not in self._chunking
             ]
-            drafts_np, dlens = self._propose_step_drafts(live_before)
+            live = [s for s, _ in rows]
+            drafts_np, dlens = self._propose_step_drafts(live)
             spec_step = any(dlens)
             need = 1
             reached = 0     # table blocks the live rows attend this step
-            for s in live_before:
+            for s in live:
                 last = self._slot_len[s] + (dlens[s] if spec_step else 0)
                 while self._slot_blocks[s] * B <= last:
                     self._table[s, self._slot_blocks[s]] = self._alloc_block()
@@ -1574,80 +1707,134 @@ class Engine:
             if self.cache.quantized:
                 self._flush_fresh_scales()
             self._set_attended(need)
-        tracer = trace.active_tracer()
-        sp = trace.NOOP_SPAN
-        if tracer is not None:
-            sp = tracer.sampled_span("serve.step", live=len(live_before))
-        with sp, annotate("serve.step"):
-            t0 = time.perf_counter()
-            with annotate("serve.dispatch"):
-                sig = (self.cache.n_blocks, self._attended)
-                if spec_step:
-                    self.cache, self.state, toks, n_emit, aux = \
-                        self._get_decode(sig, self.serve.spec_max_draft)(
-                            self._dec_params, self.cache, self._table_dev,
-                            self.state, drafts_np, np.asarray(dlens, np.int32),
-                        )
-                else:
-                    # no live slot drafted: the plain 1-wide step (also the
-                    # only step compiled with spec off — same signatures as
-                    # the pre-spec engine)
-                    self.cache, self.state, toks, aux = \
-                        self._get_decode(sig)(
-                            self._dec_params, self.cache, self._table_dev,
-                            self.state,
-                        )
-            # EXPLICIT per-step sync: continuous batching needs the sampled
-            # tokens + done flags on host to steer admission — this is the
-            # engine's one designed sync point per decode step, and its one
-            # transfer: the flags (and a speculative step's emit counts)
-            # ride the tokens' fetch
-            with annotate("serve.sync"):
-                (toks_np, emit_np, done_np), hmon = self._fetch(
-                    (toks, n_emit if spec_step else None, self.state.done),
-                    aux)
-            dt = time.perf_counter() - t0
+            return _InFlight(
+                rows=rows, drafts=drafts_np if spec_step else None,
+                dlens=dlens, reached=reached, width=self._attended,
+            )
+
+    def _dispatch(self, step: _InFlight) -> None:
+        """Launch ``step``'s program and advance what PLANNING reads —
+        ``_slot_len``, ``_slot_remaining`` — by what the step is known to
+        do: one position a live row (a speculative step's further emitted
+        tokens are added at emit; nothing is planned between). What CALLERS
+        see advances at emit."""
+        step.t0 = time.perf_counter()
+        with annotate("serve.dispatch"):
+            sig = (self.cache.n_blocks, self._attended)
+            if step.drafts is not None:
+                self.cache, self.state, toks, n_emit, step.aux = \
+                    self._get_decode(sig, self.serve.spec_max_draft)(
+                        self._dec_params, self.cache, self._table_dev,
+                        self.state, step.drafts,
+                        np.asarray(step.dlens, np.int32),
+                    )
+            else:
+                # no live slot drafted: the plain 1-wide step (also the
+                # only step compiled with spec off — same signatures as
+                # the pre-spec engine)
+                n_emit = None
+                self.cache, self.state, toks, step.aux = \
+                    self._get_decode(sig)(
+                        self._dec_params, self.cache, self._table_dev,
+                        self.state,
+                    )
+            step.out = (toks, n_emit)
+        for s, _ in step.rows:
+            self._slot_len[s] += 1
+            self._slot_remaining[s] -= 1
+            step.left.append(self._slot_remaining[s])
+
+    def _sync(self, step: _InFlight) -> None:
+        """EXPLICIT per-step sync: continuous batching needs the sampled
+        tokens on host to steer admission — the engine's one designed sync
+        point per decode step, and its one transfer (a speculative step's
+        emit counts and the expert routes ride the tokens' fetch). A step's
+        wall time runs from the later of its own dispatch and the previous
+        step's fetch returning, to its own fetch returning: ``decode_s``
+        stays the wall time spent on decode steps and counts no millisecond
+        twice when two steps overlap. Does nothing for a step already
+        brought to the host (:meth:`_drain`)."""
+        if step.out is None:
+            return
+        with annotate("serve.sync"):
+            step.host, step.aux = self._fetch(step.out, step.aux)
+        step.out = None
+        now = time.perf_counter()
+        step.dt = now - max(step.t0, self._synced_t)
+        self._synced_t = now
+
+    def _drain(self) -> None:
+        """Wait for the step in flight and bring its results to the host
+        now (they stay on the record; the next ``step()`` emits them). What
+        rewrites slots or pools between steps on its own — a prefill chunk,
+        the gang's block hand-off, a pool shrink — calls this first and then
+        runs on a drained device, as it did before steps overlapped."""
+        if self._inflight is not None:
+            self._sync(self._inflight)
+
+    def _emit(self, step: _InFlight) -> None:
+        """What CALLERS see advances here: tokens to completions, finish
+        reasons, freed slots, metrics. A row whose request finished since
+        the dispatch — an eos found in the step before, which was read only
+        after this one went out — no longer owns its slot, also when a new
+        request was admitted into it in between: the row ran one more step
+        on the device (it re-emitted its eos into a position whose block
+        the plan allocated; ``done`` rows are harmless by construction) and
+        its tokens of this step are dropped."""
         with annotate("serve.emit"):
+            toks_np, emit_np = step.host
+            owned = [(s, n) for (s, r), n in zip(step.rows, step.left)
+                     if self._slot_rid[s] == r]
+            live = [s for s, _ in owned]
+            spec_step = emit_np is not None
             if spec_step:
-                new_total = int(sum(int(emit_np[s]) for s in live_before))
-                prop_total = sum(dlens[s] for s in live_before)
-                acc_total = sum(max(int(emit_np[s]) - 1, 0) for s in live_before)
+                new_total = int(sum(int(emit_np[s]) for s in live))
+                prop_total = sum(step.dlens[s] for s in live)
+                acc_total = sum(max(int(emit_np[s]) - 1, 0) for s in live)
                 self.metrics.record_spec(prop_total, acc_total)
                 self._c_draft_prop.inc(prop_total)
                 self._c_draft_acc.inc(acc_total)
             else:
-                new_total = len(live_before)
+                new_total = len(live)
             self.metrics.record_decode(
-                dt, new_total, len(live_before), self.serve.slots,
-                attn_blocks=(reached, self.serve.slots * self._attended),
+                step.dt, new_total, len(live), self.serve.slots,
+                attn_blocks=(step.reached, self.serve.slots * step.width),
+                ahead=step.ahead,
             )
+            if step.ahead:
+                self._c_steps_ahead.inc()
             hbm.sample()  # stride-counted device-memory reading (no sync)
+            hmon = step.aux
             if hmon:
                 # stride-counted health sample: DEVICE references + the host
                 # slot->request map for per-request trip attribution; the
                 # device_get sync happens on the sentinel's worker thread
                 slot_rids = list(self._slot_rid)
                 health.sample(
-                    metrics=hmon, slot_rids=slot_rids, live_slots=live_before
+                    metrics=hmon, slot_rids=slot_rids, live_slots=live
                 )
             series.sample()  # stride-counted scrape of the attached sources
-            self._h_step.observe(dt)
+            self._h_step.observe(step.dt)
             self._c_tokens.inc(new_total)
-            for s in live_before:
+            for s, left in owned:
                 if spec_step:
                     n = int(emit_np[s])
                     new_toks = [int(t) for t in toks_np[s, :n]]
                 else:
                     n = 1
                     new_toks = [int(toks_np[s])]
-                self._slot_len[s] += n
+                # the dispatch counted one position; a speculative step's rest
+                self._slot_len[s] += n - 1
+                self._slot_remaining[s] -= n - 1
                 self._completions[self._slot_rid[s]].tokens.extend(new_toks)
                 if self.serve.spec:
                     self._slot_ctx[s].extend(new_toks)
-                self._slot_remaining[s] -= n
-                if done_np[s]:
+                # emission stops AT an eos (serve/spec.py), so it is the last
+                if new_toks[-1] == self._slot_eos[s]:
                     self._finish(s, "eos")
-                elif self._slot_remaining[s] <= 0:
+                elif left - (n - 1) <= 0:
+                    # by what THIS step left owed: ``_slot_remaining`` may
+                    # already count the step dispatched after it
                     self._finish(s, "length")
 
 
@@ -1746,8 +1933,11 @@ def _decode_fn(cfg: LlamaConfig, decode_impl: str, kv_block: int,
     ``draft_k`` it is the speculative verify step ``jit_serve_spec_decode``
     (two more arguments, ``n_emit`` among its results); both run the
     family's one ``decode_step``. What the host needs of a step — the
-    tokens, the new state's ``done`` flags, ``n_emit``, the routes ``aux``
-    counts — it reads in the ONE ``device_get`` of ``Engine._fetch``.
+    tokens, ``n_emit``, the routes ``aux`` counts — it reads in the ONE
+    ``device_get`` of ``Engine._fetch``, possibly AFTER the next step was
+    dispatched: only results of their own, never a field of the new state
+    (donated by then; ``done`` stays on the device, the host derives a
+    finish from the tokens).
 
     Contract: the cache (arg 1) and the slot state (arg 3) are DONATED and
     the pools come back as the same buffers — carried through the layer
