@@ -376,8 +376,7 @@ def test_engine_serves_mixed_requests_and_returns_every_slot_and_block(model, se
 def test_engine_through_the_paged_kernel_serves_the_scan_paths_tokens(model, paged_kernel):
     """The same requests end to end with the paged attention as the XLA scan
     and as the Pallas kernel (interpreted; on the chip the op picks it): the
-    same greedy tokens, and the counters say how little of the table the
-    kernel had to read."""
+    same greedy tokens."""
     outs = []
     for kernel in (False, True):
         paged_kernel(kernel)
@@ -386,8 +385,6 @@ def test_engine_through_the_paged_kernel_serves_the_scan_paths_tokens(model, pag
                 for i, n in enumerate([5, 17, 30, 41])]
         done = eng.run()
         outs.append([done[r].tokens for r in rids])
-        m = eng.metrics
-        assert 0 < m.attn_blocks_live < m.attn_blocks_table <= m.decode_steps * 3 * (96 // 8)
     assert outs[0] == outs[1]
 
 

@@ -564,18 +564,22 @@ def test_disarmed_annotate_is_within_noise_of_noop():
     from tony_tpu.obs.profiler import annotate
 
     N = 50_000
-    for _ in range(1000):
-        with annotate("serve.plan"):
-            pass
-    per_call = math.inf
-    for _ in range(5):
-        t0 = time.perf_counter()
-        for _ in range(N):
-            with annotate("serve.plan"):
+    # a phase name takes no argument; a marker (one a decode step, at the end
+    # of Engine._emit) carries the step's numbers, which must not be encoded
+    # with no session on: both forms under the one bound
+    for name, numbers in (("serve.plan", {}), ("serve.ahead", dict(n=7, gap_us=18000))):
+        for _ in range(1000):
+            with annotate(name, **numbers):
                 pass
-        per_call = min(per_call, (time.perf_counter() - t0) / N)
-    assert per_call < 5e-6, (
-        f"disarmed annotate costs {per_call * 1e9:.0f}ns/block — the no-op "
-        "path regressed (is a profiler session left on, or is annotate "
-        "doing work of its own?)"
-    )
+        per_call = math.inf
+        for _ in range(5):
+            t0 = time.perf_counter()
+            for _ in range(N):
+                with annotate(name, **numbers):
+                    pass
+            per_call = min(per_call, (time.perf_counter() - t0) / N)
+        assert per_call < 5e-6, (
+            f"disarmed annotate({name!r}) costs {per_call * 1e9:.0f}ns/block — "
+            "the no-op path regressed (is a profiler session left on, or is "
+            "annotate doing work of its own?)"
+        )

@@ -295,7 +295,7 @@ class TestTonyTop:
         (sdir / "decode_0_user.jsonl").write_text("".join(
             json.dumps({
                 "ts": now - 10 + i, "queue_depth": i, "occupancy": 0.5,
-                "ttft_p99_s": 0.2,
+                "ttft_p99_s": 0.2, "stalled_steps": 3.0,
             }) + "\n"
             for i in range(8)
         ))
@@ -321,6 +321,10 @@ class TestTonyTop:
         frame = render(view)
         assert "decode_0_user" in frame and "TRIP:ttft_p99_s" in frame
         assert "ttft_p99" in frame  # the column header
+        # the engine's stalled decode steps have their column (the other row has none)
+        head, row = (next(ln for ln in frame.splitlines() if ln.startswith(k))
+                     for k in ("proc", "decode_0_user"))
+        assert row[head.index("stalls"):head.index("stalls") + 6].split() == ["3"]
         # sparkline maths: monotone values render monotone glyphs
         s = sparkline([0, 1, 2, 3])
         assert len(s) == 4 and s[0] == "▁" and s[-1] == "█"

@@ -29,6 +29,10 @@ PHASES = {
     "serve.admit", "serve.prefill", "serve.prefill_chunk", "serve.activate",
     "serve.plan", "serve.step", "serve.dispatch", "serve.sync", "serve.emit",
 }
+# one marker a decode step, named by why it ran as it did, and one a request
+STEP_MARKERS = {"serve.ahead", "serve.kept_finish", "serve.kept_admit", "serve.kept_spec",
+                "serve.kept_chunk", "serve.fresh"}
+MARKERS = STEP_MARKERS | {"serve.visible"}
 
 
 @pytest.fixture(scope="module")
@@ -69,7 +73,11 @@ def traced(model, tmp_path_factory, host_trace_events):
     finally:
         tracing.stop()
     found = host_trace_events(log_dir)
-    return {"events": [e for e in found if e[0].startswith("serve.")],
+    return {"events": [e for e in found if e[0].startswith("serve.") and e[0] not in MARKERS],
+            "markers": [e for e in found if e[0] in MARKERS], "log_dir": log_dir,
+            "kept": {why: eng.metrics.steps_kept[why] + ceng.metrics.steps_kept[why]
+                     for why in eng.metrics.steps_kept},
+            "fresh": eng.metrics.steps_fresh + ceng.metrics.steps_fresh,
             "programs": {e[0] for e in found if e[0].startswith("PjitFunction(")},
             "tokens": tokens, "chunk_tokens": chunk_tokens,
             "decode_steps": eng.metrics.decode_steps + ceng.metrics.decode_steps,
@@ -95,6 +103,60 @@ def test_every_phase_of_the_step_is_annotated(traced):
     assert names == PHASES
     # the reducer keeps a host event only if its name fits this pattern
     assert all(HOST_SPAN.match(n) for n in names)
+
+
+def _arguments(log_dir):
+    """{event name: [its arguments as a dict, one an event]} of the ``serve.*``
+    host events: this jaxlib gives an annotation's keyword arguments as the
+    event's ``stats`` and leaves the name bare."""
+    import glob
+    import os
+
+    (path,) = glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"), recursive=True)
+    found = {}
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name.startswith("serve."):
+                        found.setdefault(e.name, []).append(dict(e.stats))
+    return found
+
+
+def test_the_phase_names_take_no_argument_and_the_markers_enclose_none(traced):
+    """What reads the capture today (``benchmark/host_spans.phase_segments``,
+    ``trace_reduce.HOST_SPAN``) matches the phase names whole and takes a
+    top-level ``serve.*`` span as the phase: the nine names stay bare and
+    without arguments, and a marker is an EMPTY block — no phase begins
+    inside one — whose numbers are the step's or the request's."""
+    args = _arguments(traced["log_dir"])
+    assert PHASES | MARKERS >= set(args) >= PHASES
+    assert all(a == {} for name in PHASES for a in args[name])
+    ev = traced["events"]
+    for _, ms, me in traced["markers"]:
+        assert not any(ms <= s < me for _, s, _ in ev)
+    steps = [a for name in STEP_MARKERS for a in args.get(name, [])]
+    assert len(steps) == traced["decode_steps"]
+    # each argument has its reader (docs/OBS.md): none rides along unread
+    assert all(set(a) == {"n", "admitted", "admit_us", "gap_us"} for a in steps)
+    assert all((a["admitted"] == 0) == (a["admit_us"] == 0) and a["admit_us"] <= a["gap_us"]
+               for a in steps)
+    # two engines, each counting its steps from 0
+    ordinals = sorted(a["n"] for a in steps)
+    assert ordinals[:2] == [0, 0] and ordinals[-1] < traced["decode_steps"]
+    assert all(isinstance(v, int) and v >= 0 for a in steps for v in a.values())
+    assert len(args["serve.ahead"]) == traced["steps_ahead"]
+    for why, n in traced["kept"].items():
+        assert len(args.get("serve.kept_" + why, [])) == n, why
+    assert traced["kept"]["finish"] > 0
+    assert len(args["serve.fresh"]) == traced["fresh"] > 0
+    visible = args["serve.visible"]
+    assert len(visible) == len(SIZES) + 1
+    assert all(set(a) == {"queue_us", "behind_us", "prefill_us", "activate_us", "held_us"}
+               for a in visible)
+    assert all(v >= 0 for a in visible for v in a.values())
+    # every marker's name passes the reducer's pattern as the phases' do
+    assert all(HOST_SPAN.match(n) for n in args)
 
 
 def test_phases_nest_as_the_trace_reader_takes_them_to(traced):

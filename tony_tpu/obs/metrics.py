@@ -8,7 +8,8 @@ north-star metric (BASELINE.md: >= 45% MFU target).
 
 from __future__ import annotations
 
-from dataclasses import dataclass
+from collections import deque
+from dataclasses import dataclass, field
 from typing import Any
 
 import jax
@@ -124,6 +125,16 @@ class StepTimer:
         return self.tokens_per_sec_per_chip * self.flops_per_token / peak
 
 
+# why a decode step ran as it did (Engine._may_run_ahead): dispatched ahead of
+# the read before it; kept by the first condition that said no; or dispatched
+# with nothing in flight and no verdict before it
+KEPT_REASONS = ("finish", "admit", "spec", "chunk")
+STEP_REASONS = ("ahead", *KEPT_REASONS, "fresh")
+STALL_FACTOR = 4.0    # a gap beyond this many running medians of its kind is a stall
+MEDIAN_WINDOW = 32    # gaps of one reason the running median is taken over
+MEDIAN_MIN = 8        # ... and how many it needs before it calls a stall
+
+
 @dataclass
 class DecodeMetrics:
     """Serving-side counters fed by the decode engine (serve/engine.py).
@@ -162,11 +173,6 @@ class DecodeMetrics:
     moe_tokens: int = 0            # tokens routed (prompt + decode)
     moe_experts_hit: Any = None    # [expert layers] int64, summed over steps
     moe_steps: int = 0             # decode steps counted in moe_experts_hit
-    # paged decode attention, summed over decode steps: the table blocks the
-    # live rows reach (what the kernel has to read) and the blocks the step's
-    # table names for all slots (what a gather of every entry reads)
-    attn_blocks_live: int = 0
-    attn_blocks_table: int = 0
     # the second kind of state (a family's ``slot_state``, docs/SERVE.md):
     # bytes resident for all slots, and prefill or chunk results written into
     # a slot's (admissions + chunk boundaries); both 0 for a family without
@@ -180,8 +186,36 @@ class DecodeMetrics:
     slot_programs: int = 0
     # decode steps dispatched before the step before them was read (the
     # host's wait for step N under the device's work on N+1); the rest of
-    # decode_steps ran in dispatch-then-read order
+    # decode_steps ran in dispatch-then-read order: ``steps_kept`` counts
+    # them by the FIRST condition of ``Engine._may_run_ahead`` that said no
+    # (KEPT_REASONS), ``steps_fresh`` those dispatched with nothing in flight
+    # and no such verdict before them (the first step after the engine was
+    # idle). steps_ahead + sum(steps_kept) + steps_fresh == decode_steps
     steps_ahead: int = 0
+    steps_kept: dict = field(default_factory=lambda: dict.fromkeys(KEPT_REASONS, 0))
+    steps_fresh: int = 0
+    # per reason (STEP_REASONS), summed over its steps: the step's visible
+    # gap (the emit before it ended -> its own emit ended: what one
+    # inter-token sample is) and its share of ``decode_s``
+    step_gap_s: dict = field(default_factory=lambda: dict.fromkeys(STEP_REASONS, 0.0))
+    step_dt_s: dict = field(default_factory=lambda: dict.fromkeys(STEP_REASONS, 0.0))
+    # a request's time to first token, tiled (docs/SERVE.md "The step
+    # loop"): five consecutive differences of one clock, summed over the
+    # requests started; they add up to submit -> the ``step()`` call that
+    # admitted the request returning, which is when a caller sees the token
+    ttft_queue_s: float = 0.0      # submit -> the admission round that dequeues it begins
+    ttft_behind_s: float = 0.0     # round begins -> its own prefill is dispatched
+    ttft_prefill_s: float = 0.0    # prefill dispatched -> its first token on the host
+    ttft_activate_s: float = 0.0   # token on the host -> the slot is activated
+    ttft_held_s: float = 0.0       # activated -> step() returns to its caller
+    # a step whose gap, less what the admissions of the same call took,
+    # exceeds STALL_FACTOR x the running median of its own reason's is a
+    # stall: counted, with the seconds beyond that median (the engine logs
+    # each once, with the host phase that held most of it)
+    stalled_steps: int = 0
+    stalled_s: float = 0.0
+    _gaps: dict = field(default_factory=lambda: {
+        r: deque(maxlen=MEDIAN_WINDOW) for r in STEP_REASONS}, repr=False)
 
     def record_prompt(self, plen: int, hit_tokens: int = 0) -> None:
         self.prompt_tokens += plen
@@ -214,22 +248,59 @@ class DecodeMetrics:
         self.requests_started += 1
         self.generated_tokens += 1  # prefill samples the first token
 
+    def record_visible(self, queue_s: float, behind_s: float, prefill_s: float,
+                       activate_s: float, held_s: float) -> None:
+        """One request's first token became visible to its caller: the five
+        parts of its time to first token (``requests_started`` counts the
+        requests, :meth:`record_prefill`)."""
+        self.ttft_queue_s += queue_s
+        self.ttft_behind_s += behind_s
+        self.ttft_prefill_s += prefill_s
+        self.ttft_activate_s += activate_s
+        self.ttft_held_s += held_s
+
     def record_decode(self, dt_s: float, new_tokens: int, live: int,
-                      slots: int, attn_blocks: tuple[int, int] = (0, 0),
-                      ahead: bool = False) -> None:
-        """``attn_blocks``: (blocks the live rows reach, slots x table width)
-        of this step, from the host's own bookkeeping; ``ahead``: the step
-        was dispatched before the one before it was read, and ``dt_s`` then
-        starts where that one's fetch returned."""
+                      slots: int, why: str = "fresh") -> None:
+        """``why``: one of STEP_REASONS — ``ahead`` where the step was
+        dispatched before the one before it was read (``dt_s`` then starts
+        where that one's fetch returned), else what kept it."""
         self.decode_s += dt_s
         self.decode_steps += 1
-        self.steps_ahead += ahead
+        if why == "ahead":
+            self.steps_ahead += 1
+        elif why == "fresh":
+            self.steps_fresh += 1
+        else:
+            self.steps_kept[why] += 1
+        self.step_dt_s[why] += dt_s
         self.generated_tokens += new_tokens
         self.decode_tokens += new_tokens
         self.decode_live_sum += live
         self.occupancy_sum += live / max(slots, 1)
-        self.attn_blocks_live += attn_blocks[0]
-        self.attn_blocks_table += attn_blocks[1]
+
+    def record_step(self, why: str, gap_s: float, admit_s: float = 0.0) -> bool:
+        """One emitted decode step's visible gap, ``admit_s`` of it spent in
+        the admissions (prefills, chunks) of the same ``step()`` call. True
+        if the step stalled: the rest of the gap lies beyond STALL_FACTOR x
+        the running median of its reason's, once MEDIAN_MIN of them are
+        known. A stalled step does not enter the median's window."""
+        self.step_gap_s[why] += gap_s
+        own = gap_s - admit_s
+        gaps = self._gaps[why]
+        if len(gaps) >= MEDIAN_MIN:
+            median = sorted(gaps)[len(gaps) // 2]
+            if own > STALL_FACTOR * median:
+                self.stalled_steps += 1
+                self.stalled_s += own - median
+                return True
+        gaps.append(own)
+        return False
+
+    def steps_of(self, why: str) -> int:
+        """Decode steps counted under the reason ``why`` (STEP_REASONS)."""
+        if why == "ahead":
+            return self.steps_ahead
+        return self.steps_fresh if why == "fresh" else self.steps_kept[why]
 
     @property
     def elapsed_s(self) -> float:
@@ -287,6 +358,7 @@ class DecodeMetrics:
             "slot_occupancy": round(self.slot_occupancy, 3),
             "decode_steps": self.decode_steps,
             "steps_ahead": self.steps_ahead,
+            "stalled_steps": self.stalled_steps,
             "requests_finished": self.requests_finished,
             "prefill_compiles": self.prefill_compiles,
             "decode_compiles": self.decode_compiles,

@@ -109,17 +109,22 @@ def trace_window(log_dir: str, enabled: bool = True):
             log.info("profiler trace written to %s", handle.path)
 
 
-def annotate(name: str):
+def annotate(name: str, **numbers):
     """Named region in traces: ``with annotate('data-load'): ...``
 
     The one spelling of ``jax.profiler.TraceAnnotation`` in ``tony_tpu/``
     (train/loop.py, serve/engine.py, obs/profile.py). Pass a literal, bare
-    dotted name (``serve.plan``): no arguments, no digits — request identity
-    belongs in the journal span beside it (obs/trace.py). With no profiler
-    session on, a block costs one small object and the profiler's own
-    active check (tests/test_perf_guard.py holds it to the other disarmed
-    hooks' bound)."""
-    return jax.profiler.TraceAnnotation(name)
+    dotted name (``serve.plan``), no digits. ``numbers`` are numeric
+    arguments of the event — durations in microseconds, counts, a step's
+    ordinal — and only for a name that has them at every call (the engine's
+    markers ``serve.ahead`` ... ``serve.visible``): the phase names that
+    readers match whole (``serve.plan``, ``train.step``) take none, ever.
+    Never a request id: request identity belongs in the journal span beside
+    it (obs/trace.py). With no profiler session on, a block costs one small
+    object and the profiler's own active check, and the numbers are not
+    encoded (tests/test_perf_guard.py holds both forms to the other
+    disarmed hooks' bound)."""
+    return jax.profiler.TraceAnnotation(name, **numbers)
 
 
 __all__ = [

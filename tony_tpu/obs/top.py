@@ -38,6 +38,7 @@ _VALUE_COLS = (
     ("occup", "occupancy", "{:.2f}"),
     ("hit%", "prefix_hit_rate", "{:.2f}"),  # prefix-store reuse (serve)
     ("tok/st", "tokens_per_step", "{:.2f}"),  # >1 = speculation paying off
+    ("stalls", "stalled_steps", "{:.0f}"),  # decode steps 4x their kind's gap (engine log names the phase)
     ("kvB/t", "kv_bytes_per_token", "{:.0f}"),  # drops under quantized KV
     ("goodput", "goodput_frac", "{:.2f}"),
     ("hbm_gb", "hbm_live_bytes", None),  # formatted specially

@@ -85,6 +85,14 @@ from tony_tpu.serve.spec import DRAFT_SOURCES, propose_drafts
 
 log = logging.getLogger(__name__)
 
+# one marker a decode step in a profiler capture, named by why the step ran
+# as it did (literal names: obs/profiler.annotate)
+_STEP_MARKER = {
+    "ahead": "serve.ahead", "finish": "serve.kept_finish",
+    "admit": "serve.kept_admit", "spec": "serve.kept_spec",
+    "chunk": "serve.kept_chunk", "fresh": "serve.fresh",
+}
+
 
 @dataclass(frozen=True)
 class ServeConfig:
@@ -225,15 +233,30 @@ class _InFlight:
     rows: list[tuple[int, int]]   # (slot, rid) in the decode batch at dispatch
     drafts: Any                   # [S, k] draft batch of a speculative step, else None
     dlens: list[int]              # draft tokens per slot (all 0 on a plain step)
-    reached: int                  # table blocks the live rows attend (record_decode)
-    width: int                    # table width the step was dispatched at
     left: list[int] = field(default_factory=list)  # per row: tokens still owed after this step
-    ahead: bool = False           # dispatched before the step before it was read
+    # why it ran as it did (obs.metrics.STEP_REASONS): 'ahead' = dispatched
+    # before the step before it was read, else what kept it, or 'fresh'
+    why: str = "fresh"
     t0: float = 0.0               # dispatch time
     out: Any = None               # device results the host reads: (tokens, n_emit | None)
     aux: dict = field(default_factory=dict)  # rides the fetch; then the health monitors
     host: Any = None              # ``out`` on the host, once fetched
     dt: float = 0.0               # wall time charged to the step (Engine._sync)
+
+
+@dataclass(slots=True)
+class _FirstToken:
+    """One request's way to its first token: consecutive readings of ONE
+    clock (``time.perf_counter``), from ``submit()`` to the slot's
+    activation. ``Engine.step`` takes the last one, when it returns to its
+    caller, and the five differences are the request's time to first token,
+    tiled (docs/SERVE.md "The step loop")."""
+
+    submit: float          # submit()
+    round: float           # the _admit() round that dequeued it began
+    dispatch: float = 0.0  # its own prefill (a chunked prompt's first chunk) is dispatched
+    token: float = 0.0     # its first token is on the host
+    active: float = 0.0    # _activate_slot returned
 
 
 class _SlotState(NamedTuple):
@@ -435,6 +458,22 @@ class Engine:
         self._inflight: _InFlight | None = None
         self._synced_t = 0.0                      # when the last decode fetch returned
         self._submit_t: dict[int, float] = {}
+        # the step loop's own account (docs/SERVE.md "The step loop"): a
+        # request's readings on the way to its first token, and those whose
+        # token the current step() call will hand to its caller
+        self._first: dict[int, _FirstToken] = {}
+        self._came_visible: list[_FirstToken] = []
+        self._round_t = 0.0                       # when the current _admit() round began
+        self._call_t = 0.0                        # when the current step() call began
+        self._call_admitted = 0                   # prefills and chunks it ran
+        self._call_admit_s = 0.0                  # and the seconds they took (no drain)
+        # what kept the NEXT step planned with nothing in flight ('fresh':
+        # no verdict stands before it), when the last emit ended (0: the
+        # engine was idle since), and host seconds under plan / dispatch /
+        # sync since then
+        self._next_why = "fresh"
+        self._emit_t = 0.0
+        self._phase_s = [0.0, 0.0, 0.0]
         self._next_rid = 0
         self._prefill_fns: dict[int, Any] = {}
         self._tail_fns: dict[tuple[int, int], Any] = {}
@@ -632,11 +671,35 @@ class Engine:
             # under the device's work)
             "decode_steps": float(self.metrics.decode_steps),
             "steps_ahead": float(self.metrics.steps_ahead),
+            # the rest of them by what kept them (`_may_run_ahead`; those with
+            # no verdict before them are what is left of decode_steps), and
+            # the steps whose visible gap, less the same call's admissions,
+            # was STALL_FACTOR x the running median of their kind's (each
+            # logged once, with its largest phase)
+            **{f"steps_kept_{why}": float(n)
+               for why, n in self.metrics.steps_kept.items()},
+            "stalled_steps": float(self.metrics.stalled_steps),
+            "stalled_s": round(self.metrics.stalled_s, 4),
             # pool label (disaggregated gangs): a string, so it rides the
             # series journal but the numeric AM metrics push drops it —
             # AM-rollup consumers derive the pool from the task type instead
             "pool": self.serve.pool,
         }
+        m = self.metrics
+        if m.requests_started:
+            # a request's time to first token, tiled: the means of its five
+            # parts, which add up to the mean of ``ttft_visible`` below
+            # (what a caller feels; ``ttft`` ends when the token is on the
+            # host, a part and a decode step earlier)
+            for part in ("queue", "behind", "prefill", "activate", "held"):
+                snap[f"ttft_{part}_mean_s"] = round(
+                    getattr(m, f"ttft_{part}_s") / m.requests_started, 6)
+        for why in m.step_gap_s:
+            n = m.steps_of(why)
+            if n:
+                # per reason: a step's visible gap and its share of decode_s
+                snap[f"step_gap_mean_s_{why}"] = round(m.step_gap_s[why] / n, 6)
+                snap[f"step_dt_mean_s_{why}"] = round(m.step_dt_s[why] / n, 6)
         if self._chunking:
             # slots mid-chunked-prefill: occupied but not decoding yet
             snap["chunking_slots"] = float(len(self._chunking))
@@ -667,6 +730,7 @@ class Engine:
             snap["pool_blocks"] = float(self._pool.n_blocks)
         for hist, prefix in (
             (self._h_ttft, "ttft"),
+            (self._h_visible, "ttft_visible"),
             (self._h_tpot, "tpot"),
             (self._h_step, "decode_step"),
         ):
@@ -695,8 +759,16 @@ class Engine:
 
     def _init_registry(self) -> None:
         reg = self.registry = Registry()
-        self._h_ttft = reg.histogram("tony_ttft_seconds",
-                                     "request submit -> first sampled token")
+        self._h_ttft = reg.histogram(
+            "tony_ttft_seconds",
+            "request submit -> first sampled token on the engine's host (the "
+            "engine's view; a caller sees the token when step() returns: "
+            "tony_ttft_visible_seconds)")
+        self._h_visible = reg.histogram(
+            "tony_ttft_visible_seconds",
+            "request submit -> the step() call that admitted it returns to "
+            "its caller (what a caller feels: tony_ttft_seconds + the slot's "
+            "activation + the decode step the same call runs first)")
         self._h_tpot = reg.histogram("tony_tpot_seconds",
                                      "mean per-token latency after the first")
         self._h_step = reg.histogram("tony_decode_step_seconds",
@@ -747,6 +819,12 @@ class Engine:
             "tony_serve_steps_ahead_total",
             "decode steps dispatched before the step before them was read",
         )
+        self._c_stalled = reg.counter(
+            "tony_serve_stalled_steps_total",
+            "decode steps whose visible gap, less the same call's admissions, "
+            "exceeded 4x the running median of their kind's (each logged with "
+            "its largest host phase)",
+        )
         self._c_handoff_shipped = reg.counter(
             "tony_serve_handoff_shipped_blocks_total",
             "physical blocks exported for a blockwise KV handoff",
@@ -794,6 +872,7 @@ class Engine:
                 sp.end(reason="shutdown")
             spans.clear()
         self._first_tok_t.clear()
+        self._first.clear()
         if self._inflight is not None:
             # a step still in flight: wait for it, so the device is quiet
             # when close() returns, and drop what it sampled
@@ -876,18 +955,54 @@ class Engine:
         # disarmed): a broadcast window brackets decode steps exactly like
         # train steps, so `tony profile` anatomises serving hosts too
         profile.maybe_capture()
+        self._call_t = time.perf_counter()
+        self._call_admitted = 0
+        self._call_admit_s = 0.0
         # chunked-prefill interleave: slots already chunking advance ONE
         # chunk each per step (slots _admit parks into chunking below ran
         # their first chunk inside admission — advancing them again here
         # would burn two chunks in one step)
         pending = sorted(self._chunking)
         self._admit()
-        for slot in pending:
-            if slot in self._chunking:
-                self._prefill_chunk(slot)
-        if self.n_decoding:
+        if pending:
+            t0, synced = time.perf_counter(), self._phase_s[2]
+            for slot in pending:
+                if slot in self._chunking:
+                    self._prefill_chunk(slot)
+            # as a round of admissions: without the wait for a step in flight
+            self._call_admit_s += time.perf_counter() - t0 - (self._phase_s[2] - synced)
+        decoding = self.n_decoding
+        if decoding:
             self._decode_once()
-        return self.n_live
+        live = self.n_live
+        if not (decoding and live):
+            # nothing decoded in this call, or nothing is left alive: no
+            # verdict stands before the next step, and its gap starts with
+            # the call that runs it
+            self._next_why, self._emit_t = "fresh", 0.0
+        if self._came_visible:
+            self._hand_over_first_tokens()
+        return live
+
+    def _hand_over_first_tokens(self) -> None:
+        """The last thing a ``step()`` call does: the requests it activated
+        become visible to its caller NOW. One more reading of the clock
+        closes each one's time to first token — ``held``, the decode step
+        (and a step dispatched ahead) the same call ran after the activation
+        — and the five parts go to the counters, ``tony_ttft_visible_seconds``
+        and, under a profiler session, one ``serve.visible`` marker a request."""
+        now = time.perf_counter()
+        for f in self._came_visible:
+            queue, behind = f.round - f.submit, f.dispatch - f.round
+            prefill, activate = f.token - f.dispatch, f.active - f.token
+            held = now - f.active
+            self.metrics.record_visible(queue, behind, prefill, activate, held)
+            self._h_visible.observe(now - f.submit)
+            with annotate("serve.visible", queue_us=int(queue * 1e6),
+                          behind_us=int(behind * 1e6), prefill_us=int(prefill * 1e6),
+                          activate_us=int(activate * 1e6), held_us=int(held * 1e6)):
+                pass
+        self._came_visible.clear()
 
     def completion_of(self, rid: int) -> Completion | None:
         """Live view of a request's completion: ``tokens`` grows in place
@@ -939,9 +1054,14 @@ class Engine:
         free = [s for s, r in enumerate(self._slot_rid) if r is None]
         if not free:
             return
+        self._round_t, synced = time.perf_counter(), self._phase_s[2]
         with annotate("serve.admit"):
             while free and self._queue:
                 self._admit_one(free.pop(0), *self._queue.popleft())
+        # part of the visible gap of the step this call emits and not that
+        # step's own, but for the wait for a step in flight (:meth:`_drain`)
+        self._call_admit_s += (time.perf_counter() - self._round_t
+                               - (self._phase_s[2] - synced))
 
     def _bucket_for(self, plen: int) -> int:
         for b in self.serve.prefill_buckets:
@@ -960,6 +1080,9 @@ class Engine:
         prompt = np.asarray(jax.device_get(req.prompt), np.int32).reshape(-1)
         plen = len(prompt)
         bucket = self._bucket_for(plen)
+        first = self._first[rid] = _FirstToken(
+            submit=self._submit_t[rid], round=self._round_t)
+        self._call_admitted += 1
         # prefix match: pure host-side hashing on the admission path (no
         # device work, GL001-clean). A match is used only when it covers at
         # least one full block — shorter overlaps would pay a COW block
@@ -1014,6 +1137,7 @@ class Engine:
         with trace.span("serve.prefill", rid=rid, bucket=bucket, slot=slot,
                         matched=matched), annotate("serve.prefill"):
             self._plan_blocks(slot, plen, match)
+            first.dispatch = time.perf_counter()
             if match is None:
                 padded = np.zeros((1, bucket), np.int32)
                 padded[0, :plen] = prompt
@@ -1032,6 +1156,7 @@ class Engine:
             # EXPLICIT sync: the sampled first token steers admission on
             # the host (transfer-guard-clean under GRAFT_SANITIZE)
             tok = int(self._fetch(tok, aux, step=False)[0])
+            first.token = time.perf_counter()
         with annotate("serve.activate"):
             self._activate_slot(slot, rid, req, prompt, tok, carry, t0)
 
@@ -1047,6 +1172,11 @@ class Engine:
         plen = len(job.prompt)
         end = min(job.pos + self.serve.chunk_tokens, plen)
         final = 1 if end == plen else 0
+        first = self._first[job.rid]
+        if not first.dispatch:
+            first.dispatch = time.perf_counter()   # the first chunk's
+        else:
+            self._call_admitted += 1
         with trace.span("serve.prefill_chunk", rid=job.rid, slot=slot,
                         start=job.pos, end=end, final=final), \
                 annotate("serve.prefill_chunk"):
@@ -1055,6 +1185,7 @@ class Engine:
             )
             if final:
                 tok = int(self._fetch(tok, aux, step=False)[0])
+                first.token = time.perf_counter()
             elif aux:
                 self._fetch((), aux, step=False)
         if not final:
@@ -1096,7 +1227,10 @@ class Engine:
     def _activate_slot(self, slot: int, rid: int, req: Request,
                        prompt: np.ndarray, tok: int, carry, t0: float) -> None:
         """Post-prefill activation: the sampled first token lands, TTFT is
-        recorded, and the slot joins the decode batch."""
+        recorded (``tony_ttft_seconds``: the token on the engine's host; what
+        the caller waits for beyond it, this activation and the rest of the
+        ``step()`` call, is closed in :meth:`_hand_over_first_tokens`), and
+        the slot joins the decode batch."""
         plen = len(prompt)
         self._register_prompt(slot, prompt)
         now = time.perf_counter()
@@ -1136,6 +1270,9 @@ class Engine:
             self._finish(slot, "eos")
         elif self._slot_remaining[slot] <= 0:
             self._finish(slot, "length")
+        first = self._first.pop(rid)
+        first.active = time.perf_counter()
+        self._came_visible.append(first)
 
     def _finish(self, slot: int, reason: str) -> None:
         rid = self._slot_rid[slot]
@@ -1634,9 +1771,10 @@ class Engine:
         head, ahead = self._inflight, None
         fresh = head is None        # nothing in flight: today's order
         if fresh:
-            head = self._plan()
-        elif self._may_run_ahead(head):
-            ahead = self._plan()
+            head = self._plan(self._next_why)
+            self._next_why = "fresh"
+        else:
+            ahead = self._plan_ahead(head)
         tracer = trace.active_tracer()
         sp = trace.NOOP_SPAN
         if tracer is not None:
@@ -1644,47 +1782,68 @@ class Engine:
         with sp, annotate("serve.step"):
             if fresh:
                 self._dispatch(head)
-                if self._may_run_ahead(head):
-                    ahead = self._plan()
+                ahead = self._plan_ahead(head)
             if ahead is not None:
-                ahead.ahead = True
                 self._dispatch(ahead)
             self._sync(head)
         self._inflight = ahead
         self._emit(head)
 
-    def _may_run_ahead(self, step: _InFlight) -> bool:
-        """May the step after ``step`` be dispatched before ``step``'s
-        tokens are read? Only if its plan needs nothing they decide:
+    def _plan_ahead(self, head: _InFlight) -> _InFlight | None:
+        """The plan of the step after ``head`` where it may be dispatched
+        before ``head`` is read; else None, and what kept it is remembered
+        for the step that the next call plans with nothing in flight."""
+        why = self._may_run_ahead(head)
+        if why == "ahead":
+            return self._plan("ahead")
+        self._next_why = why
+        return None
 
-        - no row of ``step`` is on its LAST token by length — a finish the
-          host can foresee ends at a boundary with nothing queued, so the
-          caller's next request is admitted, and its prefill starts, on an
-          idle device exactly as without a pipeline (a queued decode step
-          would sit in front of every such first token);
-        - no request waits for a slot that is already free: the next call
-          admits it, and the step after ``step`` is the first it decodes in;
-        - the engine does not speculate: drafts are proposed on the host
-          from the tokens just read;
-        - no prompt is prefilling in chunks: a chunk, and the activation
-          after the last one, run between steps on a drained device.
+    def _may_run_ahead(self, step: _InFlight) -> str:
+        """May the step after ``step`` be dispatched before ``step``'s
+        tokens are read? ``'ahead'`` if so, else the FIRST condition below
+        that said no (``obs.metrics.KEPT_REASONS``): the name the step after
+        ``step`` is counted and marked under. It may only if its plan needs
+        nothing those tokens decide:
+
+        - ``'finish'``: no row of ``step`` is on its LAST token by length —
+          a finish the host can foresee ends at a boundary with nothing
+          queued, so the caller's next request is admitted, and its prefill
+          starts, on an idle device exactly as without a pipeline (a queued
+          decode step would sit in front of every such first token);
+        - ``'admit'``: no request waits for a slot that is already free: the
+          next call admits it, and the step after ``step`` is the first it
+          decodes in;
+        - ``'spec'``: the engine does not speculate: drafts are proposed on
+          the host from the tokens just read;
+        - ``'chunk'``: no prompt is prefilling in chunks: a chunk, and the
+          activation after the last one, run between steps on a drained
+          device.
 
         Decided from what the engine observes at each step; no knob. An eos
         cannot be foreseen: it is found one device step late (:meth:`_emit`),
         and the request admitted into its slot — like one that arrives from
         outside between two calls — starts behind the step in flight and
         decodes from the step after it."""
-        last = any(n <= 0 for n in step.left)
-        admits = bool(self._queue) and self.n_live < self.serve.slots
-        return not (last or admits or self.serve.spec or self._chunking)
+        if any(n <= 0 for n in step.left):
+            return "finish"
+        if self._queue and self.n_live < self.serve.slots:
+            return "admit"
+        if self.serve.spec:
+            return "spec"
+        if self._chunking:
+            return "chunk"
+        return "ahead"
 
-    def _plan(self) -> _InFlight:
+    def _plan(self, why: str) -> _InFlight:
         """Per-step block planning: a live row allocates blocks NOW to
         cover every position this step may write (host-side, before
         dispatch) — position pos autoregressively, pos..pos+draft_len
         speculatively; the attended table width tracks the live maximum.
         Reads the host bookkeeping as the step before it left it at ITS
-        dispatch, so it is right whether or not that step has been read."""
+        dispatch, so it is right whether or not that step has been read.
+        ``why``: the reason the step will be counted under."""
+        t0 = time.perf_counter()
         with annotate("serve.plan"):
             B = self.serve.kv_block
             rows = [
@@ -1695,7 +1854,6 @@ class Engine:
             drafts_np, dlens = self._propose_step_drafts(live)
             spec_step = any(dlens)
             need = 1
-            reached = 0     # table blocks the live rows attend this step
             for s in live:
                 last = self._slot_len[s] + (dlens[s] if spec_step else 0)
                 while self._slot_blocks[s] * B <= last:
@@ -1703,14 +1861,15 @@ class Engine:
                     self._slot_blocks[s] += 1
                     self._table_dirty = True
                 need = max(need, last // B + 1)
-                reached += last // B + 1
             if self.cache.quantized:
                 self._flush_fresh_scales()
             self._set_attended(need)
-            return _InFlight(
+            step = _InFlight(
                 rows=rows, drafts=drafts_np if spec_step else None,
-                dlens=dlens, reached=reached, width=self._attended,
+                dlens=dlens, why=why,
             )
+        self._phase_s[0] += time.perf_counter() - t0
+        return step
 
     def _dispatch(self, step: _InFlight) -> None:
         """Launch ``step``'s program and advance what PLANNING reads —
@@ -1739,6 +1898,7 @@ class Engine:
                         self.state,
                     )
             step.out = (toks, n_emit)
+        self._phase_s[1] += time.perf_counter() - step.t0
         for s, _ in step.rows:
             self._slot_len[s] += 1
             self._slot_remaining[s] -= 1
@@ -1756,10 +1916,12 @@ class Engine:
         brought to the host (:meth:`_drain`)."""
         if step.out is None:
             return
+        t0 = time.perf_counter()
         with annotate("serve.sync"):
             step.host, step.aux = self._fetch(step.out, step.aux)
         step.out = None
         now = time.perf_counter()
+        self._phase_s[2] += now - t0
         step.dt = now - max(step.t0, self._synced_t)
         self._synced_t = now
 
@@ -1780,7 +1942,9 @@ class Engine:
         request was admitted into it in between: the row ran one more step
         on the device (it re-emitted its eos into a position whose block
         the plan allocated; ``done`` rows are harmless by construction) and
-        its tokens of this step are dropped."""
+        its tokens of this step are dropped. Last, the step's account
+        (:meth:`_account`)."""
+        t0 = time.perf_counter()
         with annotate("serve.emit"):
             toks_np, emit_np = step.host
             owned = [(s, n) for (s, r), n in zip(step.rows, step.left)
@@ -1798,10 +1962,9 @@ class Engine:
                 new_total = len(live)
             self.metrics.record_decode(
                 step.dt, new_total, len(live), self.serve.slots,
-                attn_blocks=(step.reached, self.serve.slots * step.width),
-                ahead=step.ahead,
+                why=step.why,
             )
-            if step.ahead:
+            if step.why == "ahead":
                 self._c_steps_ahead.inc()
             hbm.sample()  # stride-counted device-memory reading (no sync)
             hmon = step.aux
@@ -1836,7 +1999,39 @@ class Engine:
                     # by what THIS step left owed: ``_slot_remaining`` may
                     # already count the step dispatched after it
                     self._finish(s, "length")
+        self._account(step, len(live), t0)
 
+    def _account(self, step: _InFlight, live: int, t_emit: float) -> None:
+        """The emitted step's account (``t_emit``: when its emit began): its
+        visible gap is counted under why it ran as it did; one far beyond its
+        kind's is logged once, with the host phase (plan / dispatch / sync /
+        emit since the emit before it) that held most of it; and, under a
+        profiler session, ONE marker named by that reason carries the step's
+        ordinal, its gap and the same call's admissions onto the capture's
+        clock."""
+        now = time.perf_counter()
+        gap_s, admit_s = now - (self._emit_t or self._call_t), self._call_admit_s
+        plan_s, dispatch_s, sync_s = self._phase_s
+        self._phase_s = [0.0, 0.0, 0.0]
+        self._emit_t = now
+        n = self.metrics.decode_steps - 1   # the step's ordinal since reset_metrics()
+        if self.metrics.record_step(step.why, gap_s, admit_s):
+            self._c_stalled.inc()
+            phases = {"plan": plan_s, "dispatch": dispatch_s, "sync": sync_s,
+                      "emit": now - t_emit}
+            # what they and the admissions leave of the gap: the caller,
+            # between two calls
+            phases["outside the step"] = gap_s - admit_s - sum(phases.values())
+            worst = max(phases, key=phases.get)
+            log.warning(
+                "decode step %d stalled (%s, %d live, %d admitted): largest phase: %s, "
+                "%.1f ms of a %.1f ms gap; plan %.1f, dispatch %.1f, sync %.1f, emit %.1f, "
+                "admissions %.1f ms", n, step.why, live, self._call_admitted, worst,
+                1e3 * phases[worst], 1e3 * gap_s, 1e3 * plan_s, 1e3 * dispatch_s,
+                1e3 * sync_s, 1e3 * phases["emit"], 1e3 * admit_s)
+        with annotate(_STEP_MARKER[step.why], n=n, admitted=self._call_admitted,
+                      admit_us=int(admit_s * 1e6), gap_us=int(gap_s * 1e6)):
+            pass
 
 def steps_for(cfg):
     """The model family, chosen ONCE by the configuration's class: the
