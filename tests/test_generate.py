@@ -1,5 +1,7 @@
 """KV-cache decode + generation tests: cache path must match full forward."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -170,22 +172,128 @@ def test_sample_matches_legacy_sort_impl_top_p_only():
             np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
-def test_sample_tokens_vectorises_heterogeneous_rows():
-    """The engine's per-row sampler: greedy rows equal argmax regardless of
-    key; top_k=1 rows are deterministic; truncated rows only emit admitted
-    tokens."""
+def _sample_tokens_before(logits, temperature, top_k, top_p, rngs, *, max_k=64):
+    """``sample_tokens`` as it was before it branched (PR 35): every row's
+    top-k slice, scatter and draw, then the argmax where a row is greedy.
+    The oracle the branching sampler has to equal token for token."""
+    N, V = logits.shape
+    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    scaled = logits / jnp.maximum(temperature, 1e-6)[:, None]
+    k = min(max_k, V)
+    vals, idx = jax.lax.top_k(scaled, k)
+    eff_k = jnp.where(top_k > 0, jnp.minimum(top_k, k), k)
+    keep = jnp.arange(k)[None, :] < eff_k[:, None]
+    vals = jnp.where(keep, vals, -jnp.inf)
+    probs = jax.nn.softmax(vals, axis=-1)
+    cum = jnp.cumsum(probs, axis=-1)
+    keep_p = jnp.where(top_p[:, None] > 0.0, (cum - probs) < top_p[:, None], True)
+    vals = jnp.where(keep & keep_p, vals, -jnp.inf)
+    truncate = (top_k > 0) | (top_p > 0.0)
+    masked = jnp.full_like(scaled, -jnp.inf).at[jnp.arange(N)[:, None], idx].set(vals)
+    masked = jnp.where(truncate[:, None], masked, scaled)
+    sampled = jax.vmap(
+        lambda key, row: jax.random.categorical(key, row))(rngs, masked).astype(jnp.int32)
+    return jnp.where(temperature <= 0.0, greedy, sampled)
+
+
+def _rows(n, seed):
+    return jax.random.key_data(jax.random.split(jax.random.key(seed), n))
+
+
+@functools.lru_cache(maxsize=None)
+def _jitted():
+    """Both samplers compiled once for the cases that share their shapes."""
     from tony_tpu.models.generate import sample_tokens
 
-    logits = jax.random.normal(jax.random.key(2), (4, 64)) * 2.0
-    rngs = jax.random.key_data(jax.random.split(jax.random.key(3), 4))
-    temp = jnp.asarray([0.0, 1.0, 0.8, 1.2], jnp.float32)
-    top_k = jnp.asarray([0, 1, 3, 0], jnp.int32)
-    top_p = jnp.asarray([0.0, 0.0, 0.0, 0.5], jnp.float32)
-    toks = sample_tokens(logits, temp, top_k, top_p, rngs)
-    assert int(toks[0]) == int(jnp.argmax(logits[0]))
-    assert int(toks[1]) == int(jnp.argmax(logits[1]))  # top_k=1 == greedy
-    top3 = set(np.asarray(jax.lax.top_k(logits[2], 3)[1]))
-    assert int(toks[2]) in top3
+    return jax.jit(sample_tokens), jax.jit(_sample_tokens_before)
+
+
+# (temperature, top_k, top_p) of the rows that sample; "one" puts a single
+# such row among greedy rows, "all" makes every row sample
+_SAMPLED = [(1.0, 0, 0.0), (0.8, 1, 0.0), (0.7, 5, 0.0), (1.2, 0, 0.5),
+            (0.9, 5, 0.9), (0.3, 70, 0.95)]
+
+
+@pytest.mark.parametrize("case", ["heterogeneous", "greedy", *(
+    f"{layout}-{i}" for layout in ("one", "all") for i in range(len(_SAMPLED)))])
+def test_sample_tokens_vectorises_heterogeneous_rows(case):
+    """The engine's per-row sampler. ``heterogeneous``: greedy rows equal
+    argmax regardless of key; top_k=1 rows are deterministic; truncated rows
+    only emit admitted tokens. ``greedy``: a batch with no sampling row is
+    the argmax, bit for bit, ties included (the first index). The rest: a
+    batch with one sampling row among greedy rows, or with every row
+    sampling, gives the tokens the unbranched sampler gave for the same keys."""
+    sample, before = _jitted()
+    if case == "heterogeneous":
+        logits = jax.random.normal(jax.random.key(2), (4, 64)) * 2.0
+        rngs = _rows(4, 3)
+        temp = jnp.asarray([0.0, 1.0, 0.8, 1.2], jnp.float32)
+        top_k = jnp.asarray([0, 1, 3, 0], jnp.int32)
+        top_p = jnp.asarray([0.0, 0.0, 0.0, 0.5], jnp.float32)
+        toks = sample(logits, temp, top_k, top_p, rngs)
+        assert int(toks[0]) == int(jnp.argmax(logits[0]))
+        assert int(toks[1]) == int(jnp.argmax(logits[1]))  # top_k=1 == greedy
+        top3 = set(np.asarray(jax.lax.top_k(logits[2], 3)[1]))
+        assert int(toks[2]) in top3
+        return
+    N, V = 8, 300
+    # few distinct values a row: ties for the maximum in most rows
+    logits = jax.random.randint(jax.random.key(5), (N, V), -4, 4).astype(jnp.float32)
+    zeros_i = jnp.zeros((N,), jnp.int32)
+    zeros_f = jnp.zeros((N,), jnp.float32)
+    if case == "greedy":
+        for seed in range(3):
+            toks = sample(logits, zeros_f, zeros_i + 5, zeros_f + 0.9, _rows(N, seed))
+            np.testing.assert_array_equal(np.asarray(toks), np.argmax(np.asarray(logits), -1))
+        return
+    layout, i = case.split("-")
+    t, k, p = _SAMPLED[int(i)]
+    rows = np.arange(N) == 3 if layout == "one" else np.ones(N, bool)
+    temp = jnp.where(rows, t, 0.0).astype(jnp.float32)
+    top_k = jnp.where(rows, k, 0).astype(jnp.int32)
+    top_p = jnp.where(rows, p, 0.0).astype(jnp.float32)
+    logits = logits + jax.random.normal(jax.random.key(6), (N, V))
+    for seed in range(4):
+        rngs = _rows(N, 10 + seed)
+        got = sample(logits, temp, top_k, top_p, rngs)
+        want = before(logits, temp, top_k, top_p, rngs)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def _primitives(jaxpr):
+    """The names of every primitive in ``jaxpr``, its sub-jaxprs included."""
+    names = set()
+    for eqn in jaxpr.eqns:
+        names.add(eqn.primitive.name)
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    names |= _primitives(inner)
+    return names
+
+
+def test_a_greedy_batch_branches_past_the_vocabulary_wide_draw():
+    """The sampler holds a ``cond``; its greedy branch, and everything
+    outside the cond, holds no top-k and no rng op: only a batch that
+    samples runs the slice, the scatter and the draw (read off the jaxpr,
+    not a clock)."""
+    from tony_tpu.models.generate import sample_tokens
+
+    N, V = 4, 128
+    z = jnp.zeros((N,), jnp.float32)
+    closed = jax.make_jaxpr(sample_tokens)(
+        jnp.zeros((N, V)), z, jnp.zeros((N,), jnp.int32), z, _rows(N, 0))
+    conds = [e for e in closed.jaxpr.eqns if e.primitive.name == "cond"]
+    assert len(conds) == 1
+    greedy, wide = (_primitives(b.jaxpr) for b in conds[0].params["branches"])
+    outside = {e.primitive.name for e in closed.jaxpr.eqns} - {"cond"}
+
+    def wide_ops(names):
+        return {n for n in names if "top_k" in n or "random" in n or "threefry" in n}
+
+    assert not wide_ops(greedy) and not wide_ops(outside), (greedy, outside)
+    assert "top_k" in wide and wide_ops(wide) - {"top_k"}
 
 
 def test_eos_rows_stick():

@@ -330,9 +330,10 @@ class TestServeRules:
         assert s.summary()["detail"]["entropy_floor"]["rid"] == 0
 
     def test_disarmed_engine_compiles_no_monitors(self, monkeypatch):
-        """With the sentinel disabled the decode step returns an empty
-        monitor dict — the monitors are a compile-time choice, not a
-        masked cost (the engine arms itself from env by default)."""
+        """With the sentinel disabled the decode step's aux holds no
+        monitor, only what ``Engine._fetch`` reads with the tokens — the
+        monitors are a compile-time choice, not a masked cost (the engine
+        arms itself from env by default)."""
         from tony_tpu.models.llama import LlamaConfig, init_params
         from tony_tpu.serve import Engine, Request, ServeConfig
 
@@ -341,11 +342,11 @@ class TestServeRules:
         params = init_params(jax.random.key(0), cfg)
         eng = Engine(params, cfg, ServeConfig(slots=2, max_len=32, kv_block=8))
         assert eng._monitors is False
-        from tony_tpu.serve.engine import _decode_fn
+        from tony_tpu.serve.engine import _AUX_FETCHED, _decode_fn
 
         step = _decode_fn(cfg, "scan", 8, eng.serve.max_top_k, eng._monitors)
         out = step(params, eng.cache, eng._table_dev, eng.state)
-        assert out[-1] == {}
+        assert set(out[-1]) <= set(_AUX_FETCHED)
 
 
 # --- fit() integration --------------------------------------------------------

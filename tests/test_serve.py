@@ -533,9 +533,11 @@ def _eager_activate(st, slot, tok, carry, temp, top_k, top_p, eos):
 
 
 def _eager_release(st, lengths, slot):
-    """The three eager updates of ``Engine._finish``."""
+    """The four eager updates of ``Engine._finish``: the temperature too,
+    so that a free slot never switches the sampler's wide branch on."""
     st = st._replace(live=st.live.at[slot].set(False),
-                     done=st.done.at[slot].set(False))
+                     done=st.done.at[slot].set(False),
+                     temp=st.temp.at[slot].set(0.0))
     return st, lengths.at[slot].set(0)
 
 
@@ -562,7 +564,7 @@ def _transition_requests(cfg, params, case):
 def test_slot_transitions_equal_the_eager_updates(setup, monkeypatch, case):
     """Every ``serve_activate`` and ``serve_release`` of a run leaves the
     slot state — all eight fields, ALL slots, and the cache's lengths —
-    bit for bit what the eleven eager ``.at[slot].set`` of the plain
+    bit for bit what the twelve eager ``.at[slot].set`` of the plain
     reference above make of the state the program was given; there is one
     program per activation and one per finish, and no other."""
     from tony_tpu.serve import engine as engine_mod

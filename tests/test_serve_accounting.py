@@ -9,16 +9,19 @@ admitted over a step in flight), a one-token request (a slot free again with
 the queue not empty), and a prompt long enough to prefill in two chunks.
 """
 
+import dataclasses
 import logging
 import time
 import types
 
 import pytest
 
+from test_generate import _sample_tokens_before
 from test_serve import _family_model, _hold_off, _pipeline_engine, _prompts
 from tony_tpu.obs import metrics as metrics_mod
 from tony_tpu.obs.metrics import KEPT_REASONS, DecodeMetrics
 from tony_tpu.serve import Request
+from tony_tpu.serve.engine import steps_for
 
 FAMILIES = ("dense", "latent", "shortconv", "ssm_hybrid")
 PARTS = ("queue", "behind", "prefill", "activate", "held")
@@ -165,6 +168,69 @@ def test_the_counters_hold_the_parts_and_the_snapshot_their_means(accounted):
         assert snap[f"ttft_{part}_mean_s"] == pytest.approx(s / len(reqs), abs=1e-6)
     assert snap["ttft_visible_n"] == len(reqs)
     assert snap["ttft_visible_p50_s"] >= snap["ttft_p50_s"]
+
+
+def test_a_greedy_run_never_takes_the_vocabulary_wide_sampler(accounted):
+    eng = accounted["engine"]
+    snap = eng.stats_snapshot()
+    assert eng.metrics.decode_steps > 0
+    assert eng.metrics.vocab_sampler_steps == snap["vocab_sampler_steps"] == 0
+    assert snap["vocab_sampler_share"] == 0.0
+    assert eng._c_vocab_sampler.value == 0
+
+
+# the request that samples: the last admitted, so its slot stays free after
+# it while greedy rows decode on
+SAMPLED = 9
+
+
+def _sampling_run(model, mp):
+    """The accounting's requests, ``SAMPLED`` drawn with top-k and top-p,
+    through an engine that runs ahead: (the engine, {request: tokens}, per
+    decode step dispatched whether ``SAMPLED`` was on a row of it)."""
+    reqs = _requests(model[0])
+    reqs[SAMPLED] = dataclasses.replace(
+        reqs[SAMPLED], temperature=0.8, top_k=5, top_p=0.9, rng=7)
+    eng = _pipeline_engine(model)
+    rids = [eng.submit(r) for r in reqs]
+    with_sampled, dispatch = [], eng._dispatch
+
+    def watched(step):
+        with_sampled.append(any(r == rids[SAMPLED] for _, r in step.rows))
+        return dispatch(step)
+
+    mp.setattr(eng, "_dispatch", watched)
+    while eng.queue_depth or eng.n_live:
+        eng.step()
+    final = {i: tuple(eng.take_completion(rid).tokens) for i, rid in enumerate(rids)}
+    return eng, final, with_sampled
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_the_vocabulary_wide_sampler_runs_while_a_sampling_request_holds_a_slot(family, monkeypatch):
+    """One request that samples among greedy ones: the steps whose sampler
+    took its vocabulary-wide branch are those dispatched while it held its
+    slot, and none after its release zeroed the slot's temperature; a fetch
+    a step as before, and the tokens the unbranched sampler serves."""
+    model = _family_model(family)
+    eng, final, with_sampled = _sampling_run(model, monkeypatch)
+    m, snap = eng.metrics, eng.stats_snapshot()
+    assert m.vocab_sampler_steps == sum(with_sampled) >= BUDGETS[SAMPLED] - 1
+    # the greedy rows decoded on after the release, and those steps paid
+    # the argmax alone
+    assert not with_sampled[-1] and m.decode_steps == len(with_sampled) > m.vocab_sampler_steps
+    assert snap["vocab_sampler_steps"] == m.vocab_sampler_steps == eng._c_vocab_sampler.value
+    assert snap["vocab_sampler_share"] == pytest.approx(
+        m.vocab_sampler_steps / m.decode_steps, abs=1e-4)
+    assert m.device_fetches == m.decode_steps + m.requests_started
+    # the same requests, the family's steps sampling as before this PR
+    # (programs of their own: a config the run above compiled nothing for)
+    cfg, params = model
+    monkeypatch.setattr(steps_for(cfg), "sample_tokens", _sample_tokens_before)
+    _, before, _ = _sampling_run(
+        (dataclasses.replace(cfg, max_seq_len=cfg.max_seq_len + 1), params), monkeypatch)
+    assert final == before
+    assert len(final[SAMPLED]) == BUDGETS[SAMPLED]
 
 
 def test_speculation_keeps_every_step_it_does_not_finish_or_admit_on():

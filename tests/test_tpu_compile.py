@@ -498,26 +498,28 @@ def test_shortconv_decode_step_at_published_widths_keeps_pool_and_state_in_place
 
 # --- the other families' serving programs are the programs they were ----------------------
 
-# (flops, bytes accessed, temporaries, instructions of the compiled text) at the parent of
-# PR 33 (commit 69de200), which moved the layer walk into models/layer_walk.py and added
-# a family to ``steps_for``: PERF.md section 6's method (PR 29)
-_PROGRAMS_OF_PR_32 = {
-    "dense.decode": (14149430272, 912558080, 2257920, 1823),
-    "dense.prefill512": (183018209280, 1227667456, 612864, 1313),
-    "latent.decode": (94019731456, 3437949952, 8242176, 3861),
-    "latent.prefill512": (853507637248, 8985379840, 169394176, 3501),
-    "shortconv.decode": (44899729408, 1304465920, 7180800, 7940),
-    "shortconv.prefill512": (171656413184, 5460690944, 35565056, 12451),
+# (flops, bytes accessed, temporaries, instructions of the compiled text) as PR 36 left
+# them: the parent of PR 33 (commit 69de200), which moved the layer walk into
+# models/layer_walk.py and added a family to ``steps_for`` (PERF.md section 6's method,
+# PR 29), with the sampler branched on whether a row samples (a ``conditional`` whose
+# wide branch the cost analysis counts; PERF.md section 6, PR 36)
+_PROGRAMS_OF_PR_36 = {
+    "dense.decode": (14147382272, 920770560, 2354688, 1847),
+    "dense.prefill512": (183018061824, 1227668480, 838144, 1338),
+    "latent.decode": (94018191360, 3444212736, 8371200, 3886),
+    "latent.prefill512": (853507637248, 8985347072, 169490944, 3522),
+    "shortconv.decode": (44899729408, 1304457728, 7148032, 7963),
+    "shortconv.prefill512": (171656413184, 5460696064, 35661824, 12478),
 }
 
 
-@pytest.mark.parametrize("case", sorted(_PROGRAMS_OF_PR_32))
+@pytest.mark.parametrize("case", sorted(_PROGRAMS_OF_PR_36))
 def test_the_other_families_serving_programs_are_unchanged(one_chip, case):
     """The decode step and a 512 prefill of the dense (Yi-1.5-6B widths, 4
     layers), latent (DeepSeek-V3 widths, 3 layers) and short-convolution
     (LFM2's 14 layers) families, compiled for the described chip: the same
     operations, bytes, temporaries and instruction count as before a fourth
-    family was added beside them."""
+    family was added beside them, but for the sampler's branch."""
     from tony_tpu.serve.cache import create_cache
     from tony_tpu.serve.capacity import _state_avals
     from tony_tpu.serve.engine import _decode_fn, _prefill_fn
@@ -556,7 +558,7 @@ def test_the_other_families_serving_programs_are_unchanged(one_chip, case):
     cost, mem = compiled.cost_analysis(), compiled.memory_analysis()
     instructions = len(re.findall(r" = \S+ ([a-z][\w\-]*)\(", compiled.as_text()))
     assert (int(cost["flops"]), int(cost["bytes accessed"]), mem.temp_size_in_bytes,
-            instructions) == _PROGRAMS_OF_PR_32[case]
+            instructions) == _PROGRAMS_OF_PR_36[case]
 
 
 # --- the state-space family: a 1.3 GB per-slot state touched in place ------------------

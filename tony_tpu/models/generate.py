@@ -298,10 +298,34 @@ def sample_tokens(
     ``max_k`` slice (0 = no top-k: the slice bound still applies when that
     row also sets top_p). Same truncation semantics as :func:`_sample`,
     vectorised over heterogeneous requests sharing one decode step.
-    """
-    N, V = logits.shape
-    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
+    The vocabulary-wide work — the top-k slice, the ``[N, V]`` scatter and
+    the categorical draw's noise — runs only where some row samples
+    (:func:`samples_any`): a ``lax.cond`` inside the one compiled program,
+    whose other branch is the argmax alone. A batch that samples takes the
+    same path, row for row, as every batch did before; the rng keys are the
+    caller's either way, so a row's tokens never depend on its neighbours.
+    """
+    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    return lax.cond(
+        samples_any(temperature),
+        lambda: jnp.where(temperature <= 0.0, greedy, _draw(
+            logits, temperature, top_k, top_p, rngs, max_k)),
+        lambda: greedy,
+    )
+
+
+def samples_any(temperature: jax.Array) -> jax.Array:
+    """The predicate :func:`sample_tokens` branches on: some row of the
+    batch samples (``temperature > 0``). The engine returns it beside a
+    decode step's tokens (``vocab_sampler_steps``)."""
+    return jnp.any(temperature > 0.0)
+
+
+def _draw(logits, temperature, top_k, top_p, rngs, max_k):
+    """Every row's top-k / nucleus draw over the vocabulary ``[N, V]`` ->
+    ``[N]``: the wide branch of :func:`sample_tokens`."""
+    N, V = logits.shape
     scaled = logits / jnp.maximum(temperature, 1e-6)[:, None]
     k = min(max_k, V)
     vals, idx = lax.top_k(scaled, k)  # [N,k] descending
@@ -319,13 +343,12 @@ def sample_tokens(
         jnp.arange(N)[:, None], idx
     ].set(vals)
     masked = jnp.where(truncate[:, None], masked, scaled)
-    sampled = jax.vmap(
+    return jax.vmap(
         lambda key, row: jax.random.categorical(key, row)
     )(rngs, masked).astype(jnp.int32)
-    return jnp.where(temperature <= 0.0, greedy, sampled)
 
 
 __all__ = [
     "DEFAULT_NUCLEUS_K", "KVCache", "forward_with_cache", "generate",
-    "layer", "sample_tokens",
+    "layer", "sample_tokens", "samples_any",
 ]

@@ -194,6 +194,10 @@ class DecodeMetrics:
     steps_ahead: int = 0
     steps_kept: dict = field(default_factory=lambda: dict.fromkeys(KEPT_REASONS, 0))
     steps_fresh: int = 0
+    # decode steps whose sampler took its vocabulary-wide branch (top-k
+    # slice, scatter, draw: some live row has temperature > 0); the rest
+    # paid an argmax alone (models/generate.sample_tokens)
+    vocab_sampler_steps: int = 0
     # per reason (STEP_REASONS), summed over its steps: the step's visible
     # gap (the emit before it ended -> its own emit ended: what one
     # inter-token sample is) and its share of ``decode_s``

@@ -61,6 +61,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from tony_tpu.models.generate import samples_any
 from tony_tpu.models.latent_moe import LatentMoEConfig
 from tony_tpu.models.llama import LlamaConfig, Params
 from tony_tpu.models.shortconv_moe import ShortConvMoEConfig
@@ -671,6 +672,11 @@ class Engine:
             # under the device's work)
             "decode_steps": float(self.metrics.decode_steps),
             "steps_ahead": float(self.metrics.steps_ahead),
+            # decode steps whose sampler ran the vocabulary-wide top-k and
+            # draw (some live row samples), and their share of decode_steps
+            "vocab_sampler_steps": float(self.metrics.vocab_sampler_steps),
+            "vocab_sampler_share": round(
+                self.metrics.vocab_sampler_steps / max(self.metrics.decode_steps, 1), 4),
             # the rest of them by what kept them (`_may_run_ahead`; those with
             # no verdict before them are what is left of decode_steps), and
             # the steps whose visible gap, less the same call's admissions,
@@ -818,6 +824,11 @@ class Engine:
         self._c_steps_ahead = reg.counter(
             "tony_serve_steps_ahead_total",
             "decode steps dispatched before the step before them was read",
+        )
+        self._c_vocab_sampler = reg.counter(
+            "tony_serve_vocab_sampler_steps_total",
+            "decode steps whose sampler ran its vocabulary-wide top-k and draw "
+            "(some live request samples; the rest took the argmax alone)",
         )
         self._c_stalled = reg.counter(
             "tony_serve_stalled_steps_total",
@@ -1204,7 +1215,8 @@ class Engine:
         and the one reading of a step's ``aux``: ``out`` comes to the host —
         a decode step's sampled tokens and, on a speculative step,
         ``n_emit`` — and with it, in the SAME ``device_get``, the expert
-        routes a latent-attention step counted. Whatever the host needs of
+        routes a latent-attention step counted and whether the step's
+        sampler took its vocabulary-wide branch. Whatever the host needs of
         a program rides here: a second blocking transfer, with nothing
         queued on the chip, cost ~2 ms a step for the routes and 0.5 ms for
         the ``done`` flags (PERF.md §6, PRs 27 and 32). The flags are not
@@ -1219,9 +1231,12 @@ class Engine:
         ride = {k: aux[k] for k in _AUX_FETCHED if k in aux}
         out, ride = jax.device_get((out, ride))
         self.metrics.device_fetches += 1
-        if ride:
+        if "moe_routes" in ride:
             self.metrics.record_moe(
                 ride["moe_routes"], ride["moe_tokens"], step=step)
+        if int(ride.get("vocab_sampled", 0)):
+            self.metrics.vocab_sampler_steps += 1
+            self._c_vocab_sampler.inc()
         return out, {k: v for k, v in aux.items() if k not in ride}
 
     def _activate_slot(self, slot: int, rid: int, req: Request,
@@ -2077,7 +2092,7 @@ def _refuse(steps, knob: str, value) -> None:
 # the entries of a step's ``aux`` that Engine._fetch brings to the host with
 # the sampled tokens; whatever else rides there is a health monitor, but for
 # the per-slot state a prefill hands to the scatter (it stays on the device)
-_AUX_FETCHED = ("moe_routes", "moe_tokens")
+_AUX_FETCHED = ("moe_routes", "moe_tokens", "vocab_sampled")
 _AUX_SLOT_STATE = "slot_state"
 
 
@@ -2128,11 +2143,11 @@ def _decode_fn(cfg: LlamaConfig, decode_impl: str, kv_block: int,
     ``draft_k`` it is the speculative verify step ``jit_serve_spec_decode``
     (two more arguments, ``n_emit`` among its results); both run the
     family's one ``decode_step``. What the host needs of a step — the
-    tokens, ``n_emit``, the routes ``aux`` counts — it reads in the ONE
-    ``device_get`` of ``Engine._fetch``, possibly AFTER the next step was
-    dispatched: only results of their own, never a field of the new state
-    (donated by then; ``done`` stays on the device, the host derives a
-    finish from the tokens).
+    tokens, ``n_emit``, the routes ``aux`` counts, the sampler's branch —
+    it reads in the ONE ``device_get`` of ``Engine._fetch``, possibly
+    AFTER the next step was dispatched: only results of their own, never a
+    field of the new state (donated by then; ``done`` stays on the device,
+    the host derives a finish from the tokens).
 
     Contract: the cache (arg 1) and the slot state (arg 3) are DONATED and
     the pools come back as the same buffers — carried through the layer
@@ -2158,13 +2173,20 @@ def _decode_fn(cfg: LlamaConfig, decode_impl: str, kv_block: int,
     knobs.update(cfg=cfg, kv_block=kv_block, max_top_k=max_top_k,
                  monitors=monitors)
 
+    def counted(out, temp):
+        # which branch the step's sampler took (generate.samples_any over
+        # the slots' temperatures), for Engine._fetch to count
+        *rest, aux = out
+        return (*rest, {**aux, "vocab_sampled": samples_any(temp).astype(jnp.int32)})
+
     def serve_decode(params, cache, table, state):
-        return steps.decode_step(params, cache, table, state, **knobs)
+        return counted(steps.decode_step(params, cache, table, state, **knobs),
+                       state.temp)
 
     def serve_spec_decode(params, cache, table, state, drafts, draft_len):
-        return steps.decode_step(
+        return counted(steps.decode_step(
             params, cache, table, state, drafts, draft_len, draft_k=draft_k,
-            **knobs)
+            **knobs), state.temp)
 
     if draft_k:
         return jax.jit(serve_spec_decode, donate_argnums=(1, 3))
@@ -2364,13 +2386,16 @@ def _activate_fn():
 def _release_fn():
     """Jitted return of a finished request's slot (``state`` and the
     cache's ``lengths`` DONATED): ``live`` and ``done`` off, so the decode
-    step steers the slot's writes to the scratch block, and its length 0.
+    step steers the slot's writes to the scratch block, its length 0, and
+    its temperature 0, so a free slot never switches the sampler's
+    vocabulary-wide branch on (``serve_activate`` sets it again).
     It takes ``lengths`` alone — the pools stay arguments of the model's
     programs and of the scatter only."""
     def serve_release(state, lengths, slot):
         state = state._replace(
             live=state.live.at[slot].set(False),
             done=state.done.at[slot].set(False),
+            temp=state.temp.at[slot].set(0.0),
         )
         return state, lengths.at[slot].set(0)
 
